@@ -96,67 +96,78 @@ def fleet_shards(cfg: Config):
     return shards, workers
 
 
-def _resolve_act_device(spec: str):
-    """Device for actor inference, or None to leave placement alone.
+def resolve_act_device(spec: str):
+    """THE decision of where actor inference runs: the one local Device
+    an act jit is resolved for (network twin) AND its params are
+    committed to (so the un-pinned jit executes there).
 
-    "auto": the CPU backend when the default backend is an accelerator
-    (params get copied host-side once per refresh; every env step's
-    dispatch + q fetch then stays on-host).  "cpu": force it.  "default":
-    never move — inference shares the learner's device.
+    "auto": the host CPU backend when the default backend is an
+    accelerator (params get copied host-side once per refresh; every env
+    step's dispatch + q fetch then stays on-host) — a deliberate design
+    choice for thread/process actors, not a fallback.  "cpu": force it.
+    "default": the process's first local device — inference shares the
+    learner's chip.
+
+    "auto"/"cpu" in a process whose ``JAX_PLATFORMS`` leaves the CPU
+    backend out is an error, not a quiet move onto the accelerator.
     """
-    if spec == "default":
-        return None
+    default = jax.local_devices()[0]
+    if spec == "default" or (spec == "auto" and default.platform == "cpu"):
+        return default
     try:
-        cpu = jax.devices("cpu")[0]
-    except Exception:  # backend absent/filtered out — leave placement alone
-        return None
-    if spec == "cpu" or jax.devices()[0].platform != "cpu":
-        return cpu
-    return None
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            f"act_device={spec!r} runs actor inference on the host CPU "
+            "backend, which this process does not have (JAX_PLATFORMS="
+            f"{jax.config.jax_platforms!r}) — add 'cpu' to JAX_PLATFORMS, "
+            "or set act_device='default' to act on the "
+            f"{default.platform}") from e
 
 
-def make_act_fn(cfg: Config, net: R2D2Network, *,
+def make_act_fn(cfg: Config, net: R2D2Network, *, device=None,
                 retrace_name: str = "actor.act",
                 retrace_budget: Optional[int] = None):
     """Jitted batched single-step inference:
     (params, obs (B,*obs) u8, last_action (B,A) f32, last_reward (B,) f32,
     hidden (B,2,layers,H)) → (q (B,A) f32, new hidden).
 
+    ``device`` is where the act runs (default: :func:`resolve_act_device`
+    of ``cfg.act_device``); planes that own their placement — the session
+    tier, the centralized inference service — pass theirs.  The network
+    is resolved FOR that device, and the returned callable carries the
+    decision so callers commit params where it points and a run can say
+    what acted: ``.device``, ``.lstm_impl``, ``.compute_dtype``.
+
     ``retrace_name``/``retrace_budget`` override the RETRACES guard entry
     (default: one fixed lane batch, budget 2) — the session tier's
-    continuous batcher (serving/batcher.py) reuses this same twin
-    resolution but legitimately traces once per bucket shape, so it
-    registers under its own name with a bucket-count budget.
+    continuous batcher (serving/batcher.py) legitimately traces once per
+    bucket shape, so it registers under its own name with a bucket-count
+    budget.
 
-    When actor inference runs on the host CPU backend (``cfg.act_device``
-    "auto"/"cpu" with an accelerator default backend — see
-    :func:`_resolve_act_device`) but the learner's network resolved the
-    fused Pallas LSTM (TPU-only lowering), acting uses a **scan-impl twin**
-    of the network: the two implementations declare identical parameters
-    (models/network.py:resolve_lstm_impl), so the published param
-    snapshots apply unchanged — the recurrence engine is just re-chosen
-    for the platform the jit will actually lower on.  A CPU act twin also
+    When ``device`` is not a TPU but the learner's network resolved the
+    fused Pallas LSTM (TPU-only lowering), acting uses a **scan-impl
+    twin** of the network: the two implementations declare identical
+    parameters (models/network.py:resolve_lstm_impl), so the published
+    param snapshots apply unchanged — the recurrence engine is just
+    re-chosen for the platform the jit lowers on.  A CPU act twin also
     computes in float32 regardless of ``cfg.compute_dtype`` (bf16 is
     emulated on CPU; params are float32 either way)."""
     from r2d2_tpu.models.network import create_network, resolve_lstm_impl
 
-    act_dev = _resolve_act_device(cfg.act_device)
-    # act_dev None = inference stays wherever the default backend puts it
-    # (e.g. evaluating a TPU-trained, explicitly-pallas config on a
-    # CPU-only host) — judge by that platform instead
-    platform = (act_dev.platform if act_dev is not None
-                else jax.default_backend())
+    if device is None:
+        device = resolve_act_device(cfg.act_device)
     twin = {}
     if (resolve_lstm_impl(cfg) == "pallas"
-            and not cfg.pallas_interpret and platform != "tpu"):
+            and not cfg.pallas_interpret and device.platform != "tpu"):
         twin["lstm_impl"] = "scan"
-    if platform == "cpu" and cfg.compute_dtype == "bfloat16":
+    if device.platform == "cpu" and cfg.compute_dtype == "bfloat16":
         # bf16 matmuls are emulated (slow) on CPU and params are f32
         # anyway; the f32 twin is ~30% faster per inference call — material
         # when the whole fleet shares one host core with the learner loop
         twin["compute_dtype"] = "float32"
-    act_net = (create_network(cfg.replace(**twin), net.action_dim)
-               if twin else net)
+    act_cfg = cfg.replace(**twin) if twin else cfg
+    act_net = create_network(act_cfg, net.action_dim) if twin else net
 
     def act(params, obs, last_action, last_reward, hidden):
         return act_net.apply(params, obs, last_action, last_reward, hidden,
@@ -167,8 +178,12 @@ def make_act_fn(cfg: Config, net: R2D2Network, *,
     # hot loop — the e2e tests assert the budget holds
     from r2d2_tpu.utils.trace import RETRACES
 
-    return jax.jit(RETRACES.wrap(retrace_name, act,
-                                 budget=retrace_budget))
+    jitted = jax.jit(RETRACES.wrap(retrace_name, act,
+                                   budget=retrace_budget))
+    jitted.device = device
+    jitted.lstm_impl = resolve_lstm_impl(act_cfg)
+    jitted.compute_dtype = act_cfg.compute_dtype
+    return jitted
 
 
 class VectorActor:
@@ -202,7 +217,6 @@ class VectorActor:
         self.rng = rng or np.random.default_rng(cfg.seed)
 
         self.N = len(envs)
-        self._act_device = _resolve_act_device(cfg.act_device)
         if env_workers is None:
             env_workers = cfg.env_workers
         self._pool: Optional[ThreadPoolExecutor] = None
@@ -227,6 +241,10 @@ class VectorActor:
         self.actor_steps = 0
         self._param_version = 0
         self._params = None
+        # where acting was OBSERVED to run (the first act output's device
+        # platform; None until then, and in serve mode, where the
+        # trainer's InferenceService acts) — train() reports it
+        self.act_platform: Optional[str] = None
 
         # batched AgentState
         self.obs = np.zeros((self.N, *cfg.stored_obs_shape), np.uint8)
@@ -257,28 +275,19 @@ class VectorActor:
     def _refresh_params(self) -> None:
         if self._act_client is not None:
             return  # serve mode: weights never leave the trainer
-        if self._act_device is not None:
-            # actor inference runs on the CPU backend: the reference's
-            # actors hold CPU model copies (worker.py:504-507), and on an
-            # accelerator learner this keeps the per-env-step
-            # dispatch+q-fetch off the device interconnect entirely.  One
-            # params transfer per refresh (every actor_update_interval
-            # steps) replaces a round trip per env step — and the placed
-            # copy is CACHED per published version, so a multi-fleet
-            # actor plane pays the device→host wire transfer once per
-            # publish, not once per fleet.
-            version, params = self.param_store.get_placed(self._act_device)
-            if params is not None and version != self._param_version:
-                self._params = params
-                self._param_version = version
-            return
-        version, params = self.param_store.get()
+        # params are committed where the act fn was resolved to run
+        # (make_act_fn) — with "auto" on an accelerator learner that is
+        # the host CPU backend: the reference's actors hold CPU model
+        # copies (worker.py:504-507), and it keeps the per-env-step
+        # dispatch+q-fetch off the device interconnect entirely.  One
+        # params transfer per refresh (every actor_update_interval steps)
+        # replaces a round trip per env step — and the placed copy is
+        # CACHED per published version, so a multi-fleet actor plane pays
+        # the device→host transfer once per publish, not once per fleet.
+        # (Multi-host publishes HOST arrays, learner._publish; the same
+        # call commits them to this process's act device.)
+        version, params = self.param_store.get_placed(self.act_fn.device)
         if params is not None and version != self._param_version:
-            if isinstance(jax.tree.leaves(params)[0], np.ndarray):
-                # multi-host publishes HOST arrays (learner._publish) so
-                # actor jits stay process-local; commit them to one local
-                # device per refresh rather than re-uploading every call
-                params = jax.device_put(params, jax.local_devices()[0])
             self._params = params
             self._param_version = version
 
@@ -398,6 +407,8 @@ class VectorActor:
             q, new_hidden = self.act_fn(self._params, self.obs,
                                         self.last_action, self.last_reward,
                                         self.hidden)
+            if self.act_platform is None and self._act_client is None:
+                self.act_platform = next(iter(q.devices())).platform
             q = np.asarray(q)
             new_hidden = np.asarray(new_hidden)
 

@@ -19,6 +19,7 @@ import os
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from r2d2_tpu.actor import make_act_fn
@@ -36,6 +37,9 @@ def run_episodes(cfg: Config, net: R2D2Network, params: Any,
     epsilon = cfg.test_epsilon if epsilon is None else epsilon
     rng = rng or np.random.default_rng(cfg.seed)
     act_fn = act_fn or make_act_fn(cfg, net)
+    # commit the params where the act fn was resolved to run (host trees
+    # from a checkpoint restore and a learner's device trees alike)
+    params = jax.device_put(params, act_fn.device)
     N = len(envs)
     action_dim = envs[0].action_space.n
 

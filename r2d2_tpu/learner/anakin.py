@@ -281,7 +281,11 @@ def _make_emit(cfg: Config, action_dim: int, done: bool):
         offs = jnp.cumsum(cut_i) - cut_i              # rank among cut lanes
         slot = jnp.where(cut, (ast["ptr"] + offs) % NB, NB)   # NB = dropped
 
-        arrays = {k: arrays[k].at[slot].set(blocks["slot"][k], mode="drop")
+        # (the ring pads its frame-row axis to whole tiles,
+        # replay/device_ring._slot_shapes: a cut writes its real rows)
+        arrays = {k: (arrays[k].at[slot, :blocks["slot"][k].shape[1]]
+                      if k == "obs" else arrays[k].at[slot]
+                      ).set(blocks["slot"][k], mode="drop")
                   for k in arrays}
         leaf = (slot * K)[:, None] + jnp.arange(K)[None, :]
         prios = prios.at[leaf.reshape(-1)].set(
@@ -389,7 +393,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
         p = ast["prefix"] + ast["size"] + 1
         s = ast["size"]
         ast = {**ast,
-               "buf_obs": ast["buf_obs"].at[lanes, p].set(obs_step),
+               "buf_obs": ast["buf_obs"].at[lanes, p].set(
+                   obs_step.reshape(N, -1)),
                "buf_last_action":
                    ast["buf_last_action"].at[lanes, p].set(one_hot),
                "buf_last_reward":
@@ -447,8 +452,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "prefix": jnp.where(tr, 0, ast["prefix"]),
                "size": jnp.where(tr, 0, ast["size"]),
                "buf_obs": ast["buf_obs"].at[:, 0].set(
-                   jnp.where(tr.reshape((N,) + (1,) * (obs_step.ndim - 1)),
-                             obs_reset, ast["buf_obs"][:, 0])),
+                   jnp.where(tr[:, None], obs_reset.reshape(N, -1),
+                             ast["buf_obs"][:, 0])),
                "buf_last_action": ast["buf_last_action"].at[:, 0].set(
                    jnp.where(trc, noop, ast["buf_last_action"][:, 0])),
                "buf_last_reward": ast["buf_last_reward"].at[:, 0].set(
@@ -545,8 +550,10 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
         last_action=jnp.zeros((N, A), jnp.float32),
         last_reward=jnp.zeros(N, jnp.float32),
         hidden=jnp.zeros((N, 2, layers, H), jnp.float32),
-        buf_obs=jnp.zeros((N, cap, *obs_shape), jnp.uint8
-                          ).at[:, 0].set(obs0),
+        # frames as flat byte rows, the device ring's slot format
+        # (replay/device_ring._slot_shapes): the cut scatters straight in
+        buf_obs=jnp.zeros((N, cap, int(np.prod(obs_shape))), jnp.uint8
+                          ).at[:, 0].set(obs0.reshape(N, -1)),
         buf_last_action=jnp.asarray(buf_la),
         buf_last_reward=jnp.zeros((N, cap), jnp.float32),
         buf_hidden=jnp.zeros((N, cap, 2, layers, H), jnp.float32),
@@ -916,10 +923,7 @@ class AnakinPlane:
             with self._stats_lock:
                 self.frames += self._frames_per_dispatch
                 self.super_steps += 1
-            try:
-                flat.copy_to_host_async()  # explicit: guard-exempt
-            except Exception:
-                pass  # no async copies on this backend: harvest pays it
+            flat.copy_to_host_async()  # explicit: guard-exempt
         return train_state, flat
 
     def harvest(self, flat) -> np.ndarray:
@@ -1313,7 +1317,7 @@ def run_anakin_loop(learner: Any, plane: AnakinPlane,
         def fin():
             try:
                 fin_box["metrics"] = learner._finish_device_run(
-                    losses_all[-100:], t0)
+                    losses_all[-100:], t0, "anakin")
             except Exception:
                 log.exception("hard-wedge epilogue save failed")
 
@@ -1326,13 +1330,15 @@ def run_anakin_loop(learner: Any, plane: AnakinPlane,
             log.error("hard-wedge final save did not complete in time — "
                       "summarizing without it")
             metrics = dict(
+                drivetrain="anakin",
                 num_updates=learner.num_updates,
                 env_steps=learner.env_steps,
                 minutes=learner.start_minutes + (time.time() - t0) / 60.0,
                 mean_loss=(float(np.mean(losses_all[-100:]))
                            if losses_all else float("nan")))
     else:
-        metrics = learner._finish_device_run(losses_all[-100:], t0)
+        metrics = learner._finish_device_run(losses_all[-100:], t0,
+                                             "anakin")
     metrics["losses"] = losses_all
     metrics["dispatch_wedged"] = wedged
     metrics["env_steps"] = plane.env_steps

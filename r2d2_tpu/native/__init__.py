@@ -7,65 +7,60 @@ host core shared with actor inference.  The C implementations are exact
 ports (bit-identical arithmetic, see native/sumtree.c) and release the
 GIL for the duration of the call.
 
-Build model: ``cc -O2 -shared -fPIC`` at first use into a cache directory
-(``$R2D2_NATIVE_CACHE`` or ``~/.cache/r2d2_tpu``), keyed by a content
-hash of the source; loaded via ctypes (no Python.h / pybind dependency).  Anything failing —
-no compiler, read-only cache, load error — degrades silently to the numpy
-implementations (``R2D2_NO_NATIVE=1`` forces that).
+Build model: ``cc -O2 -shared -fPIC`` at first use into the in-checkout
+cache directory (``utils/compile_cache.CACHE_ROOT``, next to the XLA
+cache), keyed by a content hash of the source; loaded via ctypes (no
+Python.h / pybind dependency).  Anything failing — no compiler, read-only
+checkout, load error — degrades to the numpy implementations with ONE
+logged warning naming the cause (``R2D2_NO_NATIVE=1`` forces the numpy
+path); :func:`available` is how a run reports which one it got.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 from typing import Optional
 
 import numpy as np
 
+from r2d2_tpu.utils.compile_cache import CACHE_ROOT
+
+log = logging.getLogger(__name__)
+
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "sumtree.c")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _cache_dir() -> str:
-    return (os.environ.get("R2D2_NATIVE_CACHE")
-            or os.path.join(os.path.expanduser("~"), ".cache", "r2d2_tpu"))
+_CACHE_DIR = os.path.join(CACHE_ROOT, "native")
 
 
 def _build() -> Optional[str]:
-    try:
-        with open(_SRC, "rb") as f:
-            import hashlib
+    import hashlib
 
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError:
-        return None
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     # content-keyed cache: mtimes collide across wheel builds
     # (SOURCE_DATE_EPOCH) and same-second edits, silently loading stale code
     # uid-scoped filename: users sharing a cache dir never collide, and a
     # pre-planted file under our exact name still fails the ownership
     # check below and is rebuilt over (never silently loaded)
-    out = os.path.join(_cache_dir(), f"sumtree_{digest}_u{os.getuid()}.so")
-    if os.path.exists(out):
+    out = os.path.join(_CACHE_DIR, f"sumtree_{digest}_u{os.getuid()}.so")
+    if os.path.exists(out) and os.stat(out).st_uid == os.getuid():
         # only trust a cached .so we own: a writable shared cache path must
-        # not let a pre-planted file be ctypes-loaded into the process
-        try:
-            if os.stat(out).st_uid == os.getuid():
-                return out
-        except OSError:
-            return None
-        # foreign-owned file under our name: fall through and rebuild over
-        # it (os.replace) instead of permanently disabling the fast path
-    cc = os.environ.get("CC", "cc")
-    try:
-        os.makedirs(_cache_dir(), mode=0o700, exist_ok=True)
-        tmp = out + f".tmp{os.getpid()}"
-        subprocess.run([cc, "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
-                       check=True, capture_output=True, timeout=60)
-        os.replace(tmp, out)  # atomic: concurrent builders race benignly
+        # not let a pre-planted file be ctypes-loaded into the process.  A
+        # foreign-owned file under our name falls through and is rebuilt
+        # over (os.replace) instead of permanently disabling the fast path
         return out
-    except Exception:
-        return None
+    cc = os.environ.get("CC", "cc")
+    os.makedirs(_CACHE_DIR, mode=0o700, exist_ok=True)
+    tmp = out + f".tmp{os.getpid()}"
+    subprocess.run([cc, "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
+                   check=True, capture_output=True, timeout=60)
+    os.replace(tmp, out)  # atomic: concurrent builders race benignly
+    return out
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -75,11 +70,8 @@ def _load() -> Optional[ctypes.CDLL]:
     _tried = True
     if os.environ.get("R2D2_NO_NATIVE"):
         return None
-    path = _build()
-    if path is None:
-        return None
     try:
-        lib = ctypes.CDLL(path)
+        lib = ctypes.CDLL(_build())
         i64, f64p, i64p = (ctypes.c_int64, ctypes.POINTER(ctypes.c_double),
                            ctypes.POINTER(ctypes.c_int64))
         lib.st_update.argtypes = [f64p, i64, i64, i64p, f64p, i64]
@@ -89,7 +81,11 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.st_prefix_mass.argtypes = [f64p, i64, i64]
         lib.st_prefix_mass.restype = ctypes.c_double
         _lib = lib
-    except Exception:
+    except (OSError, subprocess.SubprocessError, AttributeError) as e:
+        # no compiler / read-only checkout / unloadable .so: the numpy
+        # implementations are exact, only slower — say so once
+        log.warning("native sum-tree unavailable (%s: %s) — using the "
+                    "numpy implementation", type(e).__name__, e)
         _lib = None
     return _lib
 

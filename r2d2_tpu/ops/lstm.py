@@ -9,15 +9,14 @@ input-projection slice in and the (B, H) hidden slice out.  It is the
 TPU-native stand-in for the implicit cuDNN fused LSTM the reference gets
 for free on the acting path (reference model.py:51,65-79).
 
-**Inference-only — the backward kernel was retired in round 5.**  The
-round-4 on-chip measurement (tools/measure_tpu.py:pallas_lstm_section,
-v5e, B=64 T=85 H=512 bf16) put the fused forward+backward at 0.96x the
-scan recurrence: XLA's scan lowering on current runtimes already keeps
-the MXU busy through the training path, so a 150-line custom-VJP kernel
-bought nothing there.  The forward-only (inference) path kept a 1.07x
-edge — actors and evaluators stream no residuals, and the kernel's
-VMEM-resident h/c is exactly what a T=1..85 acting unroll wants — so that
-half stays.  Training always runs the scan (learner/step.py builds its
+**Inference-only — the backward kernel was retired in round 5.**  A
+round-4 on-chip observation (v5e, B=64 T=85 H=512 bf16; builder-logged,
+its record is gone, never reproduced) put the fused forward+backward at
+0.96x the scan recurrence and the forward-only path at 1.07x, so only the
+inference half stayed.  Whether it earns its keep at the T=1 shape
+production actually runs is not measured (ROADMAP Design 4);
+chip_smoke.py's ``kernel`` leg only proves Mosaic compiles it and it
+matches scan.  Training always runs the scan (learner/step.py builds its
 loss networks with ``lstm_impl="scan"``); differentiating through this
 kernel is unsupported and raises at trace time.
 

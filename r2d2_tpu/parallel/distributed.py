@@ -46,29 +46,6 @@ from r2d2_tpu.config import Config
 from r2d2_tpu.parallel.sharding import DEVICE_BATCH_KEYS, ShardingTable
 
 
-def _distributed_initialized() -> bool:
-    """Has ``jax.distributed.initialize`` already run in this process?
-
-    ``jax.distributed.is_initialized`` only exists in newer JAX; older
-    releases (e.g. 0.4.x) expose the same fact as
-    ``jax.distributed.global_state.client`` being non-None.  Probing via
-    ``getattr`` keeps the bring-up idempotent on both.
-    """
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    state = getattr(jax.distributed, "global_state", None)
-    if state is None:
-        # 0.4.x keeps global_state in the private module only
-        try:
-            from jax._src import distributed as _distributed_src
-
-            state = getattr(_distributed_src, "global_state", None)
-        except ImportError:
-            state = None
-    return state is not None and getattr(state, "client", None) is not None
-
-
 def init_distributed(coordinator_address: Optional[str] = None,
                      num_processes: Optional[int] = None,
                      process_id: Optional[int] = None,
@@ -97,7 +74,7 @@ def init_distributed(coordinator_address: Optional[str] = None,
 
     # NOTE: nothing before initialize() may touch the backend
     # (jax.devices(), jax.process_count(), ...) or it would raise
-    if not _distributed_initialized():
+    if not jax.distributed.is_initialized():
         if coordinator_address is not None:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,

@@ -268,8 +268,6 @@ class RemoteActClient:
         self.param_store = param_store
         self._local_act_factory = local_act_factory
         self._local_act = None
-        self._local_params = None
-        self._local_version = -1
         self.retry = retry if retry is not None else RetryPolicy(
             attempts=3, base=0.05, max_delay=1.0,
             seed=cfg.seed + 7_577 * (src + 1))
@@ -327,24 +325,19 @@ class RemoteActClient:
 
     # ---------------------------------------------------------- local path
     def _await_params(self):
-        """Latest pumped params for the local act twin, committed to a
-        local device once per version.  Blocks (stop-aware) until the
-        param feed delivers the first snapshot — the pump primes each
-        fleet's queue at spawn, so in practice this returns immediately."""
+        """Latest pumped params for the local act twin, committed to the
+        device it was resolved for once per version.  Blocks (stop-aware)
+        until the param feed delivers the first snapshot — the pump
+        primes each fleet's queue at spawn, so in practice this returns
+        immediately."""
         if self.param_store is None:
             raise RuntimeError(
                 f"fleet{self.src}: circuit open but no local fallback "
                 "was provisioned (no param feed)")
         while True:
-            version, params = self.param_store.get()
+            _, params = self.param_store.get_placed(self._local_act.device)
             if params is not None:
-                if version != self._local_version:
-                    import jax
-
-                    self._local_params = jax.device_put(
-                        params, jax.local_devices()[0])
-                    self._local_version = version
-                return self._local_params
+                return params
             if self.stop_event.is_set():
                 raise FleetStopped
             time.sleep(0.05)
@@ -606,31 +599,34 @@ class InferenceService:
     def start(self, param_store) -> None:
         self.param_store = param_store
         if self._act is None:
-            from r2d2_tpu.actor import make_act_fn
+            from r2d2_tpu.actor import make_act_fn, resolve_act_device
             from r2d2_tpu.models.network import create_network
 
-            # "auto" resolves to the DEFAULT backend here (the learner's
+            # "auto" resolves to the DEFAULT device here (the learner's
             # accelerator — centralized inference exists to use it), not
             # local mode's CPU twin; "cpu" still forces the CPU twin, and
             # on a CPU-only host both land on the same scan/f32 twin
-            dev = ("default" if self.cfg.act_device == "auto"
-                   else self.cfg.act_device)
-            acfg = self.cfg.replace(act_device=dev)
-            self._act = make_act_fn(acfg, create_network(acfg,
-                                                         self.action_dim))
+            device = resolve_act_device(
+                "default" if self.cfg.act_device == "auto"
+                else self.cfg.act_device)
+            self._act = make_act_fn(
+                self.cfg, create_network(self.cfg, self.action_dim),
+                device=device)
+
+    @property
+    def act_device(self):
+        """The device the service acts on (resolved by :meth:`start`)."""
+        return self._act.device
 
     def _refresh_params(self) -> None:
-        """Adopt the newest ParamStore publication.  Single-host, params
-        are the learner's own device arrays — zero copies, ~zero
-        staleness; multi-host publishes host arrays, committed to a local
-        device once per version (VectorActor._refresh_params's rule)."""
-        version, params = self.param_store.get()
+        """Adopt the newest ParamStore publication, committed to the
+        device the act was resolved for.  Acting on the learner's own
+        device, the placement is a no-op on its published arrays — zero
+        copies, ~zero staleness; host arrays (multi-host publishes) and a
+        forced CPU twin pay one transfer per version."""
+        version, params = self.param_store.get_placed(self._act.device)
         if params is None or version == self._param_version:
             return
-        import jax
-
-        if isinstance(jax.tree.leaves(params)[0], np.ndarray):
-            params = jax.device_put(params, jax.local_devices()[0])
         self._params = params
         self._param_version = version
 

@@ -355,7 +355,7 @@ def pjit_train_step(cfg, net, table: Optional[ShardingTable] = None,
 
     ``donate_batch=False`` keeps the batch alive across calls — ONLY for
     diagnostics that deliberately re-step one device-resident batch
-    (bench.py / measure_tpu timing loops); the training drivetrains
+    (bench.py's timing loop); the training drivetrains
     always donate.
 
     ``state_template`` (a live TrainState or its avals) derives the

@@ -2,9 +2,8 @@
 
 The reference's data plane moves every training batch across the host↔device
 boundary (worker.py:330-342 `.to(device)` per step).  At flagship shapes that
-is ~40 MB per batch — the dominant system cost on any real interconnect
-(PCIe, and catastrophically so on a tunneled chip).  The TPU-first redesign
-inverts the flow:
+is ~40 MB per batch — the dominant system cost on any real interconnect.
+The TPU-first redesign inverts the flow:
 
 - Each experience block crosses H2D **once**, when the actor produces it
   (~3 MB, at block-production rate — orders of magnitude less traffic than
@@ -77,11 +76,34 @@ from r2d2_tpu.replay.block import Block
 from r2d2_tpu.parallel.sharding import RING_DATA_KEYS as _DATA_KEYS
 
 
+# rows per u8 tile on the TPU: 8 sublanes x 4 bytes packed per 32-bit word
+_OBS_ROW_TILE = 32
+
+
 def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
+    """Per-slot shapes of the DEVICE ring.  Same fields as the host ring,
+    except for how a block's frames are laid out — two repairs found
+    bringing the super-step up on a v5e (PERF.md Findings, PR 21):
+
+    - a frame is one FLAT byte row.  XLA answers a gather from a
+      ``(NB, MS, 21, 21, 16)`` ring that feeds a conv by re-laying-out the
+      WHOLE ring for the conv (a padded copy 7x the ring: 21.7 GB for a
+      3.1 GB ring — the super-step does not compile), while gathering
+      flat rows and reshaping the gathered batch costs a 141 MB temp;
+    - the frame-row axis is padded to a whole number of u8 tiles
+      (441 -> 448 rows).  With a ragged row count the compiler lays the
+      ring out block-minor to save the padding, and the gather it emits
+      for that layout inside the k-step loop reads out of bounds — the
+      core halts with an HBM page fault on the first dispatch whose
+      window reaches a block's late rows.  Pad rows are never read
+      (:func:`gather_batch` clamps to ``max_block_steps - 1``).
+
+    :func:`gather_batch` restores ``cfg.stored_obs_shape``."""
     MS, BL = cfg.max_block_steps, cfg.block_length
     K, layers, H = cfg.seqs_per_block, cfg.lstm_layers, cfg.hidden_dim
+    obs_rows = -(-MS // _OBS_ROW_TILE) * _OBS_ROW_TILE
     return dict(
-        obs=((MS, *cfg.stored_obs_shape), np.uint8),
+        obs=((obs_rows, int(np.prod(cfg.stored_obs_shape))), np.uint8),
         last_action=((MS, action_dim), np.bool_),
         last_reward=((MS,), np.float32),
         action=((BL,), np.uint8),
@@ -89,6 +111,14 @@ def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
         n_step_gamma=((BL,), np.float32),
         hidden=((K, 2, layers, H), np.float32),
     )
+
+
+def device_bytes(cfg: Config, action_dim: int) -> int:
+    """Logical bytes of the device ring's arrays (what the capacity guard
+    budgets; HBM holds them at ~1.03x on the v5e)."""
+    return cfg.num_blocks * sum(
+        int(np.prod(shape)) * np.dtype(dtype).itemsize
+        for shape, dtype in _slot_shapes(cfg, action_dim).values())
 
 
 def _write_slot_fn(arrays: Dict[str, jnp.ndarray],
@@ -122,7 +152,10 @@ def gather_batch(cfg: Config, arrays: Dict[str, jnp.ndarray],
     widx = jnp.minimum(seq_idx[:, None] * L + jnp.arange(L),
                        cfg.block_length - 1)                 # (B, L)
     return dict(
-        obs=arrays["obs"][bcol, time_idx],
+        # flat frame rows in the ring (see _slot_shapes); the network's
+        # frame shape is restored on the gathered batch only
+        obs=arrays["obs"][bcol, time_idx].reshape(
+            *time_idx.shape, *cfg.stored_obs_shape),
         last_action=arrays["last_action"][bcol, time_idx].astype(jnp.float32),
         last_reward=arrays["last_reward"][bcol, time_idx],
         hidden=arrays["hidden"][block_idx, seq_idx],
@@ -304,6 +337,8 @@ class DeviceRing:
             src = getattr(block, k)
             if k == "hidden":
                 arr[:block.num_sequences] = src
+            elif k == "obs":     # flat frame rows (see _slot_shapes)
+                arr[:src.shape[0]] = src.reshape(src.shape[0], -1)
             else:
                 arr[:src.shape[0]] = src
             slot[k] = self._put_slot(arr)

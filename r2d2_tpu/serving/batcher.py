@@ -49,6 +49,8 @@ class ContinuousBatcher:
     """Ragged-batch act over bucket-shaped jitted entry points."""
 
     def __init__(self, cfg: Config, action_dim: int):
+        import jax
+
         from r2d2_tpu.actor import make_act_fn
         from r2d2_tpu.models.network import create_network
 
@@ -56,10 +58,18 @@ class ContinuousBatcher:
         self.action_dim = action_dim
         self.buckets = bucket_sizes(cfg.serve_max_batch)
         net = create_network(cfg, action_dim)
-        # one jitted instance; each bucket shape is one deliberate trace
-        # (+1 slack for a weak-type wobble on the very first call)
-        self._act = make_act_fn(cfg, net, retrace_name="serving.act",
+        # the session tier serves from this process's first device — the
+        # accelerator when there is one — and its act net is resolved FOR
+        # that device (cfg.act_device is the training actors' knob: its
+        # "auto" means a host-CPU twin, which must not be what the chip
+        # executes).  One jitted instance; each bucket shape is one
+        # deliberate trace (+1 slack for a weak-type wobble on the very
+        # first call)
+        self._act = make_act_fn(cfg, net, device=jax.local_devices()[0],
+                                retrace_name="serving.act",
                                 retrace_budget=len(self.buckets) + 1)
+        # where an act output was OBSERVED to live (set by the first act)
+        self.act_platform: Optional[str] = None
         self._params = None
         self.version = 0
         # per-bucket padded scratch, allocated on first use of each size
@@ -90,11 +100,9 @@ class ContinuousBatcher:
 
         if self.cfg.serve_dtype == "bfloat16":
             params = self._quantize(params)
-        # host trees (a checkpoint restore) commit to a local device once
-        # per publish, the VectorActor._refresh_params rule
-        if isinstance(jax.tree.leaves(params)[0], np.ndarray):
-            params = jax.device_put(params, jax.local_devices()[0])
-        self._params = params
+        # commit once per publish to the device the act was resolved for
+        # (host trees from a checkpoint restore and device trees alike)
+        self._params = jax.device_put(params, self._act.device)
         self.version += 1
         return self.version
 
@@ -121,8 +129,7 @@ class ContinuousBatcher:
         lr = rng.normal(size=n).astype(np.float32)
         hid = (rng.normal(size=(n, 2, cfg.lstm_layers, cfg.hidden_dim))
                .astype(np.float32) * 0.1)
-        if isinstance(jax.tree.leaves(params)[0], np.ndarray):
-            params = jax.device_put(params, jax.local_devices()[0])
+        params = jax.device_put(params, self._act.device)
         q_ref, _ = self._act(params, obs, la, lr, hid)
         q_bf16, _ = self._act(self._quantize(params), obs, la, lr, hid)
         return bool((np.asarray(q_ref).argmax(axis=1)
@@ -131,6 +138,13 @@ class ContinuousBatcher:
     @property
     def ready(self) -> bool:
         return self._params is not None
+
+    def act_info(self) -> dict:
+        """What serves: the platform an act output was observed on (None
+        before the first act) and the network resolved for it."""
+        return dict(act_platform=self.act_platform,
+                    act_lstm_impl=self._act.lstm_impl,
+                    act_compute_dtype=self._act.compute_dtype)
 
     # ---------------------------------------------------------------- act
     def bucket(self, n: int) -> int:
@@ -183,6 +197,8 @@ class ContinuousBatcher:
                 q, new_hidden = self._act(self._params, s["obs"],
                                           s["last_action"],
                                           s["last_reward"], s["hidden"])
+            if self.act_platform is None:
+                self.act_platform = next(iter(q.devices())).platform
             # ONE explicit D2H for both outputs (audit r19: was two
             # implicit np.asarray syncs — same values, one blocking
             # fetch, and explicit transfers stay guard-exempt)

@@ -512,7 +512,8 @@ class SessionServer:
             act_failures=self.act_failures,
             mean_batch=round(self.requests / self.batches, 2)
             if self.batches else 0.0,
-            param_version=self.batcher.version, **c, **a)
+            param_version=self.batcher.version,
+            **self.batcher.act_info(), **c, **a)
 
     # ------------------------------------------------------------- snapshot
     def save_sessions(self, ckpt) -> Dict[str, Any]:
@@ -747,8 +748,10 @@ def run_server(cfg: Config, checkpoint_dir: str,
                 signal.signal(sig, handler)
             except (ValueError, OSError):
                 pass
+    from r2d2_tpu.utils.trace import device_memory
+
     out = dict(server.stats(), step=int(step), port=server.port,
-               health=final_health)
+               health=final_health, device_memory=device_memory())
     if follow:
         out.update(followed_step=followed["step"],
                    republishes=followed["republishes"],

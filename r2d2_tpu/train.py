@@ -64,7 +64,7 @@ from r2d2_tpu.telemetry import Telemetry, format_entry
 from r2d2_tpu.utils.math import epsilon_ladder
 from r2d2_tpu.utils.store import ParamStore
 from r2d2_tpu.utils.supervisor import Heartbeat, Supervisor
-from r2d2_tpu.utils.trace import Tracer, device_profile
+from r2d2_tpu.utils.trace import Tracer, device_memory, device_profile
 
 log = logging.getLogger(__name__)
 
@@ -79,12 +79,9 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
            checkpoint_dir: Optional[str], resume: bool):
     """Common bring-up: envs, net, state (maybe restored), buffer, stores.
 
-    Returns the EFFECTIVE config under ``"cfg"``: degrade paths (e.g.
-    ``in_graph_per`` without a ring) flip flags here, and ``train()``
-    must make its fabric decisions from the flipped config — stripping
-    the priority thread from the outer (un-flipped) config while the
-    learner runs the host-sampled path wedges the learner on a full,
-    undrained priority queue after ~its depth in updates.
+    The single-process drivetrain is exactly the one ``cfg`` names: a
+    ``device_replay`` ring that does not fit the device is a ValueError
+    (:func:`_checked_ring_layout`), never a quiet move to host replay.
     """
     if cfg.actor_transport == "process":
         # the fleets own the envs in their subprocesses; the trainer only
@@ -136,41 +133,11 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
     param_store = ParamStore()
     ring = None
     if cfg.device_replay and jax.process_count() == 1:
-        from r2d2_tpu.replay.device_ring import DeviceRing, resolve_layout
-        from r2d2_tpu.replay.replay_buffer import data_bytes
+        from r2d2_tpu.replay.device_ring import DeviceRing
 
-        need, dev_cap = data_bytes(cfg, action_dim), _device_memory_bytes()
-        if dev_cap is not None:
-            cap = dev_cap
-        else:
-            # backend exposes no memory stats (e.g. the CPU client):
-            # "device" memory IS host memory, so apply the host guard
-            from r2d2_tpu.replay.replay_buffer import _available_host_bytes
-
-            cap = _available_host_bytes()
-        # "auto" shards the slot axis over dp when the ring outgrows one
-        # device's HBM; the guard below then checks the per-device share.
-        # Only genuine per-device stats (dev_cap) may trigger
-        # auto-sharding: on a host-RAM fallback cap every "device" shares
-        # one memory, so splitting the accounting per device would wave
-        # through a ring the host cannot hold (an explicit 'dp' request
-        # still honours the user's judgement).
-        layout = resolve_layout(cfg, mesh, need, dev_cap)
-        # budget per real device; against a host-RAM fallback cap the
-        # shards share one memory, so the whole ring is the burden
-        per_device = (need // (mesh.shape["dp"] if layout == "dp" else 1)
-                      if dev_cap is not None else need)
-        if cap is not None and per_device > 0.8 * cap:
-            import warnings
-
-            warnings.warn(
-                f"device_replay ring needs {per_device / 1e9:.1f} GB per "
-                f"device (layout={layout}) but the device has "
-                f"{cap / 1e9:.1f} GB; falling back to host replay — "
-                "reduce buffer_capacity to fit", stacklevel=2)
-        else:
-            ring = (DeviceRing(cfg, action_dim, table=table, layout=layout)
-                    if mesh is not None else DeviceRing(cfg, action_dim))
+        layout = _checked_ring_layout(cfg, action_dim, mesh)
+        ring = (DeviceRing(cfg, action_dim, table=table, layout=layout)
+                if mesh is not None else DeviceRing(cfg, action_dim))
     elif cfg.device_replay:
         # multi-host: each host owns the slot slabs of its dp groups — a
         # dp-layout ring over its LOCAL submesh.  The learner stitches the
@@ -213,22 +180,6 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
                     f"fits={fits} (ring {need / dp_local / 1e9:.1f} GB "
                     "per device); using host staging instead",
                     stacklevel=2)
-    if cfg.in_graph_per and ring is None:
-        # a ring fallback above (doesn't fit / multi-host shapes failed)
-        # must degrade the PER plane with it: device PER cannot run on
-        # host staging (ReplayBuffer would fail fast), and the reference
-        # behavior here is host replay, not a crash.  The presets default
-        # in_graph_per=True, so a single small-HBM chip lands here.
-        import warnings
-
-        warnings.warn(
-            "in_graph_per disabled: no device ring was built (see the "
-            "fallback warning above) — continuing on host-sampled PER; "
-            "shrink buffer_capacity to restore the device-PER plane",
-            stacklevel=2)
-        cfg = cfg.replace(in_graph_per=False)
-    # the learner is built AFTER the ring/in_graph_per decisions so it
-    # (and everything below) sees the effective config
     learner = Learner(cfg, net, state, mesh=mesh, param_store=param_store,
                       checkpointer=checkpointer,
                       start_env_steps=start_env_steps,
@@ -360,7 +311,7 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
                 "a replay snapshot exists but this run uses device_replay "
                 "— replay state lives in HBM and is not restored (resuming "
                 "with a cold ring)", stacklevel=2)
-    return dict(cfg=cfg, envs=envs, action_dim=action_dim, net=net,
+    return dict(envs=envs, action_dim=action_dim, net=net,
                 learner=learner, buffer=buffer, actors=actors,
                 actor=actors[0] if actors else None, plane=plane,
                 replay_plane=replay_plane, param_store=param_store,
@@ -368,12 +319,44 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
                 checkpointer=checkpointer, host_bs=host_bs, ring=ring)
 
 
-def _device_memory_bytes():
-    try:
-        stats = jax.devices()[0].memory_stats()
-        return int(stats["bytes_limit"]) if stats else None
-    except Exception:
-        return None
+def _device_memory_bytes() -> Optional[int]:
+    """The smallest ``bytes_limit`` over this process's devices, or None
+    when the backend keeps no memory stats (the CPU client)."""
+    limits = [m["bytes_limit"] for m in device_memory()]
+    return min(limits) if limits else None
+
+
+def _checked_ring_layout(cfg: Config, action_dim: int, mesh) -> str:
+    """Resolve ``cfg.device_ring_layout`` for this bring-up and REFUSE a
+    ring that does not fit: the caller asked for the device-replay
+    drivetrain, and quietly running host replay under its name would
+    hide the device from every metric the run reports.  The budget is 80%
+    of the device's limit (headroom for params, activations and staged
+    slots)."""
+    from r2d2_tpu.replay.device_ring import device_bytes, resolve_layout
+    from r2d2_tpu.replay.replay_buffer import _available_host_bytes
+
+    need, dev_cap = device_bytes(cfg, action_dim), _device_memory_bytes()
+    # "auto" shards the slot axis over dp when the ring outgrows one
+    # device's HBM.  Only genuine per-device stats (dev_cap) may trigger
+    # auto-sharding or split the accounting: a backend without memory
+    # stats (the CPU client) keeps "device" memory in host RAM, where
+    # every shard shares one memory and the whole ring is the burden
+    layout = resolve_layout(cfg, mesh, need, dev_cap)
+    shards = (mesh.shape["dp"]
+              if layout == "dp" and dev_cap is not None else 1)
+    cap = dev_cap if dev_cap is not None else _available_host_bytes()
+    if cap is not None and need // shards > 0.8 * cap:
+        fits = (int(0.8 * cap * shards // (need // cfg.num_blocks))
+                * cfg.block_length)
+        raise ValueError(
+            f"device_replay ring needs {need // shards / 1e9:.2f} GB per "
+            f"device (layout={layout}, buffer_capacity="
+            f"{cfg.buffer_capacity}) but the device's limit is "
+            f"{cap / 1e9:.2f} GB and the ring may take 80% of it — "
+            f"buffer_capacity={fits} fits (or shard the ring over more "
+            "devices with --mesh)")
+    return layout
 
 
 class _HostScaffold:
@@ -662,7 +645,6 @@ def train_sync(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                       # re-unroll only to be discarded at harvest
                       learnhealth_interval=0)
     sys = _build(cfg, env_factory, use_mesh, checkpoint_dir, resume)
-    cfg = sys["cfg"]
     actor: VectorActor = sys["actor"]
     buffer: ReplayBuffer = sys["buffer"]
     learner: Learner = sys["learner"]
@@ -729,7 +711,7 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     four-method surface).
     """
     from r2d2_tpu.learner.anakin import AnakinPlane, run_anakin_loop
-    from r2d2_tpu.replay.device_ring import DeviceRing, resolve_layout
+    from r2d2_tpu.replay.device_ring import DeviceRing
 
     if cfg.game_name != "Fake":
         import warnings
@@ -766,13 +748,11 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     # single-device path is unchanged.
     mesh = make_mesh(cfg) if use_mesh else None
     table = None
+    layout = _checked_ring_layout(cfg, action_dim, mesh)
     if mesh is not None:
         from r2d2_tpu.parallel.sharding import ShardingTable
-        from r2d2_tpu.replay.replay_buffer import data_bytes
 
         table = ShardingTable(mesh, cfg)
-        layout = resolve_layout(cfg, mesh, data_bytes(cfg, action_dim),
-                                _device_memory_bytes())
         ring = DeviceRing(cfg, action_dim, table=table, layout=layout)
     else:
         ring = DeviceRing(cfg, action_dim)
@@ -950,6 +930,9 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
         metrics.update(buffer_size=plane.fill, logs=list(logs),
                        buffer_training_steps=plane.training_steps,
                        final_params=learner.state.params,
+                       # acting is in-graph: it ran where the loss did
+                       act_platform=jax.local_devices()[0].platform,
+                       device_memory=device_memory(),
                        restored_replay=restored_anakin,
                        learner_stalled=stall["stalled"],
                        trace=tracer.snapshot(), health=supervisor.health(),
@@ -1056,7 +1039,6 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                              tracer=tracer, profile_dir=profile_dir,
                              stop_fn=stop_fn)
     sys = _build(cfg, env_factory, use_mesh, checkpoint_dir, resume)
-    cfg = sys["cfg"]  # the EFFECTIVE config (degrade paths flip flags)
     actors: List[VectorActor] = sys["actors"]
     buffer: ReplayBuffer = sys["buffer"]
     learner: Learner = sys["learner"]
@@ -1547,9 +1529,26 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
             except Exception as e:  # never fail the run over snapshot I/O
                 log.warning("full-state replay snapshot failed: %s", e)
 
+        if actors:
+            # observed on the first act's output (None: no act ran)
+            act_platform = actors[0].act_platform
+        elif plane.service is not None:
+            act_platform = plane.service.act_device.platform
+        else:
+            act_platform = "cpu"  # local-inference fleets pin the CPU
+        from r2d2_tpu import native
+
         metrics.update(buffer_size=len(buffer), logs=list(logs),
                        buffer_training_steps=buffer.training_steps,
                        final_params=learner.state.params,
+                       # what actually ran, beside metrics["drivetrain"]
+                       # (stamped by the learner path that returned): the
+                       # platform actor inference executed on and the
+                       # host sum-tree implementation
+                       act_platform=act_platform,
+                       host_sum_tree=("native" if native.available()
+                                      else "numpy"),
+                       device_memory=device_memory(),
                        restored_replay=sys["restored_replay"],
                        learner_stalled=stall["stalled"],
                        trace=tracer.snapshot(), health=supervisor.health(),

@@ -42,10 +42,9 @@ class ParamStore:
 
         Consumers that need the snapshot on a specific backend — actor
         fleets pulling learner weights to the host CPU — would otherwise
-        each pay the same device→host transfer per refresh; on a tunneled
-        accelerator that is the whole parameter set across the wire per
-        fleet.  The transfer runs outside the lock so a slow interconnect
-        never blocks ``publish``/``get``; concurrent same-version callers
+        each pay the same device→host transfer per refresh.  The transfer
+        runs outside the lock so a slow interconnect never blocks
+        ``publish``/``get``; concurrent same-version callers
         may race the transfer (placing twice, last one cached) rather
         than serialise on it.
         """

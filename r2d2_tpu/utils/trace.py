@@ -406,3 +406,18 @@ def device_profile(log_dir: Optional[str]) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+def device_memory() -> list:
+    """Per local device, what the backend reports of its memory:
+    ``[{id, bytes_in_use, peak_bytes_in_use, bytes_limit}, ...]`` — how a
+    run shows where its ring, batch and lanes actually live (one device,
+    or spread over a mesh).  Empty on a backend that keeps no memory
+    stats (the CPU client)."""
+    import jax
+
+    return [dict(id=d.id, bytes_in_use=int(s["bytes_in_use"]),
+                 peak_bytes_in_use=int(s["peak_bytes_in_use"]),
+                 bytes_limit=int(s["bytes_limit"]))
+            for d, s in ((d, d.memory_stats()) for d in jax.local_devices())
+            if s]

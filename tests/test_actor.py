@@ -257,3 +257,47 @@ def test_seed_first_reset_wrapper():
     assert len(phases) > 1  # episodes vary after the seeded first reset
     # delegation still works
     assert a.action_space.n == 4
+
+
+def test_make_act_fn_resolves_net_for_the_given_device():
+    """One decision, one place: the act net is resolved FOR the device the
+    act will run on, and the returned callable carries that decision
+    (callers commit params to ``.device``; runs report ``.lstm_impl`` /
+    ``.compute_dtype``)."""
+    class FakeTpu:
+        platform = "tpu"
+
+    cfg = make_test_config(compute_dtype="bfloat16", lstm_impl="pallas")
+    net = create_network(cfg, A)
+    on_tpu = make_act_fn(cfg, net, device=FakeTpu())
+    assert (on_tpu.lstm_impl, on_tpu.compute_dtype) == ("pallas", "bfloat16")
+    cpu = jax.local_devices()[0]
+    on_cpu = make_act_fn(cfg, net, device=cpu)
+    assert on_cpu.device == cpu
+    assert (on_cpu.lstm_impl, on_cpu.compute_dtype) == ("scan", "float32")
+    # default: cfg.act_device through resolve_act_device
+    assert make_act_fn(cfg, net).device == cpu
+
+
+def test_resolve_act_device_refuses_a_missing_cpu_backend(monkeypatch):
+    """ISSUE 21 finding 7: "auto"/"cpu" on an accelerator process whose
+    JAX_PLATFORMS leaves the CPU backend out used to mean "leave placement
+    alone" — thread-actor inference silently moved onto the chip.  It is
+    an error that says so; "default" still names the first device."""
+    from r2d2_tpu.actor import resolve_act_device
+
+    class FakeTpu:
+        platform = "tpu"
+
+    tpu = FakeTpu()
+
+    def local_devices(backend=None):
+        if backend == "cpu":
+            raise RuntimeError("Unknown backend cpu")
+        return [tpu]
+
+    monkeypatch.setattr(jax, "local_devices", local_devices)
+    assert resolve_act_device("default") is tpu
+    for spec in ("auto", "cpu"):
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS"):
+            resolve_act_device(spec)

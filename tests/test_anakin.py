@@ -250,7 +250,9 @@ def test_anakin_blocks_match_local_buffer_oracle(mode):
     for slot, (blk, pri, _ep) in enumerate(host_blocks):
         n_obs, n_steps = blk.obs.shape[0], blk.action.shape[0]
         k = blk.num_sequences
-        np.testing.assert_array_equal(blk.obs, arrays["obs"][slot][:n_obs])
+        # the device ring stores frames as flat byte rows
+        np.testing.assert_array_equal(blk.obs.reshape(n_obs, -1),
+                                      arrays["obs"][slot][:n_obs])
         np.testing.assert_array_equal(blk.last_action,
                                       arrays["last_action"][slot][:n_obs])
         np.testing.assert_array_equal(blk.last_reward,

@@ -160,44 +160,45 @@ def test_checkpoint_arch_compat_guard(tmp_path):
         check_arch_compat(s2d, ck.peek_meta())
 
 
-def test_compile_cache_enable_and_disable(tmp_path, monkeypatch):
-    """compile_cache.enable honors the path arg and the off switch, and
-    actually points jax at the directory (warm-start machinery)."""
+def test_compile_cache_placement(tmp_path, monkeypatch):
+    """compile_cache.enable places the cache by one rule: a CPU-pinned
+    process gets none; with JAX_COMPILATION_CACHE_DIR set the code sets
+    no directory (JAX honours the variable itself); unset, the directory
+    is the one fixed in-checkout path."""
     import os
 
     import jax
 
     from r2d2_tpu.utils import compile_cache
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.CACHE_ROOT == os.path.join(repo, ".jax_cache")
+
     # jax.config mutations outlive monkeypatch: restore them explicitly,
-    # on failure paths too (a leaked deleted tmp dir would cascade
-    # cache-write noise into every later test)
+    # on failure paths too (a leaked cache dir would turn the XLA:CPU
+    # cache on for every later test)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        d = str(tmp_path / "xla")
-        monkeypatch.delenv("R2D2_COMPILE_CACHE", raising=False)
-        # explicitly-CPU-pinned processes (this test session) must NOT
-        # enable the cache by default: XLA:CPU AOT reloads can mismatch
-        # host machine features (measured ~30x act-fn degradation +
-        # SIGILL risk)
+        # explicitly-CPU-pinned processes (this test session) get no
+        # cache: XLA:CPU AOT reloads can mismatch host machine features
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.enable() is None
-        # ...but an explicit path is an opt-in that bypasses the gate
-        assert compile_cache.enable(d) == d
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
+        assert jax.config.jax_compilation_cache_dir == prev_dir
 
-        monkeypatch.setenv("R2D2_COMPILE_CACHE", "0")
-        assert compile_cache.enable(force=True) is None
+        monkeypatch.setattr(compile_cache, "_configured_platform",
+                            lambda: "tpu")
+        # env set: reported, but nothing assigned in code
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev_dir
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
-        # a non-off env value is also an explicit opt-in on CPU
-        monkeypatch.setenv("R2D2_COMPILE_CACHE", str(tmp_path / "env_xla"))
-        assert compile_cache.enable() == str(tmp_path / "env_xla")
-
-        # explicit path wins even over the env off-switch (documented
-        # precedence)
-        monkeypatch.setenv("R2D2_COMPILE_CACHE", "0")
-        assert compile_cache.enable(d) == d
+        # env unset: the fixed in-checkout path, created and assigned
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == compile_cache.CACHE_ROOT
+        assert os.path.isdir(compile_cache.CACHE_ROOT)
+        assert (jax.config.jax_compilation_cache_dir
+                == compile_cache.CACHE_ROOT)
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",

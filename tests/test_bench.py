@@ -1,6 +1,7 @@
-"""bench.py failure isolation: the headline learner metric must survive a
-crash in the actor/system phases (the driver records the one JSON line as
-the round artifact — a late-phase crash must not zero it)."""
+"""bench.py failure reporting: the headline learner metric still lands in
+the one JSON line when a later phase crashes — but ANY phase error, a
+CPU-only host, or a device with no published peak makes the exit code
+non-zero, on both entry paths (ISSUE 21 B3)."""
 import json
 import sys
 
@@ -8,14 +9,18 @@ import pytest
 
 import numpy as np
 
+V5E = dict(platform="tpu", device_kind="TPU v5 lite", device_count=1)
 
-def test_bench_main_survives_actor_and_system_crash(monkeypatch, capsys):
+
+def test_bench_main_records_phase_crashes_and_exits_nonzero(monkeypatch,
+                                                            capsys):
     from r2d2_tpu import bench
 
     # the real probe would spawn a subprocess against the default backend
-    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (True, ""))
+    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (V5E, ""))
     monkeypatch.setattr(bench, "_learner_micro_bench",
-                        lambda steps, warmup: (123456.0, 42.0, 1e9))
+                        lambda steps, warmup, fused=False:
+                        (123456.0, 42.0, 1e9))
 
     def boom(*a, **k):
         raise RuntimeError("injected bench fault")
@@ -23,7 +28,9 @@ def test_bench_main_survives_actor_and_system_crash(monkeypatch, capsys):
     monkeypatch.setattr(bench, "_actor_plane_bench", boom)
     monkeypatch.setattr(bench, "_system_bench", boom)
 
-    bench.main(steps=1, warmup=0, system_seconds=0.1)
+    with pytest.raises(SystemExit) as ex:
+        bench.main(steps=1, warmup=0, system_seconds=0.1)
+    assert ex.value.code == 1
     out = capsys.readouterr().out.strip().splitlines()
     result = json.loads(out[0])
     assert result["metric"] == "learner_env_frames_per_sec"
@@ -31,81 +38,120 @@ def test_bench_main_survives_actor_and_system_crash(monkeypatch, capsys):
     assert result["vs_baseline"] == round(123456.0 / bench.NORTH_STAR_FPS, 3)
     assert result["actor_env_frames_per_sec"] == -1.0
     assert result["system_env_frames_per_sec"] == -1.0
+    assert result["system_vs_baseline"] == -1.0
+    assert set(result["phase_errors"]) == {"actor", "system",
+                                           "system_ingraph"}
+    assert "injected bench fault" in result["phase_errors"]["actor"]
 
 
-def test_bench_json_line_is_first_stdout_line(monkeypatch, capsys):
-    """The driver parses stdout for ONE JSON line; nothing may precede it."""
+def test_bench_json_line_is_first_stdout_line_and_names_device(monkeypatch,
+                                                               capsys):
+    """The driver parses stdout for ONE JSON line; nothing may precede it,
+    and it names the device the numbers came from."""
     from r2d2_tpu import bench
 
-    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (True, ""))
+    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (V5E, ""))
     monkeypatch.setattr(bench, "_learner_micro_bench",
-                        lambda steps, warmup: (50000.0, 10.0, 0.0))
+                        lambda steps, warmup, fused=False:
+                        (50000.0, 10.0, 0.0))
     monkeypatch.setattr(bench, "_actor_plane_bench", lambda: 1.0)
     monkeypatch.setattr(bench, "_system_bench",
                         lambda s, **kw: (2.0, {}, 3))
-    bench.main(steps=1, warmup=0, system_seconds=0.1)
+    bench.main(steps=1, warmup=0, system_seconds=0.1)   # clean: returns
     lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
     parsed = json.loads(lines[0])
     assert parsed["vs_baseline"] == 1.0
     assert np.isclose(parsed["system_env_frames_per_sec"], 2.0)
+    assert parsed["device"] == V5E
+    assert "phase_errors" not in parsed
 
 
-def test_bench_reports_unreachable_device_as_artifact(monkeypatch, capsys):
-    """A wedged accelerator backend must yield a parseable JSON line (and
-    a nonzero exit) rather than an indefinite hang with no artifact."""
-    import pytest
+def test_bench_refuses_a_missing_accelerator(monkeypatch, capsys):
+    """No accelerator must yield a parseable JSON line saying so and a
+    nonzero exit — on both entry paths, with no phase run."""
+    from r2d2_tpu import bench
+
+    monkeypatch.setattr(
+        bench, "_device_probe",
+        lambda *a, **k: (None, "JAX found no accelerator (platform 'cpu')"))
+    monkeypatch.setattr(bench, "_run_phase", None)           # never called
+    monkeypatch.setattr(bench, "_learner_micro_bench", None)
+    for entry in (lambda: bench.main(steps=1, warmup=0, system_seconds=0.1),
+                  lambda: bench._main_isolated(1, 0, 0.1)):
+        with pytest.raises(SystemExit) as ex:
+            entry()
+        assert ex.value.code == 1
+        result = json.loads(capsys.readouterr().out.strip().splitlines()[0])
+        assert result["value"] == -1.0
+        assert "platform 'cpu'" in result["error"]
+
+
+def test_device_probe_requires_a_known_accelerator(monkeypatch):
+    """The probe's verdicts: a CPU is not a device to benchmark (JAX falls
+    through to it when the accelerator fails to initialise), and a
+    device_kind missing from the peak table is an error, not MFU 0."""
+    import subprocess
 
     from r2d2_tpu import bench
 
-    monkeypatch.setattr(bench, "_device_probe",
-                    lambda *a, **k: (False, "probe timed out"))
-    with pytest.raises(SystemExit) as ex:
-        bench.main(steps=1, warmup=0, system_seconds=0.1)
-    assert ex.value.code == 1
-    out = capsys.readouterr().out.strip().splitlines()
-    result = json.loads(out[0])
-    assert result["value"] == -1.0
-    assert "unreachable" in result["error"]
+    def probe_seeing(facts, rc=0):
+        monkeypatch.setattr(
+            subprocess, "run",
+            lambda *a, **k: subprocess.CompletedProcess(
+                a, rc, stdout=json.dumps(facts).encode() + b"\n",
+                stderr=b"boom\n"))
+        return bench._device_probe()
+
+    assert probe_seeing(V5E) == (V5E, "")
+    dev, why = probe_seeing(dict(platform="cpu", device_kind="cpu",
+                                 device_count=8))
+    assert dev is None and "no accelerator" in why
+    dev, why = probe_seeing(dict(V5E, device_kind="TPU v9 mystery"))
+    assert dev is None and "_PEAK_TFLOPS" in why
+    dev, why = probe_seeing(V5E, rc=3)
+    assert dev is None and "rc=3" in why and "boom" in why
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        bench._peak_tflops("TPU v9 mystery")
 
 
 def test_isolated_bench_composes_phase_results(monkeypatch, capsys):
-    """Script-mode bench (phase-per-subprocess): a wedged system phase
-    must surface as -1 + phase_errors while the already-banked micro
-    headline survives, matching the in-process failure isolation."""
+    """Script-mode bench (phase-per-subprocess): a hung system phase
+    surfaces as -1 + phase_errors while the already-banked micro headline
+    survives in the JSON — and the exit code is non-zero."""
     from r2d2_tpu import bench
 
-    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (True, ""))
+    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (V5E, ""))
 
     def fake_run_phase(phase, timeout_s, extra=(), label=None):
         if phase == "micro":
             return (dict(learner_fps=100000.0, steps_per_sec=40.0,
-                         flops=2e9, platform="tpu",
-                         device_kind="TPU v5 lite"), "")
+                         flops=2e9, **V5E), "")
         if phase == "system":
-            return None, "system phase wedged (no result after 975s; " \
+            return None, "system phase hung (no result after 975s; " \
                          "child killed)"
-        return dict(actor_fps=2400.0), ""
+        return dict(actor_fps=2400.0, **V5E), ""
 
     monkeypatch.setattr(bench, "_run_phase", fake_run_phase)
-    bench._main_isolated(steps=1, warmup=0, system_seconds=0.1)
+    with pytest.raises(SystemExit) as ex:
+        bench._main_isolated(steps=1, warmup=0, system_seconds=0.1)
+    assert ex.value.code == 1
     out = capsys.readouterr().out.strip().splitlines()
     result = json.loads(out[0])
     assert result["value"] == 100000.0
+    assert result["device"] == V5E
     assert result["system_env_frames_per_sec"] == -1.0
-    assert "wedged" in result["phase_errors"]["system"]
+    assert "hung" in result["phase_errors"]["system"]
     assert result["actor_env_frames_per_sec"] == 2400.0
-    # MFU from the micro child's flops + device kind (v5e peak 197)
+    # MFU from the micro child's flops + the probed device kind (v5e 197)
     assert result["mfu"] == round(2e9 * 40.0 / 1e12 / 197.0, 4)
 
 
-def test_isolated_bench_headline_failure_exits_nonzero(monkeypatch, capsys):
+def test_isolated_bench_all_phases_failing(monkeypatch, capsys):
     from r2d2_tpu import bench
 
-    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (True, ""))
+    monkeypatch.setattr(bench, "_device_probe", lambda *a, **k: (V5E, ""))
     monkeypatch.setattr(bench, "_run_phase",
                         lambda phase, t, extra=(), label=None: (None, f"{label or phase} died"))
-    import pytest
-
     with pytest.raises(SystemExit) as ex:
         bench._main_isolated(steps=1, warmup=0, system_seconds=0.1)
     assert ex.value.code == 1

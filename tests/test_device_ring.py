@@ -16,8 +16,12 @@ from r2d2_tpu.models.network import create_network, init_params
 from r2d2_tpu.parallel.mesh import trivial_mesh
 from r2d2_tpu.parallel.sharding import (
     ShardingTable, pjit_super_step, pjit_train_step)
-from r2d2_tpu.replay.device_ring import DeviceRing, gather_batch
-from r2d2_tpu.replay.replay_buffer import ReplayBuffer, data_bytes
+from r2d2_tpu.replay.device_ring import (
+    DeviceRing,
+    device_bytes,
+    gather_batch,
+)
+from r2d2_tpu.replay.replay_buffer import ReplayBuffer
 from r2d2_tpu.replay.block import LocalBuffer
 
 A = 4
@@ -67,10 +71,15 @@ def paired_buffers(cfg, n_blocks=4, seed=0):
     return host, dev, ring
 
 
-def test_data_bytes_matches_ring_allocation():
+def test_device_bytes_matches_ring_allocation():
+    """The capacity guard budgets exactly what the ring allocates —
+    including the frame-row axis padded to whole u8 tiles."""
     cfg = make_cfg()
     ring = DeviceRing(cfg, A)
-    assert ring.nbytes() == data_bytes(cfg, A)
+    assert ring.nbytes() == device_bytes(cfg, A)
+    rows, width = ring.arrays["obs"].shape[1:]
+    assert rows % 32 == 0 and rows >= cfg.max_block_steps
+    assert width == int(np.prod(cfg.stored_obs_shape))
 
 
 def test_device_gather_matches_host_sample_batch():
@@ -500,31 +509,40 @@ def test_train_end_to_end_device_replay_dp_layout():
     assert not metrics["fabric_failed"]
 
 
-@pytest.mark.slow
-def test_device_replay_falls_back_to_host_when_ring_too_big(monkeypatch):
-    """The capacity guard must degrade to host replay with a warning, not
-    crash or silently OOM, when the ring exceeds the device budget."""
-    import sys
-    import warnings
+def test_device_replay_ring_too_big_is_an_error(monkeypatch):
+    """A device_replay ring that does not fit the device must stop the
+    bring-up with an error naming the ring's bytes, the device's limit
+    and a buffer_capacity that fits — never warn and run host replay
+    under the device drivetrain's name (ISSUE 21 finding 1)."""
+    import importlib
+    import re
 
-    import r2d2_tpu.train  # noqa: F401 — ensure the module is loaded
-    train_mod = sys.modules["r2d2_tpu.train"]
+    # (the package re-exports train() over the submodule attribute)
+    train_mod = importlib.import_module("r2d2_tpu.train")
 
-    monkeypatch.setattr(train_mod, "_device_memory_bytes", lambda: 1024)
-    cfg = make_cfg(game_name="Fake", device_replay=True, superstep_k=2,
-                   training_steps=4, log_interval=0.2)
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        metrics = train_mod.train(
-            cfg,
-            env_factory=lambda c, seed: FakeAtariEnv(
-                obs_shape=c.stored_obs_shape, action_dim=A, seed=seed),
-            verbose=False)
-    assert any("falling back to host replay" in str(w.message)
-               for w in caught)
-    assert metrics["num_updates"] >= cfg.training_steps
-    assert np.isfinite(metrics["mean_loss"])
-    assert not metrics["fabric_failed"]
+    cfg = make_cfg(game_name="Fake", device_replay=True, in_graph_per=True)
+    need = device_bytes(cfg, A)
+    limit = need  # the ring may take 80% of the limit: this is too small
+    monkeypatch.setattr(train_mod, "_device_memory_bytes", lambda: limit)
+    factory = lambda c, seed: FakeAtariEnv(  # noqa: E731
+        obs_shape=c.stored_obs_shape, action_dim=A, seed=seed)
+    with pytest.raises(ValueError, match="buffer_capacity=") as ex:
+        train_mod._build(cfg, factory, False, None, False)
+    msg = str(ex.value)
+    assert f"{need / 1e9:.2f} GB" in msg and f"{limit / 1e9:.2f} GB" in msg
+    # the capacity it names really passes the same guard, and is the
+    # largest whole-block capacity that does
+    fits = int(re.search(r"buffer_capacity=(\d+) fits", msg).group(1))
+    assert 0 < fits < cfg.buffer_capacity and fits % cfg.block_length == 0
+    assert train_mod._checked_ring_layout(
+        cfg.replace(buffer_capacity=fits), A, None) == "replicated"
+    with pytest.raises(ValueError):
+        train_mod._checked_ring_layout(
+            cfg.replace(buffer_capacity=fits + cfg.block_length), A, None)
+    # the anakin trainer shares the guard
+    with pytest.raises(ValueError, match="buffer_capacity="):
+        train_mod.train(cfg.replace(actor_transport="anakin"),
+                        verbose=False)
 
 
 def test_run_device_cadences_and_drain(tmp_path):

@@ -531,55 +531,6 @@ def test_in_graph_per_without_ring_fails_fast():
                      device_ring=None)
 
 
-def test_train_degrades_in_graph_per_without_ring(monkeypatch):
-    """The flagship presets default in_graph_per=True; on a host whose
-    device budget rejects the ring, train() must warn and continue on
-    host-sampled PER (the reference's behavior is host replay, never a
-    crash).  Forced here by making every ring look too big.
-
-    Regression (ADVICE r5 high): _build used to flip in_graph_per only on
-    its LOCAL cfg, so train() still stripped the priority thread while
-    the learner took the host-sampled path — after ~8 updates (the
-    priority queue depth) the undrained queue wedged the learner forever.
-    training_steps=16 runs past that depth plus the superstep pipeline,
-    and the host tree must carry real priority mass with the feedback
-    counter fully applied, so the wedge can never regress silently."""
-    import importlib
-    import warnings
-
-    train_mod = importlib.import_module("r2d2_tpu.train")
-
-    built = {}
-
-    class SpyBuffer(ReplayBuffer):
-        def __init__(self, *a, **kw):
-            super().__init__(*a, **kw)
-            built["buffer"] = self
-
-    monkeypatch.setattr(train_mod, "_device_memory_bytes", lambda: 1)
-    monkeypatch.setattr(train_mod, "ReplayBuffer", SpyBuffer)
-    cfg = make_cfg(game_name="Fake", superstep_k=2, training_steps=16,
-                   log_interval=0.2)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        metrics = train_mod.train(
-            cfg,
-            env_factory=lambda c, seed: FakeAtariEnv(
-                obs_shape=c.stored_obs_shape, action_dim=A, seed=seed),
-            verbose=False)
-    assert any("in_graph_per disabled" in str(x.message) for x in w)
-    assert metrics["num_updates"] >= cfg.training_steps
-    assert np.isfinite(metrics["mean_loss"])
-    assert not metrics["fabric_failed"]
-    # the degraded run's PER plane is the HOST tree: actor-side priorities
-    # landed in it (mass > 0 — in_graph mode keeps it exactly empty), and
-    # every learner update's feedback came back through the priority
-    # thread (the path the stripped-thread wedge starved)
-    buf = built["buffer"]
-    assert buf.tree.total > 0.0
-    assert metrics["buffer_training_steps"] == metrics["num_updates"] >= 16
-
-
 def test_train_sync_accepts_in_graph_preset():
     """train_sync force-disables device_replay; it must drop in_graph_per
     with it (the pair is validated together) so the deterministic

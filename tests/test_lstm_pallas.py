@@ -132,7 +132,7 @@ def test_pallas_unroll_is_not_differentiable(inputs):
 def test_act_fn_uses_scan_twin_off_tpu():
     """Regression: on a TPU default backend the learner's network resolves
     impl=pallas, but actor inference jits onto the host CPU backend
-    (actor.py:_resolve_act_device) where compiled pallas cannot lower
+    (actor.py:resolve_act_device) where compiled pallas cannot lower
     ("Only interpret mode is supported on CPU backend").  make_act_fn must
     therefore build a scan-impl twin whenever the resolved act device is
     not a TPU — reproduced here with an explicit impl=pallas config and

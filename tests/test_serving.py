@@ -274,6 +274,42 @@ def test_batcher_bucket_padding_bit_exact_and_retrace_budget():
     RETRACES.assert_within_budgets()
 
 
+def test_batcher_acts_where_its_params_live(monkeypatch):
+    """ISSUE 21 finding 2: the session tier must resolve its act net for
+    the device its params are committed to.  The training actors' knob
+    (``cfg.act_device``: "auto" = a host-CPU twin) is never consulted —
+    the old code built that CPU twin and then ran it on the accelerator
+    the params were committed to."""
+    import r2d2_tpu.actor as actor_mod
+
+    home = jax.local_devices()[3]   # "the first local device", movable
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [home])
+
+    def never(spec):
+        raise AssertionError("the session tier consulted cfg.act_device")
+
+    monkeypatch.setattr(actor_mod, "resolve_act_device", never)
+    for spec in ("auto", "cpu", "default"):
+        cfg = _cfg(act_device=spec)
+        _, params = _net_params(cfg)
+        b = ContinuousBatcher(cfg, A)
+        assert b._act.device == home
+        assert b.act_info()["act_platform"] is None   # nothing acted yet
+        b.publish(jax.device_get(params))             # a host tree
+        assert {d for leaf in jax.tree.leaves(b._params)
+                for d in leaf.devices()} == {home}
+        n = 3
+        q, h = b.act(np.zeros((n, *cfg.stored_obs_shape), np.uint8),
+                     np.zeros((n, A), np.float32), np.zeros(n, np.float32),
+                     np.zeros((n, 2, cfg.lstm_layers, cfg.hidden_dim),
+                              np.float32))
+        assert q.shape == (n, A) and np.isfinite(q).all()
+        # observed from the output array, and the net resolved for it
+        assert b.act_info() == dict(act_platform=home.platform,
+                                    act_lstm_impl="scan",
+                                    act_compute_dtype=cfg.compute_dtype)
+
+
 def test_batcher_act_under_armed_transfer_guard():
     """The serve path's declared-transfer contract, JAX-enforced (r19):
     after warm-up, ``act()`` runs inside ``disallow("serving.act")`` —

@@ -14,15 +14,14 @@ The learnhealth plane's contract is "free when off, quantified when on":
 Cells (each a fresh subprocess so XLA state never leaks across sides,
 interleaved base/PR/base/PR so host-load drift hits both sides):
 
-- ``pjit``   — the unified pjit train step (tools/pjit_bench.py's BASE
-  geometry), median ms/step over fenced reps;
+- ``pjit``   — the unified pjit train step (a B=64 MLP geometry), median
+  ms/step over fenced reps;
 - ``anakin`` — the fused on-device super-step, updates/s.
 
-Outputs (BENCH_r05 / TRACE_r11 conventions):
-``artifacts/r14/LEARNHEALTH_AB_r14.json`` (cells + medians + ratios),
-``artifacts/r14/PROBE_r14.json`` (the accelerator probe, recorded
-either way — if a chip were reachable the deferred real-chip
-pjit/replay/anakin cells run first, per the standing side-quest).
+Output: ``artifacts/r14/LEARNHEALTH_AB_r14.json`` (cells + medians +
+ratios).  The cells run on whatever platform the environment selects
+(``JAX_PLATFORMS``) and every cell names it; the recorded r14 numbers
+are CPU-host timings, not device numbers.
 
 Run from the repo root with the PR in the working tree and the pre-PR
 commit at HEAD:  ``python tools/learnhealth_ab.py [--reps N]``
@@ -38,38 +37,6 @@ import tempfile
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 OUT = os.path.join(REPO, "artifacts/r14/LEARNHEALTH_AB_r14.json")
-PROBE = os.path.join(REPO, "artifacts/r14/PROBE_r14.json")
-
-
-def probe_accelerator() -> dict:
-    """Bounded probe for a non-CPU backend (BENCH_r05 convention):
-    one subprocess attempt with a hard timeout, recorded either way."""
-    now = datetime.datetime.now().strftime("%Y-%m-%d %H:%M:%S")
-    code = ("import jax,json;"
-            "print(json.dumps([d.platform for d in jax.devices()]))")
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    try:
-        p = subprocess.run([sys.executable, "-c", code], timeout=60,
-                           capture_output=True, text=True, env=env)
-        platforms = (json.loads(p.stdout.strip() or "[]")
-                     if p.returncode == 0 else [])
-    except (subprocess.TimeoutExpired, json.JSONDecodeError):
-        platforms = []
-    reachable = any(pl != "cpu" for pl in platforms)
-    if reachable:
-        note = ("accelerator visible — run tools/pjit_bench.py, "
-                "tools/replay_bench.py and the anakin cells on it FIRST "
-                "(the standing side-quest), then these A/B cells")
-    elif platforms:
-        note = ("only CPU platforms visible — the A/B ran host-side; "
-                "real-chip cells remain the standing side-quest "
-                "(BENCH_r05)")
-    else:
-        note = ("backend probe failed to initialise any platform "
-                "(timed out or errored); A/B ran host-side, real-chip "
-                "cells remain the standing side-quest (BENCH_r05)")
-    return dict(probed_at=now, platforms=platforms,
-                accelerator_reachable=reachable, note=note)
 
 
 # one cell per subprocess.  argv: <kind> <interval>  (interval "-1" =
@@ -77,7 +44,6 @@ def probe_accelerator() -> dict:
 # script only touches APIs both trees share.
 _CELL_SRC = r"""
 import json, os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, numpy as np, jax.numpy as jnp
 kind, interval = sys.argv[1], int(sys.argv[2])
 from r2d2_tpu.config import test_config
@@ -118,6 +84,7 @@ if kind == "pjit":
         times.append(time.perf_counter() - t0)
     ms = float(np.median(times)) * 1000
     print(json.dumps(dict(kind=kind, interval=interval,
+                          platform=jax.devices()[0].platform,
                           step_ms=round(ms, 3),
                           steps_per_sec=round(1000.0 / ms, 2))))
 else:
@@ -159,13 +126,14 @@ else:
     dt = time.perf_counter() - t0
     ups = n_disp * cfg.superstep_k / dt
     print(json.dumps(dict(kind=kind, interval=interval,
+                          platform=jax.devices()[0].platform,
                           updates_per_sec=round(ups, 2),
                           dispatch_ms=round(dt / n_disp * 1000, 2))))
 """
 
 
 def run_cell(tree: str, kind: str, interval: int) -> dict:
-    env = dict(os.environ, PYTHONPATH=tree, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=tree)
     p = subprocess.run([sys.executable, "-c", _CELL_SRC, kind,
                        str(interval)], cwd=tree, env=env, timeout=900,
                        capture_output=True, text=True)
@@ -183,11 +151,6 @@ def main() -> int:
     if "--reps" in sys.argv:
         reps = int(sys.argv[sys.argv.index("--reps") + 1])
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
-    probe = probe_accelerator()
-    with open(PROBE, "w") as f:
-        json.dump(probe, f, indent=1)
-    print(f"probe: {probe['note']}", flush=True)
-
     with tempfile.TemporaryDirectory(prefix="lh_base_") as base_tree:
         subprocess.run(["git", "worktree", "add", "--detach",
                         base_tree, "HEAD"], cwd=REPO, check=True,
@@ -221,7 +184,7 @@ def main() -> int:
     summary = dict(
         generated_at=datetime.datetime.now().strftime(
             "%Y-%m-%d %H:%M:%S"),
-        host_cpus=os.cpu_count(), reps=reps, probe=probe,
+        host_cpus=os.cpu_count(), reps=reps,
         cells=cells,
         medians=dict(
             pjit_ms={lbl: med(f"pjit.{lbl}", "step_ms")
@@ -256,7 +219,7 @@ def main() -> int:
         json.dump(summary, f, indent=1)
     print(json.dumps(dict(medians=summary["medians"],
                           ratios=summary["ratios"]), indent=1))
-    print(f"wrote {OUT} and {PROBE}", flush=True)
+    print(f"wrote {OUT}", flush=True)
     return 0
 
 

@@ -15,9 +15,9 @@ Run on the TPU host:
     python tools/tune_system.py [seconds_per_cell] [--short]
         [--out OUT.json] [--slack SECONDS]
 
-``--short`` sweeps only SHORT_GRID (the three decisive cells — bounded
-enough for a recovery watcher); ``--slack`` sets the per-cell subprocess
-timeout slack beyond the measurement wall.
+``--short`` sweeps only SHORT_GRID (the three decisive cells);
+``--slack`` sets the per-cell subprocess timeout slack beyond the
+measurement wall.
 """
 import json
 import os
@@ -48,17 +48,12 @@ SHORT_GRID = [GRID[0], GRID[1], GRID[5]]
 
 def main(seconds: float = 60.0, grid=None,
          out: str = "tune_system_results.json",
-         cell_timeout_slack: float = 900.0, inproc: bool = False) -> None:
-    """Each cell runs as a bounded subprocess via the bench phase CLI: a
-    cell wedged in an uninterruptible device call (observed round 4 —
-    k=16 sat >20 min at zero CPU and froze the whole in-process sweep)
-    costs ``seconds + cell_timeout_slack``, not the sweep.
-
-    ``inproc=True`` keeps the old same-process cells — required when the
-    caller already holds the (exclusive) chip claim, e.g. the
-    measure_tpu.py battery after its in-process micro bench; a subprocess
-    cell would deadlock against the parent's claim until timeout."""
-    from r2d2_tpu.bench import _run_phase, _system_bench
+         cell_timeout_slack: float = 900.0) -> None:
+    """Each cell runs as a bounded subprocess via the bench phase CLI (one
+    process holds the chip at a time; this parent never touches JAX): a
+    cell hung in an uninterruptible device call costs
+    ``seconds + cell_timeout_slack``, not the sweep."""
+    from r2d2_tpu.bench import _run_phase
 
     print(f"{'replay':>7} {'k':>3} {'actors':>6} {'workers':>7} {'pipe':>4} "
           f"{'frames/s':>12} {'updates':>8}  busiest_span")
@@ -69,28 +64,20 @@ def main(seconds: float = 60.0, grid=None,
         knobs = dict(device_replay=device_replay, superstep_k=k,
                      num_actors=actors, env_workers=workers,
                      superstep_pipeline=pipe, in_graph_per=in_graph)
-        if inproc:
-            try:
-                fps, top_spans, updates = _system_bench(seconds, **knobs)
-            except Exception as e:
-                res, err = None, f"{type(e).__name__}: {e}"
-            else:
-                res, err = True, ""
-        else:
-            res, err = _run_phase(
-                "system", seconds + cell_timeout_slack,
-                ("--seconds", seconds, "--knobs", json.dumps(knobs)))
-            if res is not None:
-                fps, top_spans, updates = (res["system_fps"],
-                                           res["top_spans"],
-                                           res["updates"])
+        res, err = _run_phase(
+            "system", seconds + cell_timeout_slack,
+            ("--seconds", seconds, "--knobs", json.dumps(knobs)))
         if res is None:  # keep sweeping; report the failure
             print(f"{'dev' if device_replay else 'host':>7} {k:>3} "
                   f"{actors:>6} {workers:>7} {pipe:>4} {'FAILED':>12} "
                   f"{err}")
             continue
+        fps, top_spans, updates = (res["system_fps"], res["top_spans"],
+                                   res["updates"])
         top = next(iter(top_spans), "-")
-        results.append(dict(device_replay=device_replay, superstep_k=k,
+        results.append(dict(platform=res["platform"],
+                            device_kind=res["device_kind"],
+                            device_replay=device_replay, superstep_k=k,
                             num_actors=actors, env_workers=workers,
                             superstep_pipeline=pipe, in_graph_per=in_graph,
                             frames_per_sec=round(fps, 1), updates=updates,
