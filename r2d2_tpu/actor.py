@@ -44,6 +44,7 @@ from r2d2_tpu.models.network import R2D2Network
 from r2d2_tpu.replay.block import Block, VectorLocalBuffer
 from r2d2_tpu.telemetry.tracing import EVENTS
 from r2d2_tpu.utils.store import ParamStore
+from r2d2_tpu.utils.trace import maybe_span
 
 # sink(block, priorities, episode_reward_or_None) — direct buffer.add in the
 # single-process trainer, queue.put in the process fabric.
@@ -192,14 +193,21 @@ class VectorActor:
     ``epsilons`` gives each lane its ladder ε; lanes run independent
     episode lifecycles (reset, block cut, episode-step cap) exactly as N
     reference actors would (worker.py:516-561).
+
+    ``tracer`` (utils/trace.Tracer, optional) splits a lockstep step into
+    spans: ``actor.act`` (the batched act call to its fetched outputs),
+    ``actor.env_step`` (the env pool), ``actor.record`` (the vectorised
+    bookkeeping) and, once per finished block, ``actor.cut`` (block
+    assembly and the sink, i.e. the buffer's ``add``).
     """
 
     def __init__(self, cfg: Config, envs: Sequence[Any],
                  epsilons: Sequence[float], act_fn, param_store: ParamStore,
                  sink: BlockSink, rng: Optional[np.random.Generator] = None,
-                 env_workers: Optional[int] = None):
+                 env_workers: Optional[int] = None, tracer=None):
         assert len(envs) == len(epsilons)
         self.cfg = cfg
+        self.tracer = tracer
         self.envs = list(envs)
         self.epsilons = np.asarray(epsilons, np.float64)
         self.act_fn = act_fn
@@ -372,6 +380,21 @@ class VectorActor:
                             flow=block.trace_id, fph="s", arg=i)
         self._block_start[i] = now
 
+    def _cut(self, i: int, bootstrap_q: Optional[np.ndarray],
+             reset: bool) -> None:
+        """Finish lane ``i``'s block and hand it to the sink.  ``reset``
+        (episode end, step cap) starts the lane's next episode BEFORE the
+        sink call: the finished Block owns copies, never vbuf storage,
+        and a sink that unwinds mid-delivery (FleetStopped during
+        shutdown) must leave the lane consistent for the shutdown
+        snapshot."""
+        with maybe_span(self.tracer, "actor.cut"):
+            item = self.vbuf.finish(i, bootstrap_q)
+            self._note_cut(i, item[0])
+            if reset:
+                self._reset_lane(i)
+            self.sink(*item)
+
     def _step_shard(self, lanes: range, actions: np.ndarray) -> None:
         """Env-step a contiguous lane shard (the only per-lane Python left
         in the hot loop — the gym API is per-env; ALE releases the GIL in
@@ -401,30 +424,28 @@ class VectorActor:
         assert self._params is not None or self._act_client is not None, \
             "ParamStore must hold initial params"
 
+        tr = self.tracer
         for _ in range(max_steps):
             if stop is not None and stop():
                 return
-            q, new_hidden = self.act_fn(self._params, self.obs,
-                                        self.last_action, self.last_reward,
-                                        self.hidden)
-            if self.act_platform is None and self._act_client is None:
-                self.act_platform = next(iter(q.devices())).platform
-            q = np.asarray(q)
-            new_hidden = np.asarray(new_hidden)
+            with maybe_span(tr, "actor.act"):
+                q, new_hidden = self.act_fn(self._params, self.obs,
+                                            self.last_action,
+                                            self.last_reward, self.hidden)
+                if self.act_platform is None and self._act_client is None:
+                    self.act_platform = next(iter(q.devices())).platform
+                q = np.asarray(q)
+                new_hidden = np.asarray(new_hidden)
 
             # deferred block-boundary cuts: this iteration's Q at the new
             # state is the bootstrap value (worker.py:550-554 semantics,
             # without the second forward)
             for i in np.nonzero(self.finish_pending)[0]:
-                # clear BEFORE the sink call: a sink that unwinds mid-
-                # delivery (FleetStopped during shutdown) must leave the
-                # lane consistent — vbuf already finished, flag cleared —
-                # or a snapshot taken now would re-finish an empty lane
-                # at resume
+                # clear BEFORE the sink call, for the same reason _cut
+                # resets before it: vbuf already finished and the flag
+                # still set would re-finish an empty lane at resume
                 self.finish_pending[i] = False
-                item = self.vbuf.finish(i, q[i])
-                self._note_cut(i, item[0])
-                self.sink(*item)
+                self._cut(i, q[i], reset=False)
 
             explore = self.rng.random(self.N) < self.epsilons
             actions = np.where(explore,
@@ -432,35 +453,30 @@ class VectorActor:
                                q.argmax(axis=1)).astype(np.int64)
 
             # env stepping: per-lane (gym API), possibly pooled
-            if self._pool is None:
-                self._step_shard(self._shards[0], actions)
-            else:
-                futures = [self._pool.submit(self._step_shard, shard, actions)
-                           for shard in self._shards]
-                for f in futures:
-                    f.result()
+            with maybe_span(tr, "actor.env_step"):
+                if self._pool is None:
+                    self._step_shard(self._shards[0], actions)
+                else:
+                    futures = [self._pool.submit(self._step_shard, shard,
+                                                 actions)
+                               for shard in self._shards]
+                    for f in futures:
+                        f.result()
 
             # all per-step bookkeeping, vectorized over the whole fleet
             # (reference actor body worker.py:537-554, batched)
-            lanes = np.arange(self.N)
-            self.last_action[:] = 0.0
-            self.last_action[lanes, actions] = 1.0
-            self.last_reward[:] = self._step_reward
-            np.copyto(self.hidden, new_hidden)
-            self.episode_steps += 1
-            self.vbuf.add_batch(lanes, actions, self._step_reward, self.obs,
-                                q, new_hidden)
+            with maybe_span(tr, "actor.record"):
+                lanes = np.arange(self.N)
+                self.last_action[:] = 0.0
+                self.last_action[lanes, actions] = 1.0
+                self.last_reward[:] = self._step_reward
+                np.copyto(self.hidden, new_hidden)
+                self.episode_steps += 1
+                self.vbuf.add_batch(lanes, actions, self._step_reward,
+                                    self.obs, q, new_hidden)
 
-            done_lanes = np.nonzero(self._step_done)[0]
-            for i in done_lanes:
-                # reset BEFORE the sink call (the finished Block owns
-                # copies, never vbuf storage): a sink that unwinds during
-                # shutdown must leave the lane consistent for the
-                # shutdown snapshot — same ordering as the boundary cut
-                item = self.vbuf.finish(i, None)
-                self._note_cut(i, item[0])
-                self._reset_lane(i)
-                self.sink(*item)
+            for i in np.nonzero(self._step_done)[0]:
+                self._cut(i, None, reset=True)
 
             capped = np.nonzero(~self._step_done
                                 & (self.episode_steps >= cfg.max_episode_steps)
@@ -481,10 +497,7 @@ class VectorActor:
                                            self.last_reward, self.hidden)
                 q_fresh = np.asarray(q_fresh)
                 for i in capped:
-                    item = self.vbuf.finish(i, q_fresh[i])
-                    self._note_cut(i, item[0])
-                    self._reset_lane(i)  # before the sink; see done_lanes
-                    self.sink(*item)
+                    self._cut(i, q_fresh[i], reset=True)
 
             self.actor_steps += 1
             if self.actor_steps % cfg.actor_update_interval == 0:

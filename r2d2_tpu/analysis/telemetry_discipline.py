@@ -13,7 +13,7 @@ The check: every call of a metric-writing method — ``inc``,
 ``counter_max``, ``set_gauge``, ``observe``, ``observe_many``,
 ``declare_histogram``, ``absorb_histogram`` on a registry-shaped
 receiver, plus the Tracer
-surface (``span``, ``gauge``, ``incr``) and the cross-process event
+surface (``span``, ``gauge``) and the cross-process event
 tracer's recording surface (``instant``, ``complete`` —
 telemetry/tracing.py; variable parts go in ``flow``/``arg``, never the
 event name) — must pass the metric/event name as a plain string
@@ -51,7 +51,7 @@ RULE = "telemetry-discipline"
 _METRIC_METHODS = ("inc", "counter_max", "set_gauge", "observe",
                    "observe_many", "declare_histogram",
                    "absorb_histogram", "span", "gauge",
-                   "incr", "instant", "complete")
+                   "instant", "complete")
 
 _RECEIVER_NAMES = ("registry", "metrics", "telemetry", "tracer", "reg",
                    "tr", "events")

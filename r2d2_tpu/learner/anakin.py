@@ -74,7 +74,12 @@ from r2d2_tpu.models.network import R2D2Network
 from r2d2_tpu.replay.device_ring import gather_batch
 from r2d2_tpu.utils.math import epsilon_ladder
 from r2d2_tpu.utils.resilience import Deadline
-from r2d2_tpu.utils.trace import HOST_TRANSFERS, RETRACES, TRANSFER_GUARD
+from r2d2_tpu.utils.trace import (
+    HOST_TRANSFERS,
+    RETRACES,
+    TRANSFER_GUARD,
+    put_scalar,
+)
 
 log = logging.getLogger(__name__)
 
@@ -270,6 +275,7 @@ def _make_emit(cfg: Config, action_dim: int, done: bool):
     alpha = cfg.prio_exponent
     assemble = jax.vmap(_make_assemble(cfg, action_dim, done))
 
+    @jax.named_scope("ring_write")
     def emit(ast, arrays, prios, seq_meta, first, cut, last_q):
         bufs = dict(obs=ast["buf_obs"], last_action=ast["buf_last_action"],
                     last_reward=ast["buf_last_reward"],
@@ -349,9 +355,10 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
     lanes = jnp.arange(N)
 
     def actor_step(params, ast, arrays, prios, seq_meta, first):
-        q, new_hidden = act_net.apply(
-            params, ast["obs"], ast["last_action"], ast["last_reward"],
-            ast["hidden"], method=R2D2Network.act)
+        with jax.named_scope("act"):
+            q, new_hidden = act_net.apply(
+                params, ast["obs"], ast["last_action"], ast["last_reward"],
+                ast["hidden"], method=R2D2Network.act)
 
         # 1) deferred block-boundary cuts: this step's Q at the new state
         #    is the bootstrap (worker.py:550-554 semantics, no 2nd forward)
@@ -384,8 +391,9 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
 
         # 3) env step (no auto-reset: the post-step obs is recorded first)
         env_state = {k: ast["env_" + k] for k in env_keys}
-        env_state, reward, truncated = env.step(env_state, actions)
-        obs_step = env.observe(env_state)
+        with jax.named_scope("env_step"):
+            env_state, reward, truncated = env.step(env_state, actions)
+            obs_step = env.observe(env_state)
 
         # 4) batched bookkeeping + local-buffer add (VectorLocalBuffer
         #    .add_batch, one scatter per field)
@@ -666,7 +674,8 @@ def make_anakin_super_step(cfg: Config, net: R2D2Network,
             else:
                 ts, loss, new_p = step(ts, batch)
             # same feedback exponentiation as the in_graph_per super-step
-            prios = prios.at[idx].set(new_p ** cfg.prio_exponent)
+            with jax.named_scope("per_scatter"):
+                prios = prios.at[idx].set(new_p ** cfg.prio_exponent)
             return ((ts, ast, arrays, prios, seq_meta, first),
                     ((loss, diag) if lh else loss))
 
@@ -912,8 +921,7 @@ class AnakinPlane:
         with TRANSFER_GUARD.disallow("anakin.dispatch"):
             # the loop's ONE recurring H2D: the dispatch index scalar
             with HOST_TRANSFERS.allowed("anakin.dispatch_put"):
-                idx = jnp.asarray(self.dispatch_no & 0xFFFFFFFF,
-                                  jnp.uint32)
+                idx = put_scalar(self.dispatch_no & 0xFFFFFFFF, np.uint32)
             self.dispatch_no += 1
             train_state, ast, arrays, prios, seq_meta, first, flat = (
                 self.super_step(train_state, self.state,
@@ -1248,7 +1256,7 @@ def run_anakin_loop(learner: Any, plane: AnakinPlane,
             if cfg.transfer_guard and not guard_armed:
                 guard_stack.enter_context(TRANSFER_GUARD.arm())
                 guard_armed = True
-            with tracer.span("learner.step_dispatch"):
+            with tracer.span("learner.step_dispatch", plane.dispatch_no):
                 learner.state, flat = plane.dispatch(learner.state)
             pending.append(flat)
             while len(pending) > cfg.superstep_pipeline and not wedged:
@@ -1259,7 +1267,8 @@ def run_anakin_loop(learner: Any, plane: AnakinPlane,
             if (learner.param_store is not None
                     and updates // cfg.weight_publish_interval
                     > prev // cfg.weight_publish_interval):
-                learner._publish()
+                with tracer.span("learner.publish"):
+                    learner._publish()
             if (learner.checkpointer is not None
                     and updates // cfg.save_interval
                     > prev // cfg.save_interval):

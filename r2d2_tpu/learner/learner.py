@@ -19,7 +19,6 @@ TPU-first redesign:
 """
 from __future__ import annotations
 
-import contextlib
 import queue
 import threading
 import time
@@ -41,7 +40,13 @@ from r2d2_tpu.parallel.sharding import (
     pjit_train_step,
 )
 from r2d2_tpu.utils.store import ParamStore
-from r2d2_tpu.utils.trace import HOST_TRANSFERS, TRANSFER_GUARD
+from r2d2_tpu.utils.trace import (
+    HOST_TRANSFERS,
+    TRANSFER_GUARD,
+    held,
+    maybe_span,
+    put_scalar,
+)
 
 def _aval_tree(tree):
     """ShapeDtypeStruct avals (shape/dtype/sharding) for every leaf —
@@ -122,7 +127,10 @@ class Learner:
                 # built once: a fresh jit per publish would re-trace (and
                 # without a compile cache, re-compile) the reshard program
                 # on the learner hot loop every publish
-                self._replicate_params = jax.jit(lambda p: p,
+                def publish_replicate_params(p):
+                    return p
+
+                self._replicate_params = jax.jit(publish_replicate_params,
                                                  out_shardings=rep)
             self.param_store.publish(jax.device_get(
                 self._replicate_params(self.state.params)))
@@ -132,8 +140,10 @@ class Learner:
                 # tree_map of jnp.copy issues one dispatch PER LEAF on
                 # the dispatch path every publish (and k=4 publishes once
                 # per super-step dispatch)
-                self._copy_params = jax.jit(
-                    lambda p: jax.tree.map(jnp.copy, p))
+                def publish_copy_params(p):
+                    return jax.tree.map(jnp.copy, p)
+
+                self._copy_params = jax.jit(publish_copy_params)
             self.param_store.publish(self._copy_params(self.state.params))
 
     @property
@@ -674,7 +684,7 @@ class Learner:
             store_prios = ring.put_prios
             gate = self._ready_gate(buffer, stop)
 
-        seed0 = jnp.asarray(0, jnp.uint32)
+        seed0 = put_scalar(0, np.uint32)
         # AOT-compile from avals, not live ring handles: actor threads
         # are already committing blocks, and a concurrent commit_per
         # donates the priorities handle — lowering from the live array
@@ -688,9 +698,9 @@ class Learner:
         dispatch_no = [0]
 
         def sample():
-            with tracer.span("learner.step_dispatch"), \
+            with tracer.span("learner.step_dispatch", dispatch_no[0]), \
                     TRANSFER_GUARD.disallow("learner.dispatch"):
-                with buffer.lock:
+                with held(buffer.lock, tracer, "learner.lock_wait"):
                     # fold_in(PRNGKey(cfg.seed), idx) happens in-graph;
                     # the u32 counter wraps harmlessly after 2^32.
                     # Multi-host: every process dispatches in lockstep
@@ -698,8 +708,8 @@ class Learner:
                     # the in-graph sampling streams — stay identical.
                     # ONE declared H2D per dispatch: the index scalar
                     with HOST_TRANSFERS.allowed("learner.dispatch_put"):
-                        idx = jnp.asarray(
-                            dispatch_no[0] & 0xFFFFFFFF, jnp.uint32)
+                        idx = put_scalar(dispatch_no[0] & 0xFFFFFFFF,
+                                         np.uint32)
                     dispatch_no[0] += 1
                     out = compiled(self.state, *ring_args(), idx)
                     if self._lh:
@@ -782,17 +792,15 @@ class Learner:
                 harvest(pending.popleft())
 
             prev, updates = updates, updates + k
-            span = (tracer.span if tracer is not None
-                    else contextlib.nullcontext)
             if (self.param_store is not None
                     and updates // cfg.weight_publish_interval
                     > prev // cfg.weight_publish_interval):
-                with span("learner.publish"):
+                with maybe_span(tracer, "learner.publish"):
                     self._publish()
             if (self.checkpointer is not None
                     and updates // cfg.save_interval
                     > prev // cfg.save_interval):
-                with span("learner.checkpoint_save"):
+                with maybe_span(tracer, "learner.checkpoint_save"):
                     self._save(updates, t0)
         while pending:
             harvest(pending.popleft())

@@ -130,10 +130,12 @@ def _double_unroll(cfg: Config, net: R2D2Network, params, target_params,
         q_online, _ = net.apply(params, batch["obs"], batch["last_action"],
                                 batch["last_reward"], batch["hidden"],
                                 method=R2D2Network.unroll)      # (B, T, A)
-        q_target_seq, _ = net.apply(target_params, batch["obs"],
-                                    batch["last_action"],
-                                    batch["last_reward"], batch["hidden"],
-                                    method=R2D2Network.unroll)
+        with jax.named_scope("target_forward"):
+            q_target_seq, _ = net.apply(target_params, batch["obs"],
+                                        batch["last_action"],
+                                        batch["last_reward"],
+                                        batch["hidden"],
+                                        method=R2D2Network.unroll)
         return q_online, jax.lax.stop_gradient(q_target_seq)
 
     stacked = jax.tree.map(
@@ -168,7 +170,13 @@ def loss_and_priorities(cfg: Config, net: R2D2Network, params, target_params,
     — stop-gradiented values, never a second forward."""
     q_online, q_target_seq = _double_unroll(cfg, net, params, target_params,
                                             batch)
+    return _td_loss(cfg, batch, q_online, q_target_seq, with_aux)
 
+
+@jax.named_scope("loss")
+def _td_loss(cfg: Config, batch, q_online, q_target_seq, with_aux: bool):
+    """The loss's own arithmetic, after the two unrolls: window gathers,
+    double-Q target, weighted TD error, priorities."""
     idx_online, idx_target, mask = _window_indices(
         cfg, batch["burn_in"], batch["learning"], batch["forward"])
 
@@ -232,13 +240,16 @@ def make_train_step(cfg: Config, net: R2D2Network,
         (loss, priorities), grads = grad_fn(state.params)
         if lh:
             priorities, aux = priorities
-        updates, new_opt_state = opt.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = opt.update(grads, state.opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
 
-        step = state.step + 1
-        sync = (step % cfg.target_net_update_interval) == 0
-        new_target = jax.tree.map(
-            lambda p, t: jnp.where(sync, p, t), new_params, state.target_params)
+            step = state.step + 1
+            sync = (step % cfg.target_net_update_interval) == 0
+            new_target = jax.tree.map(
+                lambda p, t: jnp.where(sync, p, t), new_params,
+                state.target_params)
 
         new_state = TrainState(step=step, params=new_params,
                                target_params=new_target,
@@ -380,6 +391,7 @@ def _in_graph_sample_raw(cfg: Config, key, prios, seq_meta, first_burn,
     return idx, q, ints_t
 
 
+@jax.named_scope("per_sample")
 def _in_graph_sample(cfg: Config, key, prios, seq_meta, first_burn,
                      constrain_rep=None):
     """One prioritized batch draw on-device: (idx (B,), is_weights (B,)
@@ -466,7 +478,8 @@ def make_in_graph_per_super_step_fn(cfg: Config, net: R2D2Network, k: int,
             # feedback: same exponentiation the host tree applies
             # (sum_tree.py:60); duplicate-idx writes resolve arbitrarily,
             # as does the host's sequential last-wins — both harmless
-            p = p.at[idx].set(new_p ** cfg.prio_exponent)
+            with jax.named_scope("per_scatter"):
+                p = p.at[idx].set(new_p ** cfg.prio_exponent)
             return (st, p), ((loss, diag) if lh else loss)
 
         (state, prios), ys = jax.lax.scan(body, (state, prios), keys)

@@ -270,10 +270,15 @@ class R2D2Network(nn.Module):
              last_reward[..., None].astype(jnp.float32)], axis=-1)
 
     def unroll(self, obs, last_action, last_reward, hidden):
-        feats = self._features(obs, last_action, last_reward)
-        outs, new_hidden = self._lstm_stack(feats, hidden)
-        B, T = outs.shape[:2]
-        q = self.head(outs.reshape(B * T, -1)).reshape(B, T, -1)
+        # the three scopes a profile splits a forward (and, under
+        # jvp(...) / transpose(jvp(...)), a backward) by
+        with jax.named_scope("torso"):
+            feats = self._features(obs, last_action, last_reward)
+        with jax.named_scope("core"):
+            outs, new_hidden = self._lstm_stack(feats, hidden)
+        with jax.named_scope("heads"):
+            B, T = outs.shape[:2]
+            q = self.head(outs.reshape(B * T, -1)).reshape(B, T, -1)
         return q, new_hidden
 
     def act(self, obs, last_action, last_reward, hidden):

@@ -91,7 +91,6 @@ the acting path.  On a CPU-only host (tier-1 tests under
 """
 from __future__ import annotations
 
-import contextlib
 import logging
 import threading
 import time
@@ -112,7 +111,7 @@ from r2d2_tpu.utils.resilience import (
     Deadline,
     RetryPolicy,
 )
-from r2d2_tpu.utils.trace import HOST_TRANSFERS, TRANSFER_GUARD
+from r2d2_tpu.utils.trace import HOST_TRANSFERS, TRANSFER_GUARD, maybe_span
 
 log = logging.getLogger(__name__)
 
@@ -183,11 +182,6 @@ def act_response_crc(views: dict, seq: int, mode: int) -> int:
     if int(mode) != MODE_PEEK:
         fields.append(views["rsp_hidden"])
     return payload_crc32((seq, int(mode)), fields)
-
-
-def _span(tracer, name: str):
-    return tracer.span(name) if tracer is not None else (  # graftlint: disable=telemetry-discipline -- nullable-tracer pass-through; every call site passes a literal
-        contextlib.nullcontext())
 
 
 class ActChannel:
@@ -696,7 +690,7 @@ class InferenceService:
             return 0
         tr = self.tracer
         pend = sorted(self._pending)
-        with _span(tr, "serve.assemble"):
+        with maybe_span(tr, "serve.assemble"):
             with self._hidden_lock:
                 for f in list(pend):
                     item = self._pending.get(f)
@@ -735,7 +729,7 @@ class InferenceService:
         if len(pend) < attached:
             self.partial_batches += 1
             self.registry.inc("serve.partial_batches")
-        with _span(tr, "serve.act"), \
+        with maybe_span(tr, "serve.act"), \
                 TRANSFER_GUARD.disallow("serve.act"):
             # the batch's declared H2D: the assembled lane slabs ride the
             # dispatch as implicit transfers of the numpy args
@@ -751,7 +745,7 @@ class InferenceService:
             with HOST_TRANSFERS.allowed("serve.act_fetch"):
                 q, new_hidden = jax.device_get((q, new_hidden))
         lanes = 0
-        with _span(tr, "serve.scatter"):
+        with maybe_span(tr, "serve.scatter"):
             with self._hidden_lock:
                 for f in pend:
                     item = self._pending.pop(f, None)

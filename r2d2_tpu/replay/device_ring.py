@@ -58,7 +58,6 @@ data.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -131,6 +130,7 @@ def _write_slot_fn(arrays: Dict[str, jnp.ndarray],
 _write_slot = jax.jit(_write_slot_fn, donate_argnums=(0,))
 
 
+@jax.named_scope("ring_gather")
 def gather_batch(cfg: Config, arrays: Dict[str, jnp.ndarray],
                  ints: jnp.ndarray, is_weights: jnp.ndarray
                  ) -> Dict[str, jnp.ndarray]:
@@ -206,18 +206,24 @@ def resolve_layout(cfg: Config, mesh, need_bytes: int,
     return "replicated"
 
 
-def _write_per_fn(prios: jnp.ndarray, seq_meta: jnp.ndarray,
-                  first_burn: jnp.ndarray, prios_slot: jnp.ndarray,
-                  meta_slot: jnp.ndarray, first_val: jnp.ndarray,
-                  slot: jnp.ndarray, K: int):
-    """Donated in-place write of one block's PER leaves + sampling
-    metadata (in-graph-PER mode, see :class:`DeviceRing`)."""
-    prios = jax.lax.dynamic_update_slice(prios, prios_slot, (slot * K,))
-    seq_meta = jax.lax.dynamic_update_index_in_dim(seq_meta, meta_slot,
-                                                   slot, 0)
-    first_burn = jax.lax.dynamic_update_index_in_dim(
-        first_burn, first_val, slot, 0)
-    return prios, seq_meta, first_burn
+def _ring_write_per_fn(K: int):
+    """The PER write for a ring of ``K`` sequences a block, under the name
+    the device's timeline shows it by (``jit_ring_write_per``)."""
+
+    def ring_write_per(prios: jnp.ndarray, seq_meta: jnp.ndarray,
+                       first_burn: jnp.ndarray, prios_slot: jnp.ndarray,
+                       meta_slot: jnp.ndarray, first_val: jnp.ndarray,
+                       slot: jnp.ndarray):
+        """Donated in-place write of one block's PER leaves + sampling
+        metadata (in-graph-PER mode, see :class:`DeviceRing`)."""
+        prios = jax.lax.dynamic_update_slice(prios, prios_slot, (slot * K,))
+        seq_meta = jax.lax.dynamic_update_index_in_dim(seq_meta, meta_slot,
+                                                       slot, 0)
+        first_burn = jax.lax.dynamic_update_index_in_dim(
+            first_burn, first_val, slot, 0)
+        return prios, seq_meta, first_burn
+
+    return ring_write_per
 
 
 class DeviceRing:
@@ -293,8 +299,7 @@ class DeviceRing:
                 self._per_first = jax.device_put(
                     np.zeros((NB,), np.int32), psh["first"])
                 self._per_write = jax.jit(
-                    functools.partial(_write_per_fn, K=K),
-                    donate_argnums=(0, 1, 2),
+                    _ring_write_per_fn(K), donate_argnums=(0, 1, 2),
                     out_shardings=(psh["prios"], psh["seq_meta"],
                                    psh["first"]))
             else:
@@ -304,8 +309,7 @@ class DeviceRing:
                     np.zeros((NB, K, 3), np.int32))
                 self._per_first = self._put_slot(np.zeros((NB,), np.int32))
                 self._per_write = jax.jit(
-                    functools.partial(_write_per_fn, K=K),
-                    donate_argnums=(0, 1, 2))
+                    _ring_write_per_fn(K), donate_argnums=(0, 1, 2))
 
     def _put(self, x):
         return (jax.device_put(x, self._placement)
@@ -349,8 +353,10 @@ class DeviceRing:
         into (physical) slot ``ptr``.  Caller holds the coordinating lock
         (see the module contract) — this is just one async dispatch, so
         the lock hold is microseconds."""
-        self.arrays = self._write_fn(self.arrays, slot,
-                                     jnp.asarray(ptr, jnp.int32))
+        # numpy scalars, here and in commit_per: jnp.asarray(int, dtype)
+        # runs a convert program of its own on the device for every
+        # scalar (utils/trace.put_scalar)
+        self.arrays = self._write_fn(self.arrays, slot, np.int32(ptr))
 
     def write(self, block: Block, ptr: int) -> None:
         """stage + commit in one call (caller holds the coordinating
@@ -372,10 +378,9 @@ class DeviceRing:
         self._per_prios, self._per_seq_meta, self._per_first = (
             self._per_write(
                 self._per_prios, self._per_seq_meta, self._per_first,
-                jnp.asarray(prios_alpha, jnp.float32),
-                jnp.asarray(meta, jnp.int32),
-                jnp.asarray(first_burn, jnp.int32),
-                jnp.asarray(slot, jnp.int32)))
+                np.asarray(prios_alpha, np.float32),
+                np.asarray(meta, np.int32),
+                np.int32(first_burn), np.int32(slot)))
 
     def take_prios(self) -> jnp.ndarray:
         """The current priorities handle, for a super-step dispatch that

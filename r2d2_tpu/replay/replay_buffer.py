@@ -26,6 +26,7 @@ from r2d2_tpu.config import Config
 from r2d2_tpu.replay.block import Block, slot_layout, slot_views
 from r2d2_tpu.replay.sum_tree import SumTree
 from r2d2_tpu.telemetry.tracing import EVENTS
+from r2d2_tpu.utils.trace import held, maybe_span
 
 # at most this many lineage flow points per sampled batch / feedback
 # call: a B=64 batch touching 64 distinct blocks must not dump 64 flow
@@ -127,15 +128,23 @@ class ReplayBuffer:
 
     def __init__(self, cfg: Config, action_dim: int,
                  rng: Optional[np.random.Generator] = None,
-                 device_ring: Optional[Any] = None):
+                 device_ring: Optional[Any] = None,
+                 tracer: Optional[Any] = None):
         """``device_ring`` (replay/device_ring.DeviceRing): when given, the
         bulk experience arrays live in HBM — ``add`` streams each block to
         the device once, ``sample_meta`` yields index bundles for the
         in-graph gather, and the big host data arrays are NOT allocated
-        (``sample_batch`` then raises)."""
+        (``sample_batch`` then raises).
+
+        ``tracer`` (utils/trace.Tracer): ``add`` records ``replay.stage``
+        (zero-pad and H2D, outside the lock) and ``replay.commit`` (the
+        time the lock is held against the dispatch thread);
+        ``sample_meta`` records its wait for the lock as
+        ``learner.lock_wait``."""
         self.cfg = cfg
         self.action_dim = action_dim
         self.device_ring = device_ring
+        self.tracer = tracer
         if getattr(cfg, "in_graph_per", False) and device_ring is None:
             # fail HERE with the remedy, not with an AttributeError in an
             # actor thread at the first block commit: device PER cannot
@@ -273,8 +282,10 @@ class ReplayBuffer:
         # learner's sample+dispatch serialises on this same lock.  Only the
         # donated commit (one async dispatch) needs the ordering the lock
         # provides.
-        staged = (self.device_ring.stage(block)
-                  if self.device_ring is not None else None)
+        staged = None
+        if self.device_ring is not None:
+            with maybe_span(self.tracer, "replay.stage"):
+                staged = self.device_ring.stage(block)
         in_graph = getattr(cfg, "in_graph_per", False)
         if in_graph:
             # device-PER leaves: td**alpha — ``priorities`` arrives
@@ -289,7 +300,7 @@ class ReplayBuffer:
             meta[:k_seq, 0] = block.burn_in_steps
             meta[:k_seq, 1] = block.learning_steps
             meta[:k_seq, 2] = block.forward_steps
-        with self.lock:
+        with self.lock, maybe_span(self.tracer, "replay.commit"):
             ptr = self.block_ptr
             # every array (and the PER leaves) is keyed by the PHYSICAL
             # slot; the logical ptr only orders the FIFO walk
@@ -573,7 +584,7 @@ class ReplayBuffer:
         ints = np.empty((k, B, 6), np.int32)
         weights = np.empty((k, B), np.float32)
         idxes = np.empty((k, B), np.int64)
-        with self.lock:
+        with held(self.lock, self.tracer, "learner.lock_wait"):
             if self.size == 0:
                 raise RuntimeError(
                     "sample_meta on an empty buffer; wait for add() (use "

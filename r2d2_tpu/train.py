@@ -76,8 +76,11 @@ def _default_env_factory(cfg: Config, seed: int):
 
 
 def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
-           checkpoint_dir: Optional[str], resume: bool):
+           checkpoint_dir: Optional[str], resume: bool,
+           tracer: Optional[Tracer] = None):
     """Common bring-up: envs, net, state (maybe restored), buffer, stores.
+    ``tracer`` goes to the in-process buffer and the thread actors, whose
+    spans split a block's write and a lockstep step.
 
     The single-process drivetrain is exactly the one ``cfg`` names: a
     ``device_replay`` ring that does not fit the device is a ValueError
@@ -213,7 +216,7 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
     else:
         buffer = ReplayBuffer(cfg, action_dim,
                               rng=np.random.default_rng(cfg.seed),
-                              device_ring=ring)
+                              device_ring=ring, tracer=tracer)
     buffer.env_steps = start_env_steps
     epsilons = [epsilon_ladder(i, cfg.num_actors, cfg.base_eps, cfg.eps_alpha)
                 for i in range(cfg.num_actors)]
@@ -272,7 +275,8 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
                         param_store, sink=buffer.add,
                         env_workers=fleet_workers,
                         rng=np.random.default_rng(
-                            cfg.seed + 7919 + 104729 * f))
+                            cfg.seed + 7919 + 104729 * f),
+                        tracer=tracer)
             for f, (lo, hi) in enumerate(shards)
         ]
     # full-state resume: a warm replay ring + resumable actor state saved
@@ -1038,14 +1042,15 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                              verbose=verbose, log_sink=log_sink,
                              tracer=tracer, profile_dir=profile_dir,
                              stop_fn=stop_fn)
-    sys = _build(cfg, env_factory, use_mesh, checkpoint_dir, resume)
+    tracer = tracer or Tracer()
+    sys = _build(cfg, env_factory, use_mesh, checkpoint_dir, resume,
+                 tracer=tracer)
     actors: List[VectorActor] = sys["actors"]
     buffer: ReplayBuffer = sys["buffer"]
     learner: Learner = sys["learner"]
     checkpointer = sys["checkpointer"]
     plane = sys["plane"]
     replay_plane = sys["replay_plane"]
-    tracer = tracer or Tracer()
     scaffold = _HostScaffold(cfg, checkpoint_dir,
                              max_wall_seconds=max_wall_seconds,
                              max_thread_restarts=max_thread_restarts,
@@ -1300,7 +1305,6 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
             dt = now - last_time
             tracer.gauge("batch_queue_depth", batch_queue.qsize())
             tracer.gauge("priority_queue_depth", priority_queue.qsize())
-            tracer.gauge("buffer_fill", s["size"])
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"],

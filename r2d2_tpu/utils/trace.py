@@ -15,7 +15,13 @@ the survey calls for:
   cross-process Perfetto timeline).
 - :func:`device_profile` — a context manager around ``jax.profiler`` trace
   capture, producing a TensorBoard-loadable trace of the XLA device
-  timeline for any region of the training loop.
+  timeline for any region of the training loop.  While one is open every
+  ``Tracer.span`` also opens a ``jax.profiler.TraceAnnotation`` of the
+  same name, so the dump shows the host spans, per thread, on the
+  device's clock; :data:`PROFILE_SYNC` says how to map the two clocks.
+- :func:`maybe_span` / :func:`held` — the one "span or nothing" for call
+  sites whose tracer is optional, and a ``with lock:`` whose wait is a
+  span.
 - :class:`RetraceGuard` — compile-boundary discipline made checkable
   (Podracer, PAPERS.md): every jitted entry point wraps its Python
   function in :data:`RETRACES`.wrap(name, fn, budget), so each XLA trace
@@ -48,12 +54,37 @@ import bisect
 import contextlib
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 # fixed log-spaced span-duration buckets (seconds, 4 per decade from
 # 10 µs to 100 s): every span shares them, so the per-update cost is one
 # bisect + one int increment and the percentile read needs no samples
 _SPAN_BOUNDS = tuple(10.0 ** (e / 4.0) for e in range(-20, 9))
+
+# True only while a device_profile() is open (it sets and clears this):
+# spans then also open a profiler annotation.  Closed, a span pays one
+# read of it and jax is never imported (fleet subprocesses use Tracer
+# without jax).
+_profile_open = False
+
+# what device_profile() leaves beside its dump to join the two clocks:
+# the annotation it writes once on the profiler's timeline, and the file
+# that holds the ``perf_counter`` value read inside that annotation
+PROFILE_SYNC = "tracer_clock_sync"
+PROFILE_SYNC_FILE = "clock_sync.json"
+
+
+def _annotate(stack: contextlib.ExitStack, name: str,
+              step: Optional[int]) -> None:
+    """Open the profiler's twin of a span on ``stack`` (open branch of
+    :meth:`Tracer.span` only).  A span given a ``step`` is a dispatch:
+    it also opens the profiler's step marker."""
+    import jax
+
+    if step is not None:
+        stack.enter_context(
+            jax.profiler.StepTraceAnnotation("dispatch", step_num=step))
+    stack.enter_context(jax.profiler.TraceAnnotation(name))
 
 
 class _Stat:
@@ -107,7 +138,6 @@ class Tracer:
         self._alpha = alpha
         self._spans: Dict[str, _Stat] = {}
         self._gauges: Dict[str, float] = {}
-        self._counters: Dict[str, int] = {}
         self._lock = threading.Lock()
         if events is None:
             # the process-wide structured event recorder
@@ -120,12 +150,21 @@ class Tracer:
         self._event_sink = events
 
     @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[None]:
+    def span(self, name: str, step: Optional[int] = None) -> Iterator[None]:
+        """Time the body under ``name``.  ``step`` marks a dispatch span
+        with its number: under an open :func:`device_profile` it becomes
+        the profiler's ``StepTraceAnnotation``; otherwise it is unused."""
+        annotations = None
+        if _profile_open:
+            annotations = contextlib.ExitStack()
+            _annotate(annotations, name, step)
         t0 = time.perf_counter()
         try:
             yield
         finally:
             dt = time.perf_counter() - t0
+            if annotations is not None:
+                annotations.close()
             with self._lock:
                 stat = self._spans.get(name)
                 if stat is None:
@@ -141,13 +180,9 @@ class Tracer:
         with self._lock:
             self._gauges[name] = float(value)
 
-    def incr(self, name: str, by: int = 1) -> None:
-        with self._lock:
-            self._counters[name] = self._counters.get(name, 0) + by
-
     def snapshot(self) -> Dict[str, float]:
         """Flat dict: span.<name>.{ewma_ms,mean_ms,count,p50_ms,p95_ms,
-        p99_ms}, gauge.<name>, counter.<name>.  The percentiles come
+        p99_ms}, gauge.<name>.  The percentiles come
         from each span's fixed log-bucket histogram — visible per log
         interval in /statusz and the console line without a trace
         dump."""
@@ -162,9 +197,32 @@ class Tracer:
                 out[f"span.{name}.p99_ms"] = s.percentile(0.99) * 1e3
             for name, v in self._gauges.items():
                 out[f"gauge.{name}"] = v
-            for name, v in self._counters.items():
-                out[f"counter.{name}"] = v
         return out
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(tracer: Optional[Tracer], name: str,
+               step: Optional[int] = None):
+    """``tracer.span(name)``, or nothing where the caller was given no
+    tracer — one ``if`` at a call site whose tracer is optional."""
+    if tracer is None:
+        return _NO_SPAN
+    return tracer.span(name, step)  # graftlint: disable=telemetry-discipline -- nullable-tracer pass-through; every call site passes a literal
+
+
+@contextlib.contextmanager
+def held(lock: Any, tracer: Optional[Tracer], wait_name: str
+         ) -> Iterator[None]:
+    """``with lock:`` whose wait is a span: ``wait_name`` runs from asking
+    for the lock to having it."""
+    with maybe_span(tracer, wait_name):
+        lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 class RetraceBudgetExceeded(AssertionError):
@@ -382,6 +440,20 @@ class TransferGuard:
             self._trips.clear()
 
 
+def put_scalar(value: int, dtype) -> Any:
+    """A host integer as a device scalar in ONE transfer — a dispatch's
+    declared H2D.  ``jnp.asarray(int, dtype)`` costs a program besides:
+    it binds ``convert_element_type`` eagerly on the scalar, which the
+    device's timeline shows as a ``jit_convert_element_type`` of its own
+    for every call (PERF.md, PR 25).  Uncommitted on the default device,
+    as ``jnp.asarray`` left it, so a jit places it like any host value —
+    no sharding here: a put onto a multi-host sharding is a collective."""
+    import jax
+    import numpy as np
+
+    return jax.device_put(np.asarray(value, dtype))
+
+
 # process-wide instances: jitted entry points register with RETRACES at
 # build time; the ingest / inference-service loops tick HOST_TRANSFERS
 # and open TRANSFER_GUARD windows around their dispatch/fetch bodies.
@@ -395,16 +467,39 @@ TRANSFER_GUARD = TransferGuard()
 def device_profile(log_dir: Optional[str]) -> Iterator[None]:
     """Capture a ``jax.profiler`` device trace into ``log_dir`` (viewable
     in TensorBoard / Perfetto).  No-op when ``log_dir`` is None, so call
-    sites can be unconditional."""
+    sites can be unconditional.
+
+    While it is open every ``Tracer.span`` of the process also writes a
+    profiler annotation, so the dump holds the host spans, each on its
+    own thread, beside the device's operations.  One :data:`PROFILE_SYNC`
+    annotation and ``<log_dir>/clock_sync.json`` (the ``perf_counter``
+    and wall time read inside it) let a reader place anything else timed
+    on the host's clock — the ``/tracez`` events — on the same
+    timeline."""
+    global _profile_open
     if not log_dir:
         yield
         return
+    import json
+    import os
+
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    # the program's spans are the host's timeline; the profiler's own
+    # Python tracer would record every call of every actor thread
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    _profile_open = True
     try:
+        with jax.profiler.TraceAnnotation(PROFILE_SYNC):
+            mark = dict(annotation=PROFILE_SYNC,
+                        perf_counter=time.perf_counter(), time=time.time())
+        with open(os.path.join(log_dir, PROFILE_SYNC_FILE), "w") as f:
+            json.dump(mark, f)
         yield
     finally:
+        _profile_open = False
         jax.profiler.stop_trace()
 
 

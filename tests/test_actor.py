@@ -1,4 +1,6 @@
 """Actor / AgentState / env integration tests against the fake env."""
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -301,3 +303,62 @@ def test_resolve_act_device_refuses_a_missing_cpu_backend(monkeypatch):
     for spec in ("auto", "cpu"):
         with pytest.raises(RuntimeError, match="JAX_PLATFORMS"):
             resolve_act_device(spec)
+
+
+@pytest.mark.parametrize("env_workers", [0, 2])
+def test_vector_actor_spans_split_the_lockstep_step(env_workers):
+    """Given a Tracer, a VectorActor records one actor.act, actor.env_step
+    and actor.record a lockstep step and one actor.cut a finished block
+    (pending, done and capped lanes alike), serial or pooled; the cut
+    spans cover the sink calls."""
+    from _span_log import SpanLog
+    from r2d2_tpu.utils.trace import Tracer
+
+    cfg = make_test_config(game_name="Fake", max_episode_steps=12)
+    net, params, store, act_fn = build(cfg)
+    log = SpanLog()
+    sunk = []
+    actor = VectorActor(cfg, [make_env(cfg, seed=i) for i in range(3)],
+                        [0.9, 0.5, 0.1], act_fn, store,
+                        sink=lambda b, p, r: sunk.append(time.perf_counter()),
+                        rng=np.random.default_rng(2),
+                        env_workers=env_workers,
+                        tracer=Tracer(events=log))
+    steps = 30      # block_length 8, episode cap 12: all three kinds of cut
+    actor.run(max_steps=steps)
+    actor.close()
+    for name in ("actor.act", "actor.env_step", "actor.record"):
+        assert log.count(name) == steps, name
+    assert len(sunk) > 6 and log.count("actor.cut") == len(sunk)
+    cuts = log.spans["actor.cut"]
+    assert all(any(t0 <= t <= t0 + dt for t0, dt in cuts) for t in sunk)
+    # the parts of a step lie one after another inside the run
+    for a, e, r in zip(log.spans["actor.act"], log.spans["actor.env_step"],
+                       log.spans["actor.record"]):
+        assert a[0] + a[1] <= e[0] and e[0] + e[1] <= r[0]
+
+
+def test_vector_actor_without_a_tracer_makes_the_same_blocks():
+    """The spans change nothing: with and without a tracer the same seeds
+    give the same blocks."""
+    from r2d2_tpu.utils.trace import Tracer
+
+    cfg = make_test_config(game_name="Fake")
+    net, params, store, act_fn = build(cfg)
+
+    def blocks(tracer):
+        out = []
+        actor = VectorActor(cfg, [make_env(cfg, seed=i) for i in range(2)],
+                            [0.4, 0.1], act_fn, store,
+                            sink=lambda b, p, r: out.append((b, p)),
+                            rng=np.random.default_rng(3), tracer=tracer)
+        assert actor.tracer is tracer
+        actor.run(max_steps=40)
+        return out
+
+    plain, traced = blocks(None), blocks(Tracer(events=None))
+    assert len(plain) == len(traced) > 0
+    for (b0, p0), (b1, p1) in zip(plain, traced):
+        np.testing.assert_array_equal(b0.obs, b1.obs)
+        np.testing.assert_array_equal(b0.action, b1.action)
+        np.testing.assert_array_equal(p0, p1)

@@ -15,14 +15,15 @@ def test_tracer_spans_and_gauges():
         with tr.span("work"):
             time.sleep(0.002)
     tr.gauge("queue_depth", 5)
-    tr.incr("batches")
-    tr.incr("batches", 2)
+    tr.gauge("queue_depth", 3)
     snap = tr.snapshot()
     assert snap["span.work.count"] == 3
     assert snap["span.work.mean_ms"] >= 1.0
     assert snap["span.work.ewma_ms"] > 0
-    assert snap["gauge.queue_depth"] == 5
-    assert snap["counter.batches"] == 3
+    assert snap["gauge.queue_depth"] == 3      # a gauge keeps the newest
+    # counters went with their last call site (PR 25): counts live in
+    # the telemetry registry, RETRACES and HOST_TRANSFERS
+    assert not any(k.startswith("counter.") for k in snap)
 
 
 def test_tracer_span_records_on_exception():
@@ -40,7 +41,7 @@ def test_tracer_thread_safety():
         for _ in range(200):
             with tr.span("s"):
                 pass
-            tr.incr("n")
+            tr.gauge("last", 1)
 
     threads = [threading.Thread(target=worker) for _ in range(4)]
     for t in threads:
@@ -49,7 +50,7 @@ def test_tracer_thread_safety():
         t.join()
     snap = tr.snapshot()
     assert snap["span.s.count"] == 800
-    assert snap["counter.n"] == 800
+    assert snap["gauge.last"] == 1
 
 
 def test_device_profile_noop_without_dir():

@@ -300,3 +300,76 @@ def test_ram_guard_raises_before_allocating(monkeypatch):
     monkeypatch.setattr(rb, "_available_host_bytes", lambda: 1024)
     with pytest.raises(MemoryError, match="replay ring needs"):
         ReplayBuffer(make_cfg(), action_dim=4)
+
+
+# ------------------------------------------------------------ spans (PR 25)
+
+def _device_buffer(cfg, tracer):
+    from r2d2_tpu.replay.device_ring import DeviceRing
+
+    ring = DeviceRing(cfg, A)
+    return ReplayBuffer(cfg, A, rng=np.random.default_rng(0),
+                        device_ring=ring, tracer=tracer), ring
+
+
+@pytest.mark.parametrize("in_graph_per", [False, True])
+def test_add_records_one_stage_and_one_commit_a_block(in_graph_per):
+    """A ReplayBuffer given a Tracer records replay.stage (outside the
+    lock) and replay.commit (the lock held) once a block; without a tracer
+    — and on a host ring, where nothing is staged — it runs as before."""
+    from _span_log import SpanLog
+    from r2d2_tpu.utils.trace import Tracer
+
+    cfg = make_cfg(device_replay=True, in_graph_per=in_graph_per)
+    log = SpanLog()
+    buffer, _ = _device_buffer(cfg, Tracer(events=log))
+    fill(buffer, cfg, 5)
+    assert log.count("replay.stage") == log.count("replay.commit") == 5
+    for (s0, sd), (c0, cd) in zip(log.spans["replay.stage"],
+                                  log.spans["replay.commit"]):
+        assert s0 + sd <= c0            # staged before the lock is taken
+    assert not buffer.lock.locked()
+
+    plain, _ = _device_buffer(cfg, None)
+    fill(plain, cfg, 5)
+    assert len(plain) == len(buffer) and plain.block_ptr == buffer.block_ptr
+
+    host_log = SpanLog()
+    host = ReplayBuffer(make_cfg(), A, tracer=Tracer(events=host_log))
+    fill(host, make_cfg(), 3)
+    assert host_log.count("replay.commit") == 3
+    assert host_log.count("replay.stage") == 0
+
+
+@pytest.mark.parametrize("in_graph_per,parent", [
+    (True, "learner.step_dispatch"),    # the lock is taken inside the span
+    (False, "learner.sample_meta"),     # sample_meta takes it, then dispatches
+])
+def test_the_dispatch_threads_lock_wait_is_a_span(in_graph_per, parent):
+    """Every drivetrain that dispatches under buffer.lock records its wait
+    for the lock as learner.lock_wait, once a dispatch, nested in time
+    inside the span that asks for the lock — learner.step_dispatch itself
+    in the in-graph-PER drivetrain."""
+    import jax
+    from _span_log import SpanLog
+    from r2d2_tpu.learner.learner import Learner
+    from r2d2_tpu.learner.step import create_train_state
+    from r2d2_tpu.models.network import create_network, init_params
+    from r2d2_tpu.utils.trace import Tracer
+
+    cfg = make_cfg(device_replay=True, in_graph_per=in_graph_per,
+                   superstep_k=2, training_steps=8, learning_starts=8)
+    log = SpanLog()
+    tracer = Tracer(events=log)
+    buffer, ring = _device_buffer(cfg, tracer)
+    fill(buffer, cfg, 6)
+    net = create_network(cfg, A)
+    learner = Learner(cfg, net, create_train_state(
+        cfg, init_params(cfg, net, jax.random.PRNGKey(1))))
+    metrics = learner.run_device(buffer, ring, tracer=tracer)
+    dispatches = metrics["num_updates"] // cfg.superstep_k
+    assert dispatches == 4
+    assert log.count("learner.step_dispatch") == dispatches
+    assert log.count("learner.lock_wait") == dispatches
+    assert log.inside("learner.lock_wait", parent)
+    assert not buffer.lock.locked()

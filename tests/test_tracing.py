@@ -405,3 +405,277 @@ def test_train_e2e_tracez_capture_process_transport_sharded(tmp_path):
     names = {e["name"] for e in evs}
     assert {"block.env_steps+cut", "ingest.block", "replay.route",
             "replay.sample", "replay.priority_feedback"} <= names
+
+
+# ------------------------------------- the profiler's clock (PR 25, §2)
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler's annotation classes: no profiler runs
+    on the CPU here; the test needs to see what a span constructs."""
+
+    made = []
+
+    def __init__(self, name, **kw):
+        self.made.append((type(self).__name__, name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _FakeStepAnnotation(_FakeAnnotation):
+    pass
+
+
+@pytest.fixture
+def fake_annotations(monkeypatch):
+    import jax
+
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                        _FakeStepAnnotation)
+    return _FakeAnnotation.made
+
+
+def test_span_annotates_only_under_an_open_device_profile(
+        fake_annotations, monkeypatch):
+    """Closed, Tracer.span constructs no annotation; under the flag an
+    open device_profile() sets it opens a TraceAnnotation of its own name,
+    and a span given a step also the profiler's step marker."""
+    from r2d2_tpu.utils import trace
+
+    tr = Tracer(events=None)
+    assert trace._profile_open is False
+    with tr.span("actor.act"):
+        pass
+    with tr.span("learner.step_dispatch", 7):
+        pass
+    assert fake_annotations == []
+
+    monkeypatch.setattr(trace, "_profile_open", True)
+    with tr.span("actor.act"):
+        pass
+    with tr.span("learner.step_dispatch", 7):
+        pass
+    assert fake_annotations == [
+        ("_FakeAnnotation", "actor.act", {}),
+        ("_FakeStepAnnotation", "dispatch", {"step_num": 7}),
+        ("_FakeAnnotation", "learner.step_dispatch", {})]
+    # the spans themselves are recorded either way
+    assert tr.snapshot()["span.actor.act.count"] == 2
+
+
+def test_device_profile_sets_the_flag_and_leaves_a_clock_mark(
+        fake_annotations, monkeypatch, tmp_path):
+    """device_profile() opens the flag for its body only (cleared on an
+    exception too), writes one sync annotation and records the
+    perf_counter read inside it beside the dump."""
+    import jax
+
+    from r2d2_tpu.utils import trace
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: calls.append(("start", d, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    t_before = time.perf_counter()
+    with pytest.raises(RuntimeError):
+        with trace.device_profile(str(tmp_path)):
+            assert trace._profile_open is True
+            with Tracer(events=None).span("learner.publish"):
+                pass
+            raise RuntimeError("x")
+    assert trace._profile_open is False
+    assert [c[0] for c in calls] == ["start", "stop"]
+    # the profiler's own Python tracer stays off: the spans are the
+    # host's timeline
+    assert calls[0][2]["profiler_options"].python_tracer_level == 0
+    assert fake_annotations == [
+        ("_FakeAnnotation", trace.PROFILE_SYNC, {}),
+        ("_FakeAnnotation", "learner.publish", {})]
+    with open(tmp_path / trace.PROFILE_SYNC_FILE) as f:
+        mark = json.load(f)
+    assert mark["annotation"] == trace.PROFILE_SYNC
+    assert t_before <= mark["perf_counter"] <= time.perf_counter()
+
+
+def test_a_real_profile_holds_the_spans_on_their_own_threads(tmp_path):
+    """End to end on the CPU backend's profiler: spans of two threads land
+    on two lines of the dump's host plane, beside the sync annotation."""
+    from jax.profiler import ProfileData
+
+    from r2d2_tpu.utils.trace import PROFILE_SYNC, device_profile
+
+    tr = Tracer(events=None)
+
+    def actor():
+        for _ in range(3):
+            with tr.span("actor.act"):
+                time.sleep(0.001)
+
+    with device_profile(str(tmp_path)):
+        t = threading.Thread(target=actor)
+        t.start()
+        for n in range(3):
+            with tr.span("learner.step_dispatch", n):
+                time.sleep(0.001)
+        t.join(timeout=30)
+        assert not t.is_alive()
+    (path,) = (tmp_path / "plugins" / "profile").glob("*/*.xplane.pb")
+    lines = {}      # span name -> the lines of the host plane that hold it
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                lines.setdefault(ev.name, set()).add(i)
+    assert PROFILE_SYNC in lines
+    assert len(lines["actor.act"]) == len(lines["learner.step_dispatch"]) == 1
+    assert lines["actor.act"] != lines["learner.step_dispatch"]
+    assert lines["dispatch"] == lines["learner.step_dispatch"]
+
+
+def test_maybe_span_and_held():
+    """The shared helpers: no tracer, no span (one shared no-op); held()
+    takes the lock, records the wait, and releases on an exception."""
+    from r2d2_tpu.utils.trace import held, maybe_span
+
+    assert maybe_span(None, "a.b") is maybe_span(None, "c.d")
+    with maybe_span(None, "a.b"):
+        pass
+    tr = Tracer(events=None)
+    with maybe_span(tr, "a.b"):
+        pass
+    assert tr.snapshot()["span.a.b.count"] == 1
+
+    lock = threading.Lock()
+    with pytest.raises(ValueError):
+        with held(lock, tr, "x.wait"):
+            assert lock.locked()
+            raise ValueError("x")
+    assert not lock.locked()
+    assert tr.snapshot()["span.x.wait.count"] == 1
+    with held(lock, None, "x.wait"):
+        assert lock.locked()
+    assert not lock.locked()
+
+    # a contended lock: the wait span covers the time the holder kept it
+    lock.acquire()
+    threading.Timer(0.05, lock.release).start()
+    with held(lock, tr, "y.wait"):
+        pass
+    assert tr.snapshot()["span.y.wait.mean_ms"] >= 30.0
+
+
+# ----------------------------------- tools/step_split.py (PR 25, §3)
+
+def _step_split():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "step_split.py")
+    spec = importlib.util.spec_from_file_location("step_split", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("path,scope", [
+    ("jit(super_step)/jit(main)/while/body/ring_gather/gather:", "ring_gather"),
+    ("jit(super_step)/while/body/jvp(R2D2Network.unroll)/torso/"
+     "R2D2Network._features/torso/conv_general_dilated:", "torso"),
+    ("jit(super_step)/while/body/transpose(jvp(R2D2Network.unroll))/core/"
+     "R2D2Network._lstm_stack/lstm_0/while/body/dot_general:", "core.bwd"),
+    ("jit(super_step)/while/body/transpose(jvp(loss))/mul:", "loss.bwd"),
+    # the outermost scope wins: the target net's torso is target_forward,
+    # the fused loop's act forward is act
+    ("jit(super_step)/while/body/jvp(target_forward)/R2D2Network.unroll/"
+     "torso/relu:", "target_forward"),
+    ("jit(super_step)/while/body/while/body/act/R2D2Network.act/core/"
+     "lstm_0/dot_general:", "act"),
+    ("jit(super_step)/while/body/optimizer/jit(_where)/select_n:",
+     "optimizer"),
+    # a scope is a whole component: `core_details` is not `core`
+    ("jit(super_step)/while/body/core_details/add:", "(none)"),
+    ("jit(super_step)/while/body/dynamic_slice:", "(none)"),
+    ("", "(none)"),
+    (None, "(none)"),
+])
+def test_step_split_files_an_operation_under_its_outermost_scope(path, scope):
+    assert _step_split().scope_of(path) == scope
+
+
+def test_step_split_shares_sum_to_the_busy_time():
+    """The reduction on a hand-made line: a container keeps only its self
+    time, an operation with no path is (none), shares are of the busy
+    union and sum to 100."""
+    ss = _step_split()
+    ms = 1_000_000
+
+    def ev(start, dur, path=None):
+        return dict(name=f"%op.{start}", start_ns=start * ms,
+                    dur_ns=dur * ms, path=path)
+
+    events = [
+        ev(0, 40, "jit(super_step)/while:"),                  # container
+        ev(0, 10, "jit(super_step)/while/body/ring_gather/gather:"),
+        ev(10, 20, "jit(super_step)/while/body/jvp(x)/core/dot_general:"),
+        ev(30, 6, "jit(super_step)/while/body/transpose(jvp(x))/core/dot:"),
+        ev(36, 2),                                            # no metadata
+        ev(50, 12, "jit(super_step)/while/body/optimizer/add:"),  # a gap before
+    ]
+    shares = ss.split(events)
+    assert shares == pytest.approx({
+        "core": 100 * 20 / 52, "optimizer": 100 * 12 / 52,
+        "ring_gather": 100 * 10 / 52, "core.bwd": 100 * 6 / 52,
+        "(none)": 100 * (2 + 2) / 52})
+    assert list(shares)[0] == "core"            # largest first
+    assert sum(shares.values()) == pytest.approx(100.0)
+    assert ss.split([]) == {}
+
+
+def test_step_split_reads_the_scope_from_the_events_metadata(tmp_path):
+    """A hand-made XSpace as the TPU writes it (probed on the chip, PR 25):
+    the op_name path is the stat ``tf_op`` of the event's METADATA, times
+    are picoseconds from the line's timestamp."""
+    ss = _step_split()
+    pb = ss._xplane_pb2()
+    space = pb.XSpace()
+    host = space.planes.add(name="/host:CPU")
+    host.lines.add(name="python")
+    plane = space.planes.add(name="/device:TPU:0")
+    plane.stat_metadata[1].name = "tf_op"
+    plane.stat_metadata[2].name = "hlo_category"
+    for mid, (name, path) in enumerate([
+            ("%fusion.1 = bf16[8] fusion(...)",
+             "jit(super_step)/while/body/jvp(x)/torso/conv:"),
+            ("%fusion.2 = f32[8] fusion(...)",
+             "jit(super_step)/while/body/optimizer/add:"),
+            ("%copy.3 = u8[8] copy(...)", None)], start=1):
+        md = plane.event_metadata[mid]
+        md.id, md.name = mid, name
+        md.stats.add(metadata_id=2, str_value="fusion")
+        if path:
+            md.stats.add(metadata_id=1, str_value=path)
+    modules = plane.lines.add(name="XLA Modules", timestamp_ns=1000)
+    modules.events.add(metadata_id=1, offset_ps=0, duration_ps=9_000_000)
+    ops = plane.lines.add(name="XLA Ops", timestamp_ns=1000)
+    for mid, off_ns, dur_ns in [(1, 0, 6000), (2, 6000, 3000), (3, 9000, 1000),
+                                (1, 20000, 10000)]:
+        ops.events.add(metadata_id=mid, offset_ps=off_ns * 1000,
+                       duration_ps=dur_ns * 1000)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+
+    events = ss.load_ops(str(path))
+    assert [(e["start_ns"], e["dur_ns"]) for e in events] == [
+        (1000, 6000), (7000, 3000), (10000, 1000), (21000, 10000)]
+    assert events[2]["path"] is None
+    assert ss.split(events) == pytest.approx(
+        {"torso": 80.0, "optimizer": 15.0, "(none)": 5.0})
+    assert ss.load_ops(str(path), device=1) == []
+    assert ss.main([str(path), "--json"]) == 0
