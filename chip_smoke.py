@@ -94,15 +94,16 @@ def _child_device() -> dict:
 def _ring_gather_check(cfg, A: int) -> dict:
     """The device ring's in-graph gather against numpy indexing of the same
     slots, inside a k-step scan like the super-step's: random full-length
-    blocks in the first, a middle and the LAST slot of the smoke's ring,
-    windows reaching the blocks' late rows.  Pure data movement, so the
-    comparison is exact.  (Bring-up found a ring layout whose gather read
-    out of bounds only for late rows — finite losses alone cannot see a
-    gather that reads the wrong bytes.)"""
+    blocks, staged as ``DeviceRing.stage`` stages them, in the first, a
+    middle and the LAST slot of the smoke's ring, windows reaching as far
+    past the blocks' last rows as the sampler's windows can.  Pure data
+    movement, so the comparison is exact.  (Bring-up found a ring layout
+    whose gather read out of bounds only for late rows — finite losses
+    alone cannot see a gather that reads the wrong bytes.)"""
     import jax
     import numpy as np
 
-    from r2d2_tpu.replay.device_ring import DeviceRing, gather_batch
+    from r2d2_tpu.replay.device_ring import TIME_KEYS, DeviceRing, gather_batch
 
     ring = DeviceRing(cfg, A)
     rng = np.random.default_rng(1)
@@ -112,20 +113,21 @@ def _ring_gather_check(cfg, A: int) -> dict:
     host = {}
     for ptr in slots:
         blk = {}
-        for name, a in ring.arrays.items():
-            shape, dt = a.shape[1:], a.dtype
+        for name, (shape, dt) in ring._slot_shapes.items():
             blk[name] = (rng.integers(0, 2, shape).astype(bool)
                          if dt == np.bool_ else
                          rng.integers(0, 256, shape).astype(dt)
                          if dt == np.uint8 else
                          rng.normal(size=shape).astype(dt))
+            if name in TIME_KEYS:       # spare rows: copies of the last row
+                blk[name][MS:] = blk[name][MS - 1]
         host[ptr] = blk
         ring.commit({n: jax.device_put(v) for n, v in blk.items()}, ptr)
     k, B = 2, 64
     ints = np.zeros((k, B, 6), np.int32)
     ints[..., 0] = rng.choice(slots, (k, B))
-    ints[..., 1] = rng.integers(0, MS - 1, (k, B))      # t0, late rows too
-    ints[:, 0, 1] = MS - 1                               # the very last row
+    ints[..., 1] = rng.integers(0, BL - L + 1, (k, B))  # t0, late rows too
+    ints[:, 0, 1] = BL - L                  # the latest window a sampler gives
     ints[..., 2] = rng.integers(0, K, (k, B))
     w = rng.random((k, B)).astype(np.float32)
 

@@ -71,7 +71,7 @@ from r2d2_tpu.learner.step import (
     make_train_step,
 )
 from r2d2_tpu.models.network import R2D2Network
-from r2d2_tpu.replay.device_ring import gather_batch
+from r2d2_tpu.replay.device_ring import gather_batch, ring_slots
 from r2d2_tpu.utils.math import epsilon_ladder
 from r2d2_tpu.utils.resilience import Deadline
 from r2d2_tpu.utils.trace import (
@@ -287,11 +287,11 @@ def _make_emit(cfg: Config, action_dim: int, done: bool):
         offs = jnp.cumsum(cut_i) - cut_i              # rank among cut lanes
         slot = jnp.where(cut, (ast["ptr"] + offs) % NB, NB)   # NB = dropped
 
-        # (the ring pads its frame-row axis to whole tiles,
-        # replay/device_ring._slot_shapes: a cut writes its real rows)
-        arrays = {k: (arrays[k].at[slot, :blocks["slot"][k].shape[1]]
-                      if k == "obs" else arrays[k].at[slot]
-                      ).set(blocks["slot"][k], mode="drop")
+        # the ring's own slot format (replay/device_ring._slot_shapes):
+        # frame rows packed into words, and the time fields' spare rows
+        # holding copies of a block's last row
+        cut_blocks = ring_slots(blocks["slot"], arrays)
+        arrays = {k: arrays[k].at[slot].set(cut_blocks[k], mode="drop")
                   for k in arrays}
         leaf = (slot * K)[:, None] + jnp.arange(K)[None, :]
         prios = prios.at[leaf.reshape(-1)].set(
@@ -558,8 +558,9 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
         last_action=jnp.zeros((N, A), jnp.float32),
         last_reward=jnp.zeros(N, jnp.float32),
         hidden=jnp.zeros((N, 2, layers, H), jnp.float32),
-        # frames as flat byte rows, the device ring's slot format
-        # (replay/device_ring._slot_shapes): the cut scatters straight in
+        # frames as flat byte rows, a staged slot's format
+        # (replay/device_ring._slot_shapes): the cut packs them into the
+        # ring's words
         buf_obs=jnp.zeros((N, cap, int(np.prod(obs_shape))), jnp.uint8
                           ).at[:, 0].set(obs0.reshape(N, -1)),
         buf_last_action=jnp.asarray(buf_la),

@@ -8,9 +8,15 @@ The TPU-first redesign inverts the flow:
 - Each experience block crosses H2D **once**, when the actor produces it
   (~3 MB, at block-production rate — orders of magnitude less traffic than
   per-batch staging).
-- The ring arrays (same layout as the host ring, replay_buffer.py) live on
-  the device; batch assembly is an in-graph gather executed at HBM
-  bandwidth inside the jitted train step.
+- The ring arrays (same fields as the host ring, replay_buffer.py) live on
+  the device; batch assembly is an in-graph gather inside the jitted train
+  step: one contiguous time window a sample and field
+  (:func:`gather_batch`), not one fetch a row.  On a v5e the 64 frame
+  windows of a flagship batch (38.4 MB) take 0.12 ms against the 0.094 ms
+  of reading and writing them once at 819 GB/s, the frames transposed
+  and unpacked for the convolution 0.31 ms, and the whole gather 0.44 ms
+  (the row gather it replaced: 0.50 ms for the frames, 0.69 ms in all;
+  PERF.md, PR 26).
 - The host keeps what it is good at: the sum-tree, priorities, ring
   accounting, and stale-index masking.  Only tiny index/weight arrays cross
   per batch.
@@ -75,36 +81,104 @@ from r2d2_tpu.replay.block import Block
 from r2d2_tpu.parallel.sharding import RING_DATA_KEYS as _DATA_KEYS
 
 
-# rows per u8 tile on the TPU: 8 sublanes x 4 bytes packed per 32-bit word
+# rows of the frame ring are kept a multiple of this (see _slot_shapes)
 _OBS_ROW_TILE = 32
+# fields with a time axis of max_block_steps stored rows; gather_batch reads
+# them as windows of seq_len rows
+TIME_KEYS = ("obs", "last_action", "last_reward")
+# bytes of a frame that share four ring words (see pack_frames)
+_FRAME_GROUP = 16
+
+
+def window_tail(cfg: Config) -> int:
+    """Rows by which the latest window runs past a block's last stored row
+    (``max_block_steps - 1``).  The samplers give ``t0 = first_burn_in +
+    seq_idx * learning_steps - burn_in <= block_length - learning_steps``
+    (replay_buffer.sample_meta, learner/step._in_graph_sample_raw), so the
+    longest overrun is ``forward_steps - 1`` rows: the last sequence of a
+    block whose episode went on."""
+    return max(0, cfg.block_length - cfg.learning_steps + cfg.seq_len
+               - cfg.max_block_steps)
+
+
+def frame_words(n_bytes: int) -> int:
+    """Ring words a frame of ``n_bytes`` bytes takes."""
+    return -(-n_bytes // _FRAME_GROUP) * (_FRAME_GROUP // 4)
+
+
+def pack_frames(frames: jnp.ndarray) -> jnp.ndarray:
+    """``u8[..., n_bytes]`` -> ``u32[..., frame_words(n_bytes)]``, the frame
+    ring's row format.  Of every 16 bytes, word ``j`` holds bytes ``j``,
+    ``j + 4``, ``j + 8``, ``j + 12``, lowest first, so that
+    :func:`unpack_frames` gets four byte planes of four consecutive bytes
+    each from shifts alone and rebuilds the frame by laying the planes
+    side by side — the one order of those tried in which the compiler
+    unpacks with the frames already on the minor axis, where the
+    convolution wants them (PERF.md, PR 26)."""
+    *lead, n = frames.shape
+    padded = frame_words(n) * 4
+    if padded != n:
+        frames = jnp.pad(frames, [(0, 0)] * len(lead) + [(0, padded - n)])
+    p = frames.reshape(*lead, padded // _FRAME_GROUP, 4, 4).astype(jnp.uint32)
+    words = (p[..., 0, :] | (p[..., 1, :] << 8) | (p[..., 2, :] << 16)
+             | (p[..., 3, :] << 24))
+    return words.reshape(*lead, padded // 4)
+
+
+def unpack_frames(words: jnp.ndarray, n_bytes: int) -> jnp.ndarray:
+    """Inverse of :func:`pack_frames`: ``u32[..., words]`` ->
+    ``u8[..., n_bytes]``."""
+    *lead, n = words.shape
+    w = words.reshape(*lead, n // 4, 4)
+    planes = [((w >> (8 * k)) & jnp.uint32(0xFF)).astype(jnp.uint8)
+              for k in range(4)]
+    frames = jnp.concatenate(planes, axis=-1).reshape(*lead, n * 4)
+    return frames if n * 4 == n_bytes else frames[..., :n_bytes]
 
 
 def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
-    """Per-slot shapes of the DEVICE ring.  Same fields as the host ring,
-    except for how a block's frames are laid out — two repairs found
-    bringing the super-step up on a v5e (PERF.md Findings, PR 21):
+    """Per-slot shapes of a STAGED block — what :meth:`DeviceRing.stage`
+    puts on the device and the write program takes.  Same fields as the
+    host ring, except for how a block's frames and time rows are laid
+    out:
 
-    - a frame is one FLAT byte row.  XLA answers a gather from a
-      ``(NB, MS, 21, 21, 16)`` ring that feeds a conv by re-laying-out the
-      WHOLE ring for the conv (a padded copy 7x the ring: 21.7 GB for a
-      3.1 GB ring — the super-step does not compile), while gathering
-      flat rows and reshaping the gathered batch costs a 141 MB temp;
-    - the frame-row axis is padded to a whole number of u8 tiles
-      (441 -> 448 rows).  With a ragged row count the compiler lays the
-      ring out block-minor to save the padding, and the gather it emits
-      for that layout inside the k-step loop reads out of bounds — the
-      core halts with an HBM page fault on the first dispatch whose
-      window reaches a block's late rows.  Pad rows are never read
-      (:func:`gather_batch` clamps to ``max_block_steps - 1``).
+    - a frame is one FLAT row (PERF.md Findings, PR 21).  XLA answers a
+      gather from a ``(NB, MS, 21, 21, 16)`` ring that feeds a conv by
+      re-laying-out the WHOLE ring for the conv (a padded copy 7x the
+      ring: 21.7 GB for a 3.1 GB ring — the super-step does not compile);
+    - the frame-row axis is padded to a multiple of 32 rows (441 -> 448).
+      With a ragged row count the compiler lays the ring out block-minor
+      to save the padding, and the gather it emits for that layout inside
+      the k-step loop reads out of bounds — the core halts with an HBM
+      page fault on the first dispatch whose window reaches a block's
+      late rows;
+    - ``last_action`` and ``last_reward`` carry :func:`window_tail` spare
+      rows.  Every spare row of the three time fields holds a COPY of row
+      ``max_block_steps - 1`` (``stage`` and the fused loop's cut write
+      them so): a window of ``seq_len`` rows may touch the first
+      ``window_tail`` of them, only in the positions past the block's
+      last stored row, which the host path fills with that same row by
+      clamping its index — and those positions lie past every index the
+      loss gathers (the INVARIANT note in
+      ``ReplayBuffer._gather_rows``).
 
-    :func:`gather_batch` restores ``cfg.stored_obs_shape``."""
+    In the ring itself (:func:`_ring_shapes`) a frame row is packed into
+    32-bit words (:func:`pack_frames`).  A u8 array's tiles pack four
+    consecutive ROWS into every word, so a window that starts at an
+    arbitrary row has to be re-aligned byte by byte: gathered from a u8
+    ring, rows cost 73 ns each and whole windows no less (0.40 and 0.49 ms
+    a flagship batch).  Words keep a frame's bytes together and rows
+    apart: a window is whole words at a row offset, 0.12 ms a batch
+    (my chip runs, PR 26).  :func:`gather_batch` restores
+    ``cfg.stored_obs_shape`` bytes."""
     MS, BL = cfg.max_block_steps, cfg.block_length
     K, layers, H = cfg.seqs_per_block, cfg.lstm_layers, cfg.hidden_dim
     obs_rows = -(-MS // _OBS_ROW_TILE) * _OBS_ROW_TILE
+    rows = MS + window_tail(cfg)
     return dict(
         obs=((obs_rows, int(np.prod(cfg.stored_obs_shape))), np.uint8),
-        last_action=((MS, action_dim), np.bool_),
-        last_reward=((MS,), np.float32),
+        last_action=((rows, action_dim), np.bool_),
+        last_reward=((rows,), np.float32),
         action=((BL,), np.uint8),
         n_step_reward=((BL,), np.float32),
         n_step_gamma=((BL,), np.float32),
@@ -112,16 +186,46 @@ def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
     )
 
 
+def _ring_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
+    """Per-slot shapes of the ring's own arrays: a staged slot with its
+    frame rows packed into words."""
+    shapes = _slot_shapes(cfg, action_dim)
+    (rows, n_bytes), _ = shapes["obs"]
+    shapes["obs"] = ((rows, frame_words(n_bytes)), np.uint32)
+    return shapes
+
+
 def device_bytes(cfg: Config, action_dim: int) -> int:
-    """Logical bytes of the device ring's arrays (what the capacity guard
-    budgets; HBM holds them at ~1.03x on the v5e)."""
+    """Logical bytes of the device ring's arrays, spare rows included
+    (what the capacity guard budgets; HBM holds them at ~1.03x on the
+    v5e)."""
     return cfg.num_blocks * sum(
         int(np.prod(shape)) * np.dtype(dtype).itemsize
-        for shape, dtype in _slot_shapes(cfg, action_dim).values())
+        for shape, dtype in _ring_shapes(cfg, action_dim).values())
+
+
+def ring_slots(blocks: Dict[str, jnp.ndarray],
+               arrays: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Blocks of N lanes cut on the device (learner/anakin.py; fields
+    (N, ...), frames as flat byte rows, time fields ``max_block_steps``
+    rows long) in the format of the ring ``arrays`` they are written
+    into: frames packed into words, and every time field made as many
+    rows long as its slot, the spare rows copies of the last one (see
+    :func:`_slot_shapes`)."""
+    out = dict(blocks, obs=pack_frames(blocks["obs"]))
+    for k in TIME_KEYS:
+        spare = arrays[k].shape[1] - out[k].shape[1]
+        if spare:
+            out[k] = jnp.concatenate(
+                [out[k], jnp.repeat(out[k][:, -1:], spare, axis=1)], axis=1)
+    return out
 
 
 def _write_slot_fn(arrays: Dict[str, jnp.ndarray],
                    slot: Dict[str, jnp.ndarray], ptr: jnp.ndarray):
+    """One staged slot into the ring: the frames cross the host's link as
+    the bytes they are and are packed into words here, on the device."""
+    slot = dict(slot, obs=pack_frames(slot["obs"]))
     return {k: jax.lax.dynamic_update_index_in_dim(arrays[k], slot[k], ptr,
                                                    axis=0)
             for k in arrays}
@@ -130,38 +234,97 @@ def _write_slot_fn(arrays: Dict[str, jnp.ndarray],
 _write_slot = jax.jit(_write_slot_fn, donate_argnums=(0,))
 
 
+def _windows(arr: jnp.ndarray, block_idx: jnp.ndarray, start: jnp.ndarray,
+             length: int) -> jnp.ndarray:
+    """``arr[block_idx[i], start[i]:start[i] + length]`` for every sample:
+    (NB, rows, ...) -> (B, length, ...), one slice a sample — a gather
+    whose slice is the whole window, so the compiler moves B windows of
+    known length and not B * length rows."""
+    tail = arr.shape[2:]
+
+    def one(b, s):
+        return jax.lax.dynamic_slice(arr, (b, s) + (0,) * len(tail),
+                                     (1, length) + tail)[0]
+
+    return jax.vmap(one)(block_idx, start)
+
+
+def _time_windows(cfg: Config, arr: jnp.ndarray, block_idx: jnp.ndarray,
+                  t0: jnp.ndarray) -> jnp.ndarray:
+    """(B, seq_len, ...): row ``min(t0 + i, max_block_steps - 1)`` of a
+    time field at position ``i``, as the host path's clamp gives it, for
+    ``t0 <= block_length - learning_steps`` (:func:`window_tail`; a later
+    ``t0`` stays inside the array but reads other rows).  Which of two
+    ways follows from the array's row count alone."""
+    T, MS = cfg.seq_len, cfg.max_block_steps
+    rows, tail = arr.shape[1], arr.shape[2:]
+    overrun = window_tail(cfg)
+    pos = t0[:, None] + jnp.arange(T)                         # (B, T)
+
+    def per_row(mask):
+        return mask.reshape(mask.shape + (1,) * len(tail))
+
+    if rows - MS >= overrun:
+        # the longest window ends inside the slot, and what it reads past
+        # row MS - 1 are that row's copies (_slot_shapes): no repair.
+        # The select never takes its zero for such a t0.  It is here for
+        # the compiler: with an elementwise step between the windows and
+        # the unpacking it transposes the frames as words and unpacks them
+        # frames-minor (0.19 ms a flagship batch); without it, it unpacks
+        # first and transposes four byte planes (0.66 ms; PERF.md, PR 26)
+        w = _windows(arr, block_idx, t0, T)
+        return jnp.where(per_row(pos < rows), w, jnp.zeros((), arr.dtype))
+    # no spare rows (a frame ring whose max_block_steps is a multiple of
+    # 32): a late window is taken from where it still fits, moved back to
+    # its place, and its positions past the block's last row repaired
+    # from that row
+    start = jnp.minimum(t0, rows - T)
+    most = MS + overrun - rows                # the largest t0 - start
+    w = _windows(arr, block_idx, start, T)
+    w = jnp.pad(w, ((0, 0), (0, most)) + ((0, 0),) * len(tail))
+    w = jax.vmap(lambda x, s: jax.lax.dynamic_slice(
+        x, (s,) + (0,) * len(tail), (T,) + tail))(w, t0 - start)
+    last = arr[block_idx, MS - 1]
+    return jnp.where(per_row(pos > MS - 1), last[:, None], w)
+
+
 @jax.named_scope("ring_gather")
 def gather_batch(cfg: Config, arrays: Dict[str, jnp.ndarray],
                  ints: jnp.ndarray, is_weights: jnp.ndarray
                  ) -> Dict[str, jnp.ndarray]:
     """In-graph batch assembly — the device twin of
-    ``ReplayBuffer.sample_batch`` (replay_buffer.py), same index arithmetic,
-    same clamp invariant (stale/padded bytes can only occupy positions the
-    loss masks out; see the INVARIANT note there).
+    ``ReplayBuffer.sample_batch`` (replay_buffer.py): the same bytes in
+    the same positions, read as one contiguous window a sample and field
+    where the host indexes row by row (stale/padded bytes can only occupy
+    positions the loss masks out; see the INVARIANT note there).
 
     ``ints`` is (B, 6) int32: [block_idx, t0, seq_idx, burn_in, learning,
-    forward] computed host-side under the buffer lock.
+    forward] computed host-side under the buffer lock or by the in-graph
+    sampler.
     """
-    L, T = cfg.learning_steps, cfg.seq_len
-    block_idx, t0 = ints[:, 0], ints[:, 1]
-    seq_idx = ints[:, 2]
+    L, K = cfg.learning_steps, cfg.seqs_per_block
+    block_idx, t0, seq_idx = ints[:, 0], ints[:, 1], ints[:, 2]
 
-    time_idx = jnp.minimum(t0[:, None] + jnp.arange(T),
-                           cfg.max_block_steps - 1)          # (B, T)
-    bcol = block_idx[:, None]
-    widx = jnp.minimum(seq_idx[:, None] * L + jnp.arange(L),
-                       cfg.block_length - 1)                 # (B, L)
+    def learning_window(arr):
+        # entries [seq_idx * L, (seq_idx + 1) * L) of a block: with
+        # seq_idx < K the host's clamp to block_length - 1 never acts, so
+        # a sequence's window is one fetch, like its stored hidden state
+        return arr.reshape(arr.shape[0], K, L)[block_idx, seq_idx]
+
+    n_bytes = int(np.prod(cfg.stored_obs_shape))
+    obs = unpack_frames(_time_windows(cfg, arrays["obs"], block_idx, t0),
+                        n_bytes)
     return dict(
         # flat frame rows in the ring (see _slot_shapes); the network's
         # frame shape is restored on the gathered batch only
-        obs=arrays["obs"][bcol, time_idx].reshape(
-            *time_idx.shape, *cfg.stored_obs_shape),
-        last_action=arrays["last_action"][bcol, time_idx].astype(jnp.float32),
-        last_reward=arrays["last_reward"][bcol, time_idx],
+        obs=obs.reshape(*obs.shape[:2], *cfg.stored_obs_shape),
+        last_action=_time_windows(cfg, arrays["last_action"], block_idx,
+                                  t0).astype(jnp.float32),
+        last_reward=_time_windows(cfg, arrays["last_reward"], block_idx, t0),
         hidden=arrays["hidden"][block_idx, seq_idx],
-        action=arrays["action"][bcol, widx].astype(jnp.int32),
-        n_step_reward=arrays["n_step_reward"][bcol, widx],
-        n_step_gamma=arrays["n_step_gamma"][bcol, widx],
+        action=learning_window(arrays["action"]).astype(jnp.int32),
+        n_step_reward=learning_window(arrays["n_step_reward"]),
+        n_step_gamma=learning_window(arrays["n_step_gamma"]),
         burn_in=ints[:, 3],
         learning=ints[:, 4],
         forward=ints[:, 5],
@@ -272,7 +435,7 @@ class DeviceRing:
         self._slot_shapes = _slot_shapes(cfg, action_dim)
         self.arrays = {
             k: self._put(np.zeros((NB, *shape), dtype))
-            for k, (shape, dtype) in self._slot_shapes.items()}
+            for k, (shape, dtype) in _ring_shapes(cfg, action_dim).items()}
 
         # --- in-graph PER state (cfg.in_graph_per) ---------------------
         # Leaf priorities (td**alpha; 0 = never-sampleable) plus the
@@ -333,8 +496,11 @@ class DeviceRing:
 
         Short blocks are zero-padded; the padding occupies exactly the
         positions the host ring would leave stale, which the sampling
-        clamp invariant already guarantees are loss-masked.
+        clamp invariant already guarantees are loss-masked.  The spare
+        rows of the time fields get copies of the last row a block can
+        store (see :func:`_slot_shapes`).
         """
+        MS = self.cfg.max_block_steps
         slot = {}
         for k, (shape, dtype) in self._slot_shapes.items():
             arr = np.zeros(shape, dtype)
@@ -345,6 +511,8 @@ class DeviceRing:
                 arr[:src.shape[0]] = src.reshape(src.shape[0], -1)
             else:
                 arr[:src.shape[0]] = src
+            if k in TIME_KEYS:
+                arr[MS:] = arr[MS - 1]
             slot[k] = self._put_slot(arr)
         return slot
 

@@ -42,7 +42,11 @@ from r2d2_tpu.learner.learner import Learner
 from r2d2_tpu.learner.step import create_train_state
 from r2d2_tpu.models.network import create_network, init_params
 from r2d2_tpu.replay.block import LocalBuffer
-from r2d2_tpu.replay.device_ring import DeviceRing
+from r2d2_tpu.replay.device_ring import (
+    TIME_KEYS,
+    DeviceRing,
+    unpack_frames,
+)
 from r2d2_tpu.train import train
 
 A = 4
@@ -250,9 +254,19 @@ def test_anakin_blocks_match_local_buffer_oracle(mode):
     for slot, (blk, pri, _ep) in enumerate(host_blocks):
         n_obs, n_steps = blk.obs.shape[0], blk.action.shape[0]
         k = blk.num_sequences
-        # the device ring stores frames as flat byte rows
-        np.testing.assert_array_equal(blk.obs.reshape(n_obs, -1),
-                                      arrays["obs"][slot][:n_obs])
+        # a cut fills the time fields' spare rows with copies of the last
+        # row a block can store (replay/device_ring._slot_shapes)
+        MS = cfg.max_block_steps
+        for key in TIME_KEYS:
+            rows = arrays[key][slot]
+            assert rows.shape[0] > MS
+            np.testing.assert_array_equal(
+                rows[MS:], np.broadcast_to(rows[MS - 1], rows[MS:].shape))
+        # the device ring stores frames as flat rows packed into words
+        np.testing.assert_array_equal(
+            blk.obs.reshape(n_obs, -1),
+            np.asarray(unpack_frames(arrays["obs"][slot][:n_obs],
+                                     int(np.prod(cfg.stored_obs_shape)))))
         np.testing.assert_array_equal(blk.last_action,
                                       arrays["last_action"][slot][:n_obs])
         np.testing.assert_array_equal(blk.last_reward,

@@ -19,7 +19,11 @@ from r2d2_tpu.parallel.sharding import (
 from r2d2_tpu.replay.device_ring import (
     DeviceRing,
     device_bytes,
+    frame_words,
     gather_batch,
+    pack_frames,
+    unpack_frames,
+    window_tail,
 )
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
 from r2d2_tpu.replay.block import LocalBuffer
@@ -73,13 +77,18 @@ def paired_buffers(cfg, n_blocks=4, seed=0):
 
 def test_device_bytes_matches_ring_allocation():
     """The capacity guard budgets exactly what the ring allocates —
-    including the frame-row axis padded to whole u8 tiles."""
+    including the frame-row axis padded to 32 rows, the frames packed
+    into words and the time fields' spare rows."""
     cfg = make_cfg()
     ring = DeviceRing(cfg, A)
     assert ring.nbytes() == device_bytes(cfg, A)
     rows, width = ring.arrays["obs"].shape[1:]
     assert rows % 32 == 0 and rows >= cfg.max_block_steps
-    assert width == int(np.prod(cfg.stored_obs_shape))
+    assert ring.arrays["obs"].dtype == jnp.uint32
+    assert width == frame_words(int(np.prod(cfg.stored_obs_shape)))
+    for k in ("last_action", "last_reward"):
+        assert ring.arrays[k].shape[1] == (cfg.max_block_steps
+                                           + window_tail(cfg))
 
 
 def test_device_gather_matches_host_sample_batch():
@@ -119,6 +128,119 @@ def test_device_gather_after_ring_overwrite():
     np.testing.assert_array_equal(np.asarray(got["obs"]), host_batch["obs"])
     np.testing.assert_array_equal(np.asarray(got["action"]),
                                   host_batch["action"])
+
+
+# the two benchmark cells' window geometries at a small frame size: the
+# flagship's 441 stored rows are not a multiple of the ring's 32-row
+# padding (7 spare rows hold the 4-row overrun), the deep torso's 416 are
+# (no spare row: the late window is moved back and repaired)
+CELL_GEOMETRIES = dict(
+    fabric=dict(burn_in_steps=40, learning_steps=40, forward_steps=5,
+                block_length=400),
+    impala=dict(burn_in_steps=40, learning_steps=75, forward_steps=5,
+                block_length=375),
+)
+
+
+def ints_for(buf, idxes):
+    """``sample_meta``'s index arithmetic for chosen leaves."""
+    cfg = buf.cfg
+    K, L = cfg.seqs_per_block, cfg.learning_steps
+    block_idx, seq_idx = idxes // K, idxes % K
+    burn = buf.burn_in_steps[block_idx, seq_idx].astype(np.int64)
+    start = buf.first_burn_in[block_idx] + seq_idx * L
+    return np.stack(
+        [block_idx, start - burn, seq_idx, burn,
+         buf.learning_steps[block_idx, seq_idx],
+         buf.forward_steps[block_idx, seq_idx]], axis=1).astype(np.int32)
+
+
+@pytest.mark.parametrize("mode", ["jit", "scan"])
+@pytest.mark.parametrize("geometry", sorted(CELL_GEOMETRIES))
+def test_windowed_gather_equals_host_where_windows_overrun(geometry, mode):
+    """Every field of the device gather equals the host's ``sample_batch``
+    arithmetic for windows that run past the block's last stored row —
+    the last sequence of a short first block (no burn-in prefix) and of
+    full blocks — jitted, and inside a ``lax.scan`` of k = 2 like the
+    super-step's."""
+    cfg = make_cfg(buffer_capacity=4 * CELL_GEOMETRIES[geometry][
+        "block_length"], **CELL_GEOMETRIES[geometry])
+    host, dev, ring = paired_buffers(cfg, n_blocks=3)
+    K, T, MS = cfg.seqs_per_block, cfg.seq_len, cfg.max_block_steps
+    spare = ring.arrays["obs"].shape[1] - MS
+    assert (spare >= window_tail(cfg)) == (geometry == "fabric")
+    assert host.first_burn_in[0] == 0 and (
+        host.first_burn_in[1] == cfg.burn_in_steps)
+
+    # first, middle and LAST sequence of each block, twice over for k = 2
+    idxes = np.array([[b * K + q for b in range(3) for q in (0, K // 2,
+                                                             K - 1)],
+                      [b * K + q for b in (2, 0, 1) for q in (K - 1, 1,
+                                                              K - 2)]])
+    ints = np.stack([ints_for(dev, i) for i in idxes])
+    assert (ints[..., 1] + T).max() == MS + window_tail(cfg) > MS
+    w = np.random.default_rng(3).random(idxes.shape).astype(np.float32)
+
+    if mode == "jit":
+        fn = jax.jit(jax.vmap(
+            lambda arrs, i, ww: gather_batch(cfg, arrs, i, ww),
+            in_axes=(None, 0, 0)))
+    else:
+        fn = jax.jit(lambda arrs, i, ww: jax.lax.scan(
+            lambda c, x: (c, gather_batch(cfg, arrs, *x)), 0, (i, ww))[1])
+    got = jax.device_get(fn(ring.snapshot(), ints, w))
+    for j in range(idxes.shape[0]):
+        want = host._gather_rows(idxes[j])
+        for key in ("obs", "last_action", "last_reward", "hidden", "action",
+                    "n_step_reward", "n_step_gamma", "burn_in", "learning",
+                    "forward"):
+            np.testing.assert_array_equal(
+                got[key][j], np.asarray(want[key]),
+                err_msg=f"{geometry}/{mode}: field {key}, bundle {j}")
+        np.testing.assert_array_equal(got["is_weights"][j], w[j])
+
+
+def _gathers(jaxpr):
+    """Every ``gather`` equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _gathers(sub)
+
+
+def test_gather_batch_fetches_windows_not_rows():
+    """What the gather's speed rests on (PERF.md, PR 26): every ring field
+    is fetched with one index a SAMPLE (a window of rows, a sequence's
+    learning entries, a stored state), never one a row — no gather of the
+    traced function has B * T or B * L index rows."""
+    cfg = make_cfg(buffer_capacity=4 * 400, **CELL_GEOMETRIES["fabric"])
+    B = 8
+    ring = DeviceRing(cfg, A)
+    jaxpr = jax.make_jaxpr(
+        lambda arrs, i, w: gather_batch(cfg, arrs, i, w))(
+            ring.snapshot(), jnp.zeros((B, 6), jnp.int32),
+            jnp.ones((B,), jnp.float32))
+    seen = list(_gathers(jaxpr.jaxpr))
+    # obs, last_action, last_reward, hidden, action, two n-step fields
+    assert len(seen) >= 7
+    for eqn in seen:
+        index_rows = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+        assert index_rows <= B, (
+            f"a gather with {index_rows} index rows: {eqn}")
+
+
+@pytest.mark.parametrize("n_bytes", [144, 7056, 50])
+def test_frame_words_round_trip(n_bytes):
+    """``unpack_frames`` undoes ``pack_frames`` for widths that are and
+    are not whole 16-byte groups."""
+    frames = np.random.default_rng(n_bytes).integers(
+        0, 256, (3, 5, n_bytes), np.uint8)
+    words = pack_frames(jnp.asarray(frames))
+    assert words.dtype == jnp.uint32
+    assert words.shape == (3, 5, frame_words(n_bytes))
+    np.testing.assert_array_equal(
+        np.asarray(unpack_frames(words, n_bytes)), frames)
 
 
 def test_sample_batch_raises_on_device_buffer():
