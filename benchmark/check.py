@@ -10,12 +10,29 @@ by standing far off.  ``python3 benchmark/check.py --config <name>`` prints
 what the limits stand on."""
 from __future__ import annotations
 
-import importlib
 from typing import Any, Dict, List
+
+from benchmark.manifest import BENCH_DIR, find_module
 
 SEQUENCES = 4           # windows in a draw's batch
 DRAWS = 4               # seeded draws of weights and batch pooled in a run
 DRAW_STRIDE = 104729    # between the draws' seeds
+
+
+def seeded_state(cfg, n: int, rng):
+    """``n`` small seeded recurrent states, in the shape and tree of the
+    program's own ``zero_hidden(cfg, n)``: whatever the network's memory
+    core keeps, the harness does not restate it.  Leaves are drawn in the
+    tree's order."""
+    import jax
+    import numpy as np
+
+    from r2d2_tpu.models.network import zero_hidden
+
+    return jax.tree.map(
+        lambda leaf: (0.1 * rng.normal(size=leaf.shape)).astype(
+            np.dtype(leaf.dtype)),
+        jax.eval_shape(lambda: zero_hidden(cfg, n)))
 
 
 def seeded_batch(cfg, action_dim: int, seed: int) -> Dict[str, Any]:
@@ -35,8 +52,7 @@ def seeded_batch(cfg, action_dim: int, seed: int) -> Dict[str, Any]:
         obs=rng.integers(0, 256, (B, T, *cfg.stored_obs_shape), np.uint8),
         last_action=la,
         last_reward=rng.integers(0, 2, (B, T)).astype(np.float32),
-        hidden=(0.1 * rng.normal(size=(B, 2, cfg.lstm_layers,
-                                       cfg.hidden_dim))).astype(np.float32),
+        hidden=seeded_state(cfg, B, rng),
         action=rng.integers(action_dim, size=(B, L)).astype(np.int32),
         n_step_reward=rng.random((B, L)).astype(np.float32) * 3.0,
         n_step_gamma=np.full((B, L), cfg.gamma ** cfg.forward_steps,
@@ -86,14 +102,18 @@ def make_program(cfg, net):
     return program
 
 
-def reference_outputs(config_name: str, cfg, params, target, batch):
+def reference_outputs(config_name: str, cfg, params, target, batch,
+                      bench_dir: str = BENCH_DIR):
+    """The configuration's plain reference, ``reference/<config>.py``,
+    over the same weights and batch."""
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"benchmark.reference.{config_name}")
+    ref = find_module("reference", config_name, bench_dir)
     with jax.default_matmul_precision("highest"):
         dev = {k: (v if k in ("burn_in", "learning", "forward")
-                   else jnp.asarray(v)) for k, v in batch.items()}
+                   else jax.tree.map(jnp.asarray, v))
+               for k, v in batch.items()}
         return jax.device_get(ref.loss(params, target, dev,
                                        cfg.forward_steps))
 
@@ -129,7 +149,7 @@ def errors(pairs) -> Dict[str, float]:
 
 
 def draws(config_name: str, cfg, action_dim: int, seed: int,
-          weights=None, count: int = DRAWS):
+          weights=None, count: int = DRAWS, bench_dir: str = BENCH_DIR):
     """``count`` seeded draws of weights and batch, each through the program
     and the reference; ``weights`` (optional) is applied to the program's
     copy of the weights only."""
@@ -146,26 +166,38 @@ def draws(config_name: str, cfg, action_dim: int, seed: int,
                  else jax.tree.map(weights, (params, target)))
         pairs.append((jax.device_get(program(*shown, batch)),
                       reference_outputs(config_name, cfg, params, target,
-                                        batch)))
+                                        batch, bench_dir)))
     return pairs
 
 
+COMPARED = (("q_rel", "the largest Q-value difference is"),
+            ("q_rms_rel", "the rms Q-value difference is"),
+            ("loss_rel", "the loss differs from the reference by"))
+
+
+def over_limit(out: Dict[str, float], tolerance: Dict[str, Any]) -> List[str]:
+    """The numbers that pass their limit.  A number the configuration's
+    ``tolerance`` gives no limit is not compared: one whose sound readings
+    and whose control's do not lie three times apart can only fail sound
+    runs, and the file says so with the readings (PERF.md §6, PR 27)."""
+    return [f"{what} {out[key]:.4g} (limit {tolerance[key]})"
+            for key, what in COMPARED
+            if key in tolerance and not out[key] <= tolerance[key]]
+
+
 def compare(config_name: str, cfg, tolerance: Dict[str, Any],
-            action_dim: int, seed: int) -> Dict[str, Any]:
+            action_dim: int, seed: int,
+            bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
     """``{"problems": [...], "q_rel": ..., "loss_rel": ...}``."""
     import numpy as np
 
-    pairs = draws(config_name, cfg, action_dim, seed)
+    pairs = draws(config_name, cfg, action_dim, seed, bench_dir=bench_dir)
     out = errors(pairs)
     problems: List[str] = []
     if not all(np.isfinite(q).all() and np.isfinite(loss)
                for (loss, q), _ in pairs):
         problems.append("the program's Q-values or loss are not finite")
-    for key, what in (("q_rel", "the largest Q-value difference is"),
-                      ("q_rms_rel", "the rms Q-value difference is"),
-                      ("loss_rel", "the loss differs from the reference by")):
-        if not out[key] <= tolerance[key]:
-            problems.append(f"{what} {out[key]:.4g} (limit {tolerance[key]})")
+    problems += over_limit(out, tolerance)
     return dict(out, problems=problems)
 
 
@@ -189,9 +221,7 @@ def main(argv=None) -> int:
     from benchmark.drivers.train import ACTION_DIM, config_from_file, seed32
     from benchmark.manifest import Manifest
 
-    m = Manifest(root)
-    with open(os.path.join(root, m.configs[args.config]["file"])) as f:
-        doc = json.load(f)
+    doc = Manifest(root).config(args.config)
     cfg = config_from_file(doc["config"])
     seed = seed32(args.seed)
     print(json.dumps(dict(

@@ -5,7 +5,7 @@ result ``run.py`` prints.  Host actors (``"host_envs": true``) step the
 benchmark's envs; otherwise the traffic file's overrides pick the fused loop.
 
 The program is taken as it is — entry point, spans, counters, kernel names.
-Three names of ``r2d2_tpu.train`` are wrapped for the length of the call, a
+Two names of ``r2d2_tpu.train`` are wrapped for the length of the call, a
 stop-gap each until the program offers the seam (PERF.md, Open questions).
 A run in which ``train()`` never called a name that was wrapped is not
 ``correct``, so a rename in the program cannot pass unseen:
@@ -22,10 +22,7 @@ A run in which ``train()`` never called a name that was wrapped is not
   deployment's, and 64 host actors would need two minutes to fill it.  The
   buffer ``train()`` builds is filled, before ``train()`` starts its actors,
   with seeded blocks cut by the program's own ``assemble_block`` and written
-  through its own ``ReplayBuffer.add``;
-- ``make_act_fn``, only in a traced run of a cell that asks for it
-  (``act_timer``): a timer around the actors' batched act call, which the
-  program has no span for.
+  through its own ``ReplayBuffer.add``.
 """
 from __future__ import annotations
 
@@ -40,18 +37,19 @@ from typing import Any, Callable, Dict, List, Optional
 
 from benchmark import check, window
 
-# sizes a CPU rehearsal runs at: control flow and shapes-by-name only,
-# never a speed.  Widths are cut here and nowhere else (frames keep
-# their shape: the torsos need it).
-REHEARSAL = dict(hidden_dim=32, batch_size=8, burn_in_steps=4,
-                 learning_steps=4, forward_steps=2, block_length=8,
-                 compute_dtype="float32", pallas_interpret=True)
+# what a CPU rehearsal changes besides the configuration file's ``small``
+# sizes (the model's widths and lengths are the file's to cut): control
+# flow and shapes-by-name only, never a speed
+REHEARSAL = dict(compute_dtype="float32", pallas_interpret=True)
 REHEARSAL_BLOCKS = 64
 REHEARSAL_LANES = 4
 PROGRAM_SEED = 0        # cfg.seed of every run: see the module docstring
 ACTION_DIM = 4          # the fake envs' action set (envs/fake.py, envs/anakin.py)
 BACKSTOP_SECONDS = 900  # train()'s own limit; stop_fn ends the run long before
 PREFILL_TEMPLATES = 8   # distinct seeded blocks the pre-fill cycles through
+# Config fields build_config works out from the traffic file's shares and
+# block counts, whatever the configuration file or the preset says
+DERIVED_KEYS = ("learning_starts", "anakin_episode_len")
 
 
 def seed32(seed: int) -> int:
@@ -73,6 +71,23 @@ def config_from_file(config: Dict[str, Any]):
                   seed=PROGRAM_SEED)
 
 
+def preset_config(doc: Dict[str, Any], small: bool = False, **kw):
+    """The program's own preset that a configuration file names under
+    ``preset`` (``{"function": <a function of r2d2_tpu.config>, "kwargs":
+    {...}}``), with the file's ``small`` overrides if asked and then
+    ``kw``: what the file's ``config`` is held against, and the sizes the
+    CPU tests run a configuration at."""
+    from r2d2_tpu import config as program_config
+
+    preset = doc["preset"]
+    kwargs = dict(preset.get("kwargs", {}))
+    if small:
+        kwargs.update(doc["small"])
+    kwargs.update(kw)
+    return getattr(program_config, preset["function"])(
+        **{k: _tuples(v) for k, v in kwargs.items()})
+
+
 def build_config(cell, rehearsal: bool):
     """The program's ``Config`` for this cell: the configuration file, then
     the traffic file's overrides."""
@@ -80,10 +95,11 @@ def build_config(cell, rehearsal: bool):
     kw = dict(cell.config["config"])
     kw.update(traffic.get("config_overrides", {}))
     if rehearsal:
+        kw.update(cell.config["small"])
         kw.update(REHEARSAL,
                   num_actors=min(kw["num_actors"], REHEARSAL_LANES),
                   env_workers=min(kw.get("env_workers", 0), 2),
-                  buffer_capacity=REHEARSAL_BLOCKS * REHEARSAL["block_length"])
+                  buffer_capacity=REHEARSAL_BLOCKS * kw["block_length"])
     # lengths the traffic file gives as shares of the ring or in blocks, so
     # that one file serves configurations of different sizes
     kw["learning_starts"] = int(traffic["learning_starts_ring_share"]
@@ -104,7 +120,6 @@ class TrainFacts:
     compiles_in_window: List[str]           # JAX's log lines, if any
     trace_dir: Optional[str] = None         # the profiler slice, if traced
     t_mark: Optional[float] = None          # clock-sync annotation, perf
-    act_timer: Optional["ActTimer"] = None
     unused_wraps: List[str] = dataclasses.field(default_factory=list)
     wall_minus_perf: float = 0.0            # time.time() - perf_counter()
 
@@ -156,33 +171,6 @@ class CompileLog:
         return [m for t, m in self.events if t_lo <= t <= t_hi]
 
 
-class ActTimer:
-    """Wraps the actors' jitted act: host time of one batched call to the
-    fetched outputs.  Installed only in a traced run."""
-
-    def __init__(self, fn):
-        self._fn = fn
-        self.device = fn.device
-        self.lstm_impl = fn.lstm_impl
-        self.compute_dtype = fn.compute_dtype
-        self.calls: List[tuple] = []        # (t_end, seconds)
-
-    def __call__(self, *args):
-        import numpy as np
-
-        t0 = time.perf_counter()
-        out = self._fn(*args)
-        for x in out:
-            np.asarray(x)   # the fetch the actor makes next; cached after
-        t1 = time.perf_counter()
-        self.calls.append((t1, t1 - t0))
-        return out
-
-    def mean_us(self, t_lo: float, t_hi: float) -> Optional[float]:
-        durs = [d for t, d in self.calls if t_lo <= t <= t_hi]
-        return 1e6 * sum(durs) / len(durs) if durs else None
-
-
 def prefill_blocks(cfg, seed: int, count: int = PREFILL_TEMPLATES):
     """``count`` seeded full blocks of mid-episode experience, cut by the
     program's own block math: frames from the traffic env under seeded
@@ -211,8 +199,7 @@ def prefill_blocks(cfg, seed: int, count: int = PREFILL_TEMPLATES):
         out.append(assemble_block(
             cfg, obs=np.stack(obs), last_action=last_action,
             last_reward=np.asarray(rewards, np.float32),
-            hidden_stream=(0.1 * rng.normal(size=(
-                n, 2, cfg.lstm_layers, cfg.hidden_dim))).astype(np.float32),
+            hidden_stream=check.seeded_state(cfg, n, rng),
             actions=actions[prefix:].astype(np.uint8),
             rewards=np.asarray(rewards[prefix + 1:], np.float32),
             qvals=rng.normal(size=(size + 1, ACTION_DIM)).astype(np.float32),
@@ -279,7 +266,7 @@ def run_train(cell, args, cfg, t_start_perf: float,
     compiles.install()
     train_mod = importlib.import_module("r2d2_tpu.train")
     real = {n: getattr(train_mod, n)
-            for n in ("init_params", "ReplayBuffer", "make_act_fn")}
+            for n in ("init_params", "ReplayBuffer")}
     calls: Dict[str, int] = {}      # wrapped name -> times train() called it
 
     def seeded_init(cfg, net, key):
@@ -301,15 +288,6 @@ def run_train(cell, args, cfg, t_start_perf: float,
 
         calls["ReplayBuffer"] = 0
         train_mod.ReplayBuffer = prefilled_buffer
-    act_timer: List[ActTimer] = []
-    if traced and traffic.get("act_timer"):
-        def timed_make(*a, **kw):
-            calls["make_act_fn"] += 1
-            act_timer.append(ActTimer(real["make_act_fn"](*a, **kw)))
-            return act_timer[-1]
-
-        calls["make_act_fn"] = 0
-        train_mod.make_act_fn = timed_make
     prof: Dict[str, Any] = {}
     done = threading.Event()
     slicer = None
@@ -342,7 +320,6 @@ def run_train(cell, args, cfg, t_start_perf: float,
                             if win else []),
         trace_dir=prof.get("trace_dir"),
         t_mark=prof.get("t_mark"),
-        act_timer=act_timer[0] if act_timer else None,
         unused_wraps=sorted(n for n, c in calls.items() if not c),
         wall_minus_perf=wall_minus_perf)
 
@@ -395,6 +372,38 @@ def end_to_end(facts: TrainFacts) -> Dict[str, float]:
     return out
 
 
+def dispatch_gaps(sink: window.DispatchSink) -> Optional[Dict[str, float]]:
+    """How evenly the window's dispatches completed: the median time from
+    one completion to the next, the longest, and the seconds by which the
+    gaps over twice the median passed it — the time a stall took out of
+    the window.  Not a metric: it rides on the line so that a run that reads
+    far off says whether it stalled or ran slower throughout (PERF.md §6,
+    PR 27)."""
+    import statistics
+
+    win = sink.window()
+    if win is None:
+        return None
+    ends = sink.ends[win[0]:win[1] + 1]
+    gaps = [b - a for a, b in zip(ends, ends[1:])]
+    median = statistics.median(gaps)
+    long = [g for g in gaps if g > 2 * median]
+    return dict(median_ms=1e3 * median, longest_ms=1e3 * max(gaps),
+                over_twice_median=len(long),
+                stalled_s=sum(g - median for g in long))
+
+
+def ring_obs(cfg, chips: int = 1):
+    """The frame ring on one chip as the trace names an array, dtype and
+    dimensions, from the program's own ring: ``("u32", (blocks, rows, words
+    a frame))`` since PR 26."""
+    from benchmark import xplane
+    from r2d2_tpu.replay.device_ring import _ring_shapes
+
+    slot, dtype = _ring_shapes(cfg, ACTION_DIM)["obs"]
+    return xplane.hlo_dtype(dtype), (cfg.num_blocks // chips, *slot)
+
+
 def traced_parts(cell, facts: TrainFacts, device: Dict[str, Any]):
     """Per-layer metrics, the device's busy time and the breakdown of a
     traced run."""
@@ -410,19 +419,18 @@ def traced_parts(cell, facts: TrainFacts, device: Dict[str, Any]):
             # the slice as the device saw it: the profiler starts and stops
             # a little inside the host's calls
             seconds = xplane.device_extent_seconds(trace)
-    h, w, c = cfg.stored_obs_shape
-    blocks = cfg.num_blocks // (device["count"] if cell.traffic.get("mesh")
-                                else 1)
     ctx = readers.ReadContext(
-        cfg=cfg, action_dim=ACTION_DIM, chips=cell.chips,
+        cfg=cfg, config_name=cell.config_name, action_dim=ACTION_DIM,
+        chips=cell.chips,
         device_kind=device["kind"], t_open=sink.ends[i0],
         t_close=sink.ends[i1],
         updates_per_s=(i1 - i0) * cfg.superstep_k
         / (sink.ends[i1] - sink.ends[i0]),
         span_mean_ms=sink.span_mean_ms, trace=trace, trace_seconds=seconds,
         memory_peak_bytes=device.get("memory_peak_bytes"),
-        act_timer=facts.act_timer, ring_obs_shape=(blocks, None, h * w * c),
-        ring_fill_open=facts.ring_fill()["open"])
+        ring_obs=ring_obs(cfg, device["count"] if cell.traffic.get("mesh")
+                          else 1),
+        ring_fill_open=facts.ring_fill()["open"], bench_dir=cell.bench_dir)
     metrics = readers.read_all(cell.per_layer, ctx)
     busy = ctx.busy_seconds()
     breakdown = None
@@ -473,7 +481,7 @@ def run(cell, args, t_start_perf: float,
                         .get("nonfinite", 0))
         failed = min(attempted, -(-nonfinite // cfg.superstep_k))
         cmp = check.compare(cell.config_name, cfg, cell.config["tolerance"],
-                            ACTION_DIM, seed32(args.seed))
+                            ACTION_DIM, seed32(args.seed), cell.bench_dir)
         problems += cmp["problems"]
         metrics: Dict[str, Any] = {}
         breakdown = None
@@ -493,8 +501,13 @@ def run(cell, args, t_start_perf: float,
             correct=not problems, attempted=attempted, failed=failed,
             metrics=metrics, breakdown=breakdown,
             extra=dict(problems=problems, ring_fill=facts.ring_fill(),
+                       dispatch_gaps=dispatch_gaps(facts.sink),
                        reference={k: cmp[k] for k in
-                                  ("q_rel", "q_rms_rel", "loss_rel")}))
+                                  ("q_rel", "q_rms_rel", "loss_rel")},
+                       compared={k: dict(value=cmp[k],
+                                         limit=cell.config["tolerance"][k])
+                                 for k, _ in check.COMPARED
+                                 if k in cell.config["tolerance"]}))
     finally:
         if facts.trace_dir and os.path.isdir(facts.trace_dir):
             shutil.rmtree(facts.trace_dir, ignore_errors=True)

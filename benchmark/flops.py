@@ -1,17 +1,25 @@
 """Operations the model needs, from its shapes, and the table of peaks.
 
 ``train_mfu`` is model FLOP/s over peak: the multiply-adds of the
-convolutions and matrix products of torso, LSTM layers and dueling heads,
+convolutions and matrix products of torso, memory core and dueling heads,
 forward and backward, at the configuration's shapes.  Recomputed
 operations (remat), the ring copy, elementwise work and the fused loop's
 acting forwards do not count — XLA's ``cost_analysis`` counts the first
 two, which is why it is not the source.
+
+The count of one frame's multiply-adds belongs to the configuration: it is
+``model_flops/<config>.py`` exporting ``step_macs(cfg, action_dim)``, found
+by the configuration's name.  A configuration without that file has no
+``train_mfu`` and does not validate.  What the counts share (the torsos)
+is kept once, here.
 """
 from __future__ import annotations
 
 import json
 import os
 from typing import Any, Dict
+
+from benchmark.manifest import BENCH_DIR, find_module
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 NATURE = ((32, 8, 4), (64, 4, 2), (64, 3, 1))       # (channels, kernel, stride)
@@ -53,26 +61,27 @@ def torso_macs(cfg) -> int:
     return macs + h * w * c * cfg.hidden_dim        # the dense layer
 
 
-def step_macs(cfg, action_dim: int) -> int:
-    """Multiply-adds of one frame through torso, LSTM stack and heads."""
-    H = cfg.hidden_dim
-    lstm, feat = 0, H + action_dim + 1
-    for _ in range(cfg.lstm_layers):
-        lstm += (feat + H) * 4 * H
-        feat = H
-    head = 2 * H * H + H * action_dim + H
-    return torso_macs(cfg) + lstm + head
+def step_macs(config_name: str, cfg, action_dim: int,
+              bench_dir: str = BENCH_DIR) -> int:
+    """Multiply-adds of one frame through the whole network, as the
+    configuration's own file counts them."""
+    return find_module("model_flops", config_name, bench_dir).step_macs(
+        cfg, action_dim)
 
 
-def train_flops_per_update(cfg, action_dim: int) -> float:
+def train_flops_per_update(config_name: str, cfg, action_dim: int,
+                           bench_dir: str = BENCH_DIR) -> float:
     """Forward and backward of the online network plus the forward of the
     target network over B x T frames: (1 + 2 + 1) forwards' worth."""
-    forward = 2.0 * step_macs(cfg, action_dim) * cfg.batch_size * cfg.seq_len
+    forward = (2.0 * step_macs(config_name, cfg, action_dim, bench_dir)
+               * cfg.batch_size * cfg.seq_len)
     return 4.0 * forward
 
 
-def train_mfu_percent(cfg, action_dim: int, updates_per_s: float,
-                      chips: int, device_kind: str) -> float:
+def train_mfu_percent(config_name: str, cfg, action_dim: int,
+                      updates_per_s: float, chips: int, device_kind: str,
+                      bench_dir: str = BENCH_DIR) -> float:
     peak = peaks(device_kind)["bf16_flops_per_s"]
-    return (100.0 * train_flops_per_update(cfg, action_dim) * updates_per_s
-            / (chips * peak))
+    return (100.0
+            * train_flops_per_update(config_name, cfg, action_dim, bench_dir)
+            * updates_per_s / (chips * peak))

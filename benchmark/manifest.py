@@ -3,17 +3,23 @@
 A cell is one entry of ``workloads``: a configuration file, a traffic file
 and the metrics that list it.  Nothing here names a particular cell — a
 later PR adds one by adding data files and manifest entries, and this
-module finds them by name.
+module finds them by name: data files through :class:`Manifest`, code that
+belongs to one configuration, one reader kind or one driver through
+:func:`find_module`.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import importlib.util
 import json
 import os
 import re
+import sys
 from typing import Any, Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
@@ -31,6 +37,39 @@ def _read_json(path: str) -> Dict[str, Any]:
         raise ManifestError(f"{path}: {e}") from e
 
 
+def module_path(sub: str, name: str, bench_dir: str = BENCH_DIR) -> str:
+    """``<bench_dir>/<sub>/<name>.py``, which has to be there: one that is
+    not is a :class:`ManifestError`, never a default."""
+    path = os.path.join(bench_dir, sub, name + ".py")
+    if not NAME_RE.match(name) or not os.path.isfile(path):
+        raise ManifestError(f"no {sub}/{name}.py under {bench_dir}: "
+                            f"whatever names {name!r} needs that file")
+    return path
+
+
+def find_module(sub: str, name: str, bench_dir: str = BENCH_DIR):
+    """The module ``<bench_dir>/<sub>/<name>.py``: a configuration's plain
+    reference (``reference``) or count of multiply-adds (``model_flops``), a
+    reader kind (``reader_kinds``), a formula (``formulas``), a driver
+    (``drivers``).  A harness directory other than this package's own (a
+    test's copy) is loaded from its files."""
+    path = module_path(sub, name, bench_dir)
+    if (os.path.realpath(bench_dir) == os.path.realpath(BENCH_DIR)
+            and name.isidentifier()):
+        return importlib.import_module(f"benchmark.{sub}.{name}")
+    key = "_benchmark_file_" + re.sub(r"\W", "_", os.path.realpath(path))
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[key] = module
+        try:
+            spec.loader.exec_module(module)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
@@ -41,6 +80,7 @@ class Cell:
     traffic: Dict[str, Any]       # traffic/<traffic>.json as read
     end_to_end: List[Dict[str, Any]]   # manifest entries this cell reports
     per_layer: List[Dict[str, Any]]    # layer_metrics/<name>.json as read
+    bench_dir: str = BENCH_DIR         # where the cell's files were found
 
 
 class Manifest:
@@ -67,17 +107,23 @@ class Manifest:
         spec.setdefault("name", name)
         return spec
 
+    def module_file(self, sub: str, name: str) -> str:
+        """``<sub>/<name>.py`` of this harness (:func:`module_path`)."""
+        return module_path(sub, name, self.bench_dir)
+
+    def config(self, name: str) -> Dict[str, Any]:
+        """The configuration's file, as read."""
+        if name not in self.configs:
+            raise ManifestError(f"no config {name!r} in BENCHMARK.json")
+        return _read_json(os.path.join(self.root, self.configs[name]["file"]))
+
     def cell(self, name: str) -> Cell:
         if name not in self.workloads:
             raise ManifestError(
                 f"no workload {name!r} in BENCHMARK.json (have: "
                 f"{', '.join(sorted(self.workloads))})")
         w = self.workloads[name]
-        if w["config"] not in self.configs:
-            raise ManifestError(f"workload {name!r} names config "
-                                f"{w['config']!r}, which is not listed")
-        config = _read_json(os.path.join(
-            self.root, self.configs[w["config"]]["file"]))
+        config = self.config(w["config"])
         traffic = _read_json(os.path.join(
             self.bench_dir, "traffic", w["traffic"] + ".json"))
         return Cell(
@@ -87,7 +133,8 @@ class Manifest:
                         if self._applies(m, name)],
             per_layer=[self.layer_metric(m["name"])
                        for m in self.doc["per_layer"]
-                       if self._applies(m, name)])
+                       if self._applies(m, name)],
+            bench_dir=self.bench_dir)
 
     def validate(self) -> None:
         """Everything the driver refuses before a run that can be checked
@@ -111,6 +158,11 @@ class Manifest:
             return seen
 
         names(doc["configs"], "config")
+        for c in doc["configs"]:
+            # what a configuration brings as code, found by its name: its
+            # plain reference and its count of multiply-adds a frame
+            for sub in ("reference", "model_flops"):
+                self.module_file(sub, c["name"])
         cells = names(doc["workloads"], "workload")
         metrics = doc["end_to_end"] + doc["per_layer"]
         names(metrics, "metric")
@@ -149,6 +201,7 @@ class Manifest:
             if not 1 <= len(w["why"]) <= 200:
                 raise ManifestError(f"{w['name']}: why is too long")
             cell = self.cell(w["name"])     # resolves every file by name
+            self.module_file("drivers", cell.traffic.get("driver", ""))
             reported = {m["name"] for m in cell.end_to_end}
             if len(reported) < 2 or not cell.per_layer:
                 raise ManifestError(
@@ -168,6 +221,10 @@ class Manifest:
                             f"layer_metrics/{entry['name']}.json: {key}="
                             f"{spec.get(key)!r} but the manifest says "
                             f"{entry[key]!r}")
+        from benchmark import readers
+
+        for entry in doc["per_layer"]:
+            readers.resolve(self.layer_metric(entry["name"]), self.bench_dir)
         if four > max(1, len(doc["workloads"]) // 4):
             raise ManifestError("too many four-chip cells")
         used = {w["config"] for w in doc["workloads"]}

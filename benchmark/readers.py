@@ -1,18 +1,23 @@
 """Per-layer metrics: one small data file each (``layer_metrics/<name>.json``)
-naming a ``kind`` of reader below and its parameters.  A reader that finds
-nothing to read returns None, and the harness leaves the metric out."""
+naming a ``kind`` of reader and its parameters.  A kind is one of those
+below or, found by its name, a module ``reader_kinds/<kind>.py`` exporting
+``read(spec, ctx)``; a ``formula`` likewise one of those below or
+``formulas/<formula>.py``.  A reader that finds nothing to read returns
+None, and the harness leaves the metric out."""
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from benchmark import flops, xplane
+from benchmark.manifest import BENCH_DIR, find_module
 
 
 @dataclasses.dataclass
 class ReadContext:
     """What a traced run offers the readers."""
     cfg: Any
+    config_name: str                    # whose model_flops/<name>.py counts
     action_dim: int
     chips: int
     device_kind: str
@@ -23,11 +28,14 @@ class ReadContext:
     trace: Optional[Dict[str, Any]]     # xplane.load(...) of the slice
     trace_seconds: float                # length of the traced slice
     memory_peak_bytes: Optional[int]
-    act_timer: Any = None
-    # the frame ring on one chip: (blocks, None, bytes a frame) — the row
-    # count is the ring's own padding and is left open
-    ring_obs_shape: Optional[Tuple[Optional[int], ...]] = None
+    # the program's frame ring on one chip as the trace names an array:
+    # ("u32", (blocks, rows, words a frame)); None leaves a dimension open
+    ring_obs: Optional[Tuple[str, Tuple[Optional[int], ...]]] = None
     ring_fill_open: Optional[float] = None  # share of the ring, window open
+    bench_dir: str = BENCH_DIR          # where kinds and counts are found
+    # what one reader worked out for the next (a reduction of the whole
+    # slice that several metrics share)
+    cache: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def device_line(self, line: str, device: int = 0) -> List[xplane.Event]:
         planes = xplane.device_planes(self.trace) if self.trace else []
@@ -73,9 +81,9 @@ def read_xplane_ops(spec, ctx: ReadContext) -> Optional[float]:
                              else xplane.OPS_LINE)
     shape = None
     if spec.get("shape") == "ring_obs":
-        if ctx.ring_obs_shape is None:
+        if ctx.ring_obs is None:
             return None
-        shape = ("u8", tuple(ctx.ring_obs_shape))
+        shape = ctx.ring_obs
     picked = xplane.selected(events, spec["match"], shape)
     if spec["reduce"] == "ms_per_update":
         if len(picked) > 2:
@@ -101,37 +109,42 @@ def read_ring_fill(spec, ctx: ReadContext) -> Optional[float]:
     return 100.0 * ctx.ring_fill_open
 
 
-def read_formula(spec, ctx: ReadContext) -> Optional[float]:
-    if spec["formula"] == "train_mfu":
-        if ctx.device_kind == "cpu":    # a rehearsal: a CPU has no MFU
-            return None
-        return flops.train_mfu_percent(ctx.cfg, ctx.action_dim,
-                                       ctx.updates_per_s, ctx.chips,
-                                       ctx.device_kind)
-    raise KeyError(f"no formula {spec['formula']!r}")
-
-
-def read_act_timer(spec, ctx: ReadContext) -> Optional[float]:
-    """Host microseconds of one batched act call of the actors, to the
-    fetched outputs; only where the cell's traffic asks for the timer and
-    acting ran on the platform the metric's file names."""
-    t = ctx.act_timer
-    if t is None or t.device.platform != spec["act_platform"]:
+def formula_train_mfu(spec, ctx: ReadContext) -> Optional[float]:
+    if ctx.device_kind == "cpu":        # a rehearsal: a CPU has no MFU
         return None
-    return t.mean_us(ctx.t_open, ctx.t_close)
+    return flops.train_mfu_percent(ctx.config_name, ctx.cfg, ctx.action_dim,
+                                   ctx.updates_per_s, ctx.chips,
+                                   ctx.device_kind, ctx.bench_dir)
+
+
+FORMULAS = dict(train_mfu=formula_train_mfu)
 
 
 KINDS = dict(span=read_span, xplane_idle=read_xplane_idle,
              xplane_ops=read_xplane_ops, memory_stats=read_memory_stats,
-             ring_fill=read_ring_fill, formula=read_formula,
-             act_timer=read_act_timer)
+             ring_fill=read_ring_fill)   # and "formula": see resolve()
+
+
+def resolve(spec: Dict[str, Any], bench_dir: str = BENCH_DIR
+            ) -> Callable[[Dict[str, Any], ReadContext], Optional[float]]:
+    """The function that reads ``spec``: its ``kind`` among those here and
+    otherwise ``reader_kinds/<kind>.py``; for the kind ``formula`` its
+    ``formula`` among those here and otherwise ``formulas/<formula>.py``.
+    One that is nowhere is a :class:`~benchmark.manifest.ManifestError`."""
+    kind = spec.get("kind", "")
+    if kind == "formula":
+        name = spec.get("formula", "")
+        return (FORMULAS.get(name)
+                or find_module("formulas", name, bench_dir).read)
+    return KINDS.get(kind) or find_module("reader_kinds", kind,
+                                          bench_dir).read
 
 
 def read_all(specs: List[Dict[str, Any]], ctx: ReadContext
              ) -> Dict[str, Dict[str, Any]]:
     out = {}
     for spec in specs:
-        value = KINDS[spec["kind"]](spec, ctx)
+        value = resolve(spec, ctx.bench_dir)(spec, ctx)
         if value is not None:
             out[spec["name"]] = dict(value=float(value), unit=spec["unit"])
     return out

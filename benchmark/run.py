@@ -16,7 +16,6 @@ import time
 T_START = time.perf_counter()   # set-up is counted from here
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -90,8 +89,9 @@ def main(argv=None) -> int:
 
     # the traffic file names the driver; everything about the run but the
     # printing is the driver's
-    driver = importlib.import_module(
-        "benchmark.drivers." + cell.traffic["driver"])
+    from benchmark.manifest import find_module
+
+    driver = find_module("drivers", cell.traffic["driver"], cell.bench_dir)
     out = driver.run(cell, args, T_START, device)
     metrics, problems = out["metrics"], out["extra"].get("problems", [])
     extra = dict(out["extra"], workload=cell.name, seed=args.seed,
@@ -102,6 +102,15 @@ def main(argv=None) -> int:
         metrics = {}
     for p in problems:
         print(f"benchmark: {p}", file=sys.stderr)
+    # each number compared beside its limit: the last lines on standard
+    # error and the last key of the line
+    compared = extra.pop("compared", None)
+    if compared is not None:
+        for name, c in compared.items():
+            print(f"benchmark: compared {name} = {c['value']:.6g} "
+                  f"(limit {c['limit']})", file=sys.stderr)
+        extra["compared"] = compared
+    sys.stderr.flush()
     sys.stdout.flush()
     print(result_line(out["correct"], out["attempted"], out["failed"],
                       metrics, device, out.get("breakdown"), extra),

@@ -12,19 +12,36 @@ event per executed HLO operation, named by the instruction's whole text
 container such as ``while`` holds its body's events nested inside it on the
 same line), and whose line ``XLA Modules`` holds one event per executed
 program (``jit_super_step(<fingerprint>)``).  A second of a train step is
-some 350,000 operation events, so events keep a name, a start and a
-duration and nothing else.
+some 350,000 operation events, so events keep a name, a start, a duration
+and, on the ``XLA Ops`` line, the operation's ``path`` and nothing else.
+
+The ``path`` is the operation's ``op_name`` — ``jit(super_step)/.../
+jvp(core)/.../dot_general:`` — with the program's ``jax.named_scope`` names
+in it.  The trace carries it as the stat ``tf_op`` of the event's METADATA
+(probed on the chip, PR 25), which ``jax.profiler.ProfileData`` does not
+show, so ``load`` reads it from the XSpace protocol buffer with the
+``xplane_pb2`` that ships beside the installed profiler plugin.  A program
+loaded from a compile cache written before the scopes existed carries none
+(the cache key leaves metadata out).
 """
 from __future__ import annotations
 
 import glob
+import importlib.util
 import os
 import re
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Collection, Dict, Iterable, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
-Event = Dict[str, Any]      # name, start_ns, dur_ns
+SCOPE_STAT = "tf_op"
+Event = Dict[str, Any]      # name, start_ns, dur_ns; XLA Ops: path
+# the program's top-level scopes (docs/OBSERVABILITY.md): the train step's,
+# then the fused loop's
+SCOPES = ("torso", "core", "heads", "target_forward", "ring_gather",
+          "per_sample", "per_scatter", "loss", "optimizer",
+          "env_step", "act", "ring_write")
+NO_SCOPE = "(none)"
 
 
 def find_xplane(trace_dir: str) -> Optional[str]:
@@ -36,10 +53,13 @@ def find_xplane(trace_dir: str) -> Optional[str]:
 def load(path: str, planes: str = r"^/device:|^/host:CPU$",
          lines: Optional[str] = None) -> Dict[str, Any]:
     """The trace as plain dicts: ``{"planes": [{"name", "lines": [{"name",
-    "events": [{"name", "start_ns", "dur_ns"}]}]}]}``."""
+    "events": [{"name", "start_ns", "dur_ns"}]}]}]}``; an event of a
+    device's ``XLA Ops`` line whose operation has one also keeps its
+    ``path``."""
     from jax.profiler import ProfileData
 
     keep_plane, keep_line = re.compile(planes), lines and re.compile(lines)
+    paths = op_paths(path)
     out = []
     for plane in ProfileData.from_file(path).planes:
         if not keep_plane.search(plane.name):
@@ -50,9 +70,72 @@ def load(path: str, planes: str = r"^/device:|^/host:CPU$",
                 continue
             events = [dict(name=e.name, start_ns=int(e.start_ns),
                            dur_ns=int(e.duration_ns)) for e in line.events]
+            if line.name == OPS_LINE:
+                _attach_paths(events, paths.get(plane.name))
             plines.append(dict(name=line.name, events=events))
         out.append(dict(name=plane.name, lines=plines))
     return dict(planes=out)
+
+
+def _xplane_pb2():
+    """``xplane_pb2`` of the installed tsl, loaded from its file (importing
+    the package around it would start all of TensorFlow); None where it is
+    not installed."""
+    found = importlib.util.find_spec("tensorflow")
+    if found is None or not found.submodule_search_locations:
+        return None
+    path = os.path.join(found.submodule_search_locations[0],
+                        "tsl", "profiler", "protobuf", "xplane_pb2.py")
+    if not os.path.isfile(path):
+        return None
+    spec = importlib.util.spec_from_file_location("xplane_pb2", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def op_paths(path: str) -> Dict[str, List[Tuple[str, Optional[str]]]]:
+    """By device plane, ``(name, op_name path or None)`` of every event of
+    its ``XLA Ops`` line, in the line's order.  Empty where no
+    ``xplane_pb2`` is installed."""
+    pb2 = _xplane_pb2()
+    if pb2 is None:
+        return {}
+    space = pb2.XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    out = {}
+    for plane in space.planes:
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        stat_ids = {i for i, s in plane.stat_metadata.items()
+                    if s.name == SCOPE_STAT}
+        by_meta: Dict[int, Tuple[str, Optional[str]]] = {}
+        for mid, md in plane.event_metadata.items():
+            found = None
+            for st in md.stats:
+                if st.metadata_id in stat_ids:
+                    found = (plane.stat_metadata[st.ref_value].name
+                             if st.WhichOneof("value") == "ref_value"
+                             else st.str_value)
+            by_meta[mid] = (md.name, found)
+        for line in plane.lines:
+            if line.name == OPS_LINE:
+                out[plane.name] = [by_meta[ev.metadata_id]
+                                   for ev in line.events]
+    return out
+
+
+def _attach_paths(events: List[Event],
+                  paths: Optional[List[Tuple[str, Optional[str]]]]) -> None:
+    """``path`` onto the events of one ``XLA Ops`` line, where the two
+    readings of the file show the same operations in the same order."""
+    if not paths or len(paths) != len(events) or any(
+            ev["name"] != name for ev, (name, _) in zip(events, paths)):
+        return
+    for ev, (_, found) in zip(events, paths):
+        if found:
+            ev["path"] = found
 
 
 def device_planes(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
@@ -137,6 +220,19 @@ def op_shape(ev: Event) -> Optional[Tuple[str, Tuple[int, ...]]]:
                                  if d)
 
 
+def hlo_dtype(dtype) -> str:
+    """A numpy dtype under the name an instruction's text gives it:
+    ``u32``, ``s8``, ``f32``, ``bf16``, ``pred``."""
+    import numpy as np
+
+    dt = np.dtype(dtype)
+    if dt.name == "bfloat16":
+        return "bf16"
+    if dt.kind == "b":
+        return "pred"
+    return {"u": "u", "i": "s", "f": "f"}[dt.kind] + str(8 * dt.itemsize)
+
+
 def op_label(ev: Event) -> str:
     """The operation under the name the trace gives it, with its shape:
     ``copy.219_u8_1152_448_7056_``."""
@@ -175,6 +271,51 @@ def selected(events: List[Event], name: str = "",
 
 def select_seconds(events: List[Event], name: str = "", shape=None) -> float:
     return sum(ns for _, ns in selected(events, name, shape)) / 1e9
+
+
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+
+
+def scope_of(path: Optional[str],
+             scopes: Collection[str] = frozenset(SCOPES)) -> str:
+    """The outermost component of an ``op_name`` path that names a scope,
+    with ``.bwd`` where a ``transpose(...)`` wraps it or a component
+    outside it; :data:`NO_SCOPE` where none does."""
+    if not path:
+        return NO_SCOPE
+    backward = False
+    for part in path.split(":")[0].split("/"):
+        m = _WRAPPED.match(part)
+        while m:                      # jvp(x), transpose(jvp(x)), vmap(x)
+            backward = backward or m.group(1) == "transpose"
+            part = m.group(2)
+            m = _WRAPPED.match(part)
+        if part in scopes:
+            return part + (".bwd" if backward else "")
+    return NO_SCOPE
+
+
+def scope_split(events: List[Event],
+                scopes: Collection[str] = frozenset(SCOPES)
+                ) -> Dict[str, float]:
+    """Percent of the busy time by scope, largest first: every operation's
+    self time goes to the outermost scope of its ``path``, a backward pass
+    apart (``core.bwd``), operations under no scope (the loop's own control
+    flow, copies the compiler added) to :data:`NO_SCOPE`, so the shares sum
+    to 100.  ``events``: one device's ``XLA Ops`` line."""
+    busy_ns = 1e9 * busy_seconds(events)
+    if busy_ns <= 0:
+        return {}
+    by_path: Dict[Optional[str], float] = {}   # a step runs each op often
+    for ev, ns in self_times(events):
+        path = ev.get("path")
+        by_path[path] = by_path.get(path, 0.0) + ns
+    by_scope: Dict[str, float] = {}
+    for path, ns in by_path.items():
+        key = scope_of(path, scopes)
+        by_scope[key] = by_scope.get(key, 0.0) + ns
+    return {k: 100.0 * ns / busy_ns
+            for k, ns in sorted(by_scope.items(), key=lambda kv: -kv[1])}
 
 
 def idle_gaps(events: List[Event], spans: Dict[str, List[Tuple[float, float]]],

@@ -1,7 +1,38 @@
-"""The FLOP function and the table of peaks."""
+"""The FLOP functions — the torsos' shared count, each configuration's own
+count found by its name — and the table of peaks."""
 import pytest
 
 from benchmark import flops
+from benchmark.drivers import train as training
+from benchmark.manifest import Manifest, ManifestError
+
+MANIFEST = Manifest()
+# pinned to the digit: multiply-adds a frame and FLOPs an update of the
+# configurations this benchmark was accepted with (a later one pins its own)
+PINNED = {"nature_lstm512": (9_519_616, 414_293_688_320),
+          "impala_deep_lstm2": (56_897_280, 3_495_768_883_200)}
+
+
+def _file_config(name):
+    return training.config_from_file(MANIFEST.config(name)["config"])
+
+
+@pytest.mark.parametrize("name", sorted(MANIFEST.configs))
+def test_every_configuration_has_its_own_count_found_by_name(name):
+    cfg = _file_config(name)
+    macs = flops.step_macs(name, cfg, training.ACTION_DIM)
+    assert isinstance(macs, int) and macs > flops.torso_macs(cfg) > 0
+    per_update = flops.train_flops_per_update(name, cfg, training.ACTION_DIM)
+    assert per_update == 8.0 * macs * cfg.batch_size * cfg.seq_len
+    if name in PINNED:
+        assert (macs, per_update) == PINNED[name]
+
+
+def test_a_configuration_without_a_count_is_an_error_not_a_default():
+    with pytest.raises(ManifestError, match="model_flops/no_such_config.py"):
+        flops.step_macs("no_such_config", _nature(), 4)
+    with pytest.raises(ManifestError, match="model_flops/../flops.py"):
+        flops.step_macs("../flops", _nature(), 4)    # the file is there
 
 
 def _nature():
@@ -22,11 +53,11 @@ def test_nature_flops_against_a_hand_count():
                                             1605632)
     cfg = _nature()
     assert flops.torso_macs(cfg) == conv1 + conv2 + conv3 + dense
-    assert flops.step_macs(cfg, 4) == 9519616 == (
+    assert flops.step_macs("nature_lstm512", cfg, 4) == 9519616 == (
         conv1 + conv2 + conv3 + dense + lstm + head)
     # B=64 windows of T=85 frames; forward + backward (2x) + target forward
-    assert flops.train_flops_per_update(cfg, 4) == pytest.approx(
-        4 * 2 * 9519616 * 64 * 85)
+    assert flops.train_flops_per_update("nature_lstm512", cfg, 4) == (
+        4 * 2 * 9519616 * 64 * 85) == 414_293_688_320
 
 
 def test_impala_flops_follow_its_sections():
@@ -38,16 +69,19 @@ def test_impala_flops_follow_its_sections():
             + 21 * 21 * 32 * 9 * 32 + 4 * 11 * 11 * 32 * 9 * 32
             + 11 * 11 * 32 * 512)
     assert flops.torso_macs(cfg) == want == 52165888
-    assert flops.step_macs(cfg, 4) == want + (517 + 512) * 2048 + (
-        1024 * 2048) + 526848
+    assert flops.step_macs("impala_deep_lstm2", cfg, 4) == want + (
+        517 + 512) * 2048 + 1024 * 2048 + 526848 == 56_897_280
+    assert flops.train_flops_per_update("impala_deep_lstm2", cfg, 4) == (
+        3_495_768_883_200)
 
 
 def test_mfu_uses_the_published_peak_and_every_chip():
     cfg = _nature()
-    one = flops.train_mfu_percent(cfg, 4, 78.7, 1, "TPU v5 lite")
+    one = flops.train_mfu_percent("nature_lstm512", cfg, 4, 78.7, 1,
+                                  "TPU v5 lite")
     assert one == pytest.approx(100 * 414.29368832e9 * 78.7 / 197e12)
-    assert flops.train_mfu_percent(cfg, 4, 78.7, 4, "TPU v5 lite") == (
-        pytest.approx(one / 4))
+    assert flops.train_mfu_percent("nature_lstm512", cfg, 4, 78.7, 4,
+                                   "TPU v5 lite") == pytest.approx(one / 4)
 
 
 def test_the_peak_is_the_published_one_with_a_source():

@@ -37,6 +37,7 @@ LOOP_SCOPES = ("env_step", "act", "ring_write")
 
 
 def test_the_manifest_validates_with_the_ten_entries():
+    """PR 25's ten; the cells are run here as examples."""
     m = Manifest()
     m.validate()
     new = [e for e in m.doc["per_layer"]
@@ -62,7 +63,8 @@ def test_the_manifest_validates_with_the_ten_entries():
 
 def _ctx(sink, trace=None):
     return readers.ReadContext(
-        cfg=None, action_dim=4, chips=1, device_kind="TPU v5 lite",
+        cfg=None, config_name="", action_dim=4, chips=1,
+        device_kind="TPU v5 lite",
         t_open=0.0, t_close=100.0, updates_per_s=1.0,
         span_mean_ms=sink.span_mean_ms, trace=trace,
         trace_seconds=xplane.device_extent_seconds(trace) if trace else 0.0,
@@ -142,7 +144,36 @@ def test_every_span_a_metric_file_names_is_a_literal_of_the_program():
 
 
 # ---- the names the device's timeline shows: what the two program shares
-# match, and what tools/step_split.py splits the step by
+# match, and what the scope metrics (kind scope_share) split the step by
+
+def _scope_metric_scopes():
+    metrics_dir = os.path.join(ROOT, "benchmark", "layer_metrics")
+    out = set()
+    for f in os.listdir(metrics_dir):
+        with open(os.path.join(metrics_dir, f)) as fh:
+            spec = json.load(fh)
+        if spec["kind"] == "scope_share":
+            out.add(spec["scope"])
+    return out
+
+
+def test_every_scope_a_metric_file_names_is_a_scope_of_the_program():
+    """A rename in the program cannot pass unseen: a scope metric's scope
+    is one the reduction knows and a ``named_scope`` literal in
+    ``r2d2_tpu/``; the two tests of the lowered super-steps below find
+    each in the programs' metadata."""
+    scopes = _scope_metric_scopes()
+    assert {"torso", "core", "target_forward", "ring_gather"} <= scopes
+    assert scopes <= set(STEP_SCOPES + LOOP_SCOPES) == set(xplane.SCOPES)
+    source = ""
+    for folder, _, files in os.walk(os.path.join(ROOT, "r2d2_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(folder, f)) as fh:
+                    source += fh.read()
+    for scope in sorted(scopes):
+        assert re.search(rf'named_scope\(\s*"{scope}"\s*\)', source), scope
+
 
 def _program(cell_name):
     """The cell's program at rehearsal sizes: config, network, state."""
@@ -162,11 +193,10 @@ def _module_name(lowered) -> str:
 
 
 def _scopes_of(lowered) -> set:
-    """What ``tools/step_split.py`` would file the lowered program's
-    operations under: its own reading of every operation's name path."""
-    from tools.step_split import scope_of
-
-    return {scope_of(path) for path in re.findall(
+    """What the scope reduction (``benchmark/xplane.scope_of``) would file
+    the lowered program's operations under: its own reading of every
+    operation's name path."""
+    return {xplane.scope_of(path) for path in re.findall(
         r'loc\("([^"]+)"', lowered.as_text(debug_info=True))}
 
 
@@ -231,6 +261,7 @@ def test_a_steady_state_program_lowers_under_its_stable_name(
 def test_the_fabric_super_step_carries_every_scope(fabric_programs):
     found = _scopes_of(fabric_programs["super_step"])
     assert set(STEP_SCOPES) <= found
+    assert _scope_metric_scopes() <= found
     # forward and backward separate themselves
     assert {"torso.bwd", "core.bwd", "heads.bwd", "loss.bwd"} <= found
     assert not found & {s + ".bwd" for s in (
@@ -261,5 +292,6 @@ def test_the_fused_loop_lowers_as_super_step_too(fused_super_step):
 def test_the_fused_super_step_carries_every_scope(fused_super_step):
     found = _scopes_of(fused_super_step)
     assert set(STEP_SCOPES + LOOP_SCOPES) <= found
+    assert _scope_metric_scopes() <= found
     assert {"torso.bwd", "core.bwd"} <= found
     assert not found & {"act.bwd", "env_step.bwd", "ring_write.bwd"}
