@@ -172,6 +172,7 @@ def _child_kernel(rehearsal: bool) -> dict:
         create_network,
         init_params,
     )
+    from r2d2_tpu.models.state import state_spec
     from r2d2_tpu.utils.compile_cache import enable
     from r2d2_tpu.utils.trace import device_memory
 
@@ -191,8 +192,8 @@ def _child_kernel(rehearsal: bool) -> dict:
         la = np.zeros((B, A), np.float32)
         la[np.arange(B), rng.integers(A, size=B)] = 1.0
         lr = rng.normal(size=B).astype(np.float32)
-        hid = (rng.normal(size=(B, 2, cfg.lstm_layers, cfg.hidden_dim))
-               .astype(np.float32) * 0.1)
+        hid = (rng.normal(size=(B,) + state_spec(cfg)[0]) * 0.1).astype(
+            state_spec(cfg)[1])
         out, case = {}, dict(B=B)
         for impl, net in nets.items():
             fn = jax.jit(lambda p, *a, net=net: net.apply(

@@ -41,6 +41,7 @@ import numpy as np
 
 from r2d2_tpu.config import Config
 from r2d2_tpu.models.network import R2D2Network
+from r2d2_tpu.models.state import zero_state
 from r2d2_tpu.replay.block import Block, VectorLocalBuffer
 from r2d2_tpu.telemetry.tracing import EVENTS
 from r2d2_tpu.utils.store import ParamStore
@@ -61,13 +62,13 @@ class AgentState:
     obs: np.ndarray            # (*obs_shape) uint8
     last_action: np.ndarray    # (A,) float32 one-hot
     last_reward: float
-    hidden: np.ndarray         # (2, layers, H) float32
+    hidden: np.ndarray         # one state: models.network.state_spec(cfg)
 
     @classmethod
     def initial(cls, cfg: Config, obs: np.ndarray, action_dim: int
                 ) -> "AgentState":
         la = np.zeros(action_dim, np.float32)
-        hidden = np.zeros((2, cfg.lstm_layers, cfg.hidden_dim), np.float32)
+        hidden = zero_state(cfg)
         return cls(obs=np.asarray(obs, np.uint8), last_action=la,
                    last_reward=0.0, hidden=hidden)
 
@@ -77,7 +78,7 @@ class AgentState:
         self.last_action = np.zeros_like(self.last_action)
         self.last_action[action] = 1.0
         self.last_reward = float(reward)
-        self.hidden = np.asarray(hidden, np.float32)
+        self.hidden = np.asarray(hidden, self.hidden.dtype)
 
 
 def fleet_shards(cfg: Config):
@@ -258,8 +259,7 @@ class VectorActor:
         self.obs = np.zeros((self.N, *cfg.stored_obs_shape), np.uint8)
         self.last_action = np.zeros((self.N, self.action_dim), np.float32)
         self.last_reward = np.zeros(self.N, np.float32)
-        self.hidden = np.zeros((self.N, 2, cfg.lstm_layers, cfg.hidden_dim),
-                               np.float32)
+        self.hidden = zero_state(cfg, self.N)
         # per-iteration env-step scratch, filled by the (possibly pooled)
         # env stepping and consumed by the vectorized batched update
         self._step_reward = np.zeros(self.N, np.float32)

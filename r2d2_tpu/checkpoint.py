@@ -336,11 +336,23 @@ def truncate_checkpoint_dir(path: str) -> None:
 # metadata sidecar and validated before restore so a mismatch fails with an
 # actionable message instead of an opaque orbax shape error
 ARCH_FIELDS = ("obs_space_to_depth", "obs_shape", "torso", "hidden_dim",
-               "lstm_layers")
+               "lstm_layers", "core", "recurrent_state")
+
+
+def _arch_value(cfg: Any, field: str) -> Any:
+    """``recurrent_state`` is not a field but what the memory core's
+    fields add up to: the shape and dtype of one state, as the model owns
+    them (models/state.py) — it covers every core_* field that sizes it."""
+    if field == "recurrent_state":
+        from r2d2_tpu.models.state import state_spec
+
+        shape, dtype = state_spec(cfg)
+        return [list(shape), dtype.name]
+    return getattr(cfg, field)
 
 
 def arch_meta(cfg: Any) -> Dict[str, Any]:
-    return {f: getattr(cfg, f) for f in ARCH_FIELDS}
+    return {f: _arch_value(cfg, f) for f in ARCH_FIELDS}
 
 
 def check_arch_compat(cfg: Any, meta: Dict[str, Any]) -> None:
@@ -350,7 +362,7 @@ def check_arch_compat(cfg: Any, meta: Dict[str, Any]) -> None:
     mismatches = []
     for f in ARCH_FIELDS:
         if f in meta:
-            want, have = meta[f], getattr(cfg, f)
+            want, have = meta[f], _arch_value(cfg, f)
             if isinstance(have, tuple):
                 have = list(have)
             if want != have:

@@ -282,6 +282,38 @@ class Config:
     hidden_dim: int = 512       # reference: config.py:33
     torso: str = "nature"       # "nature" (model.py:39-49) or "impala" (BASELINE configs[4])
     lstm_layers: int = 1        # BASELINE configs[4] uses 2
+    # the memory core between torso and heads (models/network.py): "lstm"
+    # (the stacked LSTM above) or "xing4" (models/xing4.py: latent
+    # attention over a stored latent cache, routed experts, residual
+    # streams mixed by Sinkhorn-normalised matrices).  The core_* fields
+    # below are read only under core="xing4"; their defaults are the
+    # published sizes of the block (xing4_core_config's docstring; the
+    # source's constants that nothing varies are models/xing4.py's), and
+    # ``*_held`` say how many of a layer's heads / routed experts THIS
+    # chip holds of a deployment that divides each layer over chips (the
+    # router keeps its core_experts outputs either way)
+    core: str = "lstm"
+    core_dim: int = 3584            # width of the residual streams
+    core_layers: int = 40           # blocks, the leading dense ones included
+    core_dense_layers: int = 2      # leading blocks with a dense feed-forward
+    core_context: int = 64          # steps of latent cache a state keeps (W)
+    core_heads_held: int = 32       # attention heads computed here
+    core_q_rank: int = 768          # query latent
+    core_kv_rank: int = 512         # key/value latent (cached)
+    core_nope_dim: int = 128        # per head, without position
+    core_rope_dim: int = 64         # per head (query) / shared (key, cached)
+    core_v_dim: int = 128           # per head
+    core_rope_theta: float = 10000.0
+    core_rope_factor: float = 64.0  # YaRN
+    core_rope_original: int = 4096
+    core_dense_dim: int = 9216      # dense feed-forward width
+    core_experts: int = 64          # routed experts a layer has (router width)
+    core_experts_held: int = 64     # ... of which experts 0..held-1 live here
+    core_top_k: int = 4             # experts a token is routed to
+    core_expert_dim: int = 1024     # width of one routed / shared expert
+    core_bias_rate: float = 0.001   # step of the router's correction bias
+    core_streams: int = 4           # residual streams
+    core_sinkhorn_iters: int = 20
 
     # --- evaluation -------------------------------------------------------
     test_epsilon: float = 0.001  # reference: config.py:37
@@ -981,6 +1013,49 @@ class Config:
             raise ValueError(f"unknown torso {self.torso!r}")
         if self.lstm_layers < 1:
             raise ValueError("lstm_layers must be >= 1")
+        if self.core not in ("lstm", "xing4"):
+            raise ValueError(f"unknown core {self.core!r}")
+        if self.core == "xing4":
+            if not 0 <= self.core_dense_layers <= self.core_layers:
+                raise ValueError("core_dense_layers must lie in "
+                                 "[0, core_layers]")
+            if not 1 <= self.core_experts_held <= self.core_experts:
+                raise ValueError("core_experts_held must lie in "
+                                 "[1, core_experts]")
+            if not 1 <= self.core_top_k <= self.core_experts:
+                raise ValueError("core_top_k must lie in [1, core_experts]")
+            if self.core_rope_dim % 2:
+                raise ValueError("core_rope_dim must be even")
+            if self.core_context < 1 or self.core_heads_held < 1:
+                raise ValueError("core_context and core_heads_held must "
+                                 "be >= 1")
+            # paths that would carry one latent cache a lane, a session or
+            # a sequence over a wire sized for the LSTM's 4 kB state
+            # (369 kB in bfloat16 at the published widths): not built,
+            # refused by name (ROADMAP Queue 2)
+            if self.actor_transport == "process":
+                raise ValueError(
+                    "core='xing4' does not run under actor_transport="
+                    "'process': the act slabs and the inference service "
+                    "(parallel/inference_service.py) would carry a whole "
+                    "latent cache a lane a step; use 'thread' or 'anakin'")
+            if self.replay_shards > 1 or self.replay_transport == "socket":
+                raise ValueError(
+                    "core='xing4' does not run over the sharded replay "
+                    "plane or the net wire (replay/netwire.py): a frame "
+                    "would carry a latent cache a sequence; use the "
+                    "in-process device ring (replay_shards=1, "
+                    "replay_transport='shm')")
+            if self.fused_double_unroll:
+                raise ValueError(
+                    "core='xing4' unrolls online and target networks "
+                    "apart (fused_double_unroll stacks parameters, and "
+                    "the router's buffer is not one)")
+            if self.stored_hidden_mode != "burn_in_start":
+                raise ValueError(
+                    "core='xing4' stores the latent cache at a sequence's "
+                    "burn-in start only (stored_hidden_mode="
+                    "'burn_in_start')")
         if self.lstm_impl not in ("auto", "scan", "pallas"):
             raise ValueError(f"unknown lstm_impl {self.lstm_impl!r} "
                              "(pallas_spmd was retired in r5 with the "
@@ -1187,6 +1262,29 @@ def impala_deep_config(game: str = "MsPacman", **kw) -> Config:
         block_length=375, buffer_capacity=1_500_000, remat=True,
         obs_space_to_depth=False,
     )
+    base.update(kw)
+    return Config(**base)
+
+
+def xing4_core_config(game: str = "MsPacman", **kw) -> Config:
+    """R2D2 with the block of Xing4.0-29B-A4B
+    (huggingface.co/XingChen-AGI/Xing4.0-29B-A4B, ``config.json``) as its
+    memory core, at the published sizes: 40 blocks of width 3,584; latent
+    attention (query rank 768, key/value rank 512, 32 heads of 128 + 64
+    query/key and 128 value dimensions, YaRN factor 64 over 4,096); 64
+    routed experts of width 1,024 and one shared expert, sigmoid scores,
+    top 4 by score plus a correction bias, weights normalised and scaled
+    by 2; two leading dense blocks of width 9,216; four residual streams
+    mixed by Sinkhorn-normalised matrices.  Torso, heads, windows and
+    optimizer are R2D2's (``pong_config``).  Whole, it fits no chip: a
+    run states its share (``core_layers``, ``core_dense_layers``,
+    ``core_heads_held``, ``core_experts_held``).  ``core_bias_rate`` is
+    the 0.001 an update of DeepSeek-V3's report, whose balancing rule
+    ``noaux_tc`` names."""
+    base = dict(game_name=game, num_actors=64,
+                device_replay=True, in_graph_per=True,
+                superstep_k=4, superstep_pipeline=2,
+                core="xing4", remat=True)
     base.update(kw)
     return Config(**base)
 

@@ -26,6 +26,7 @@ from r2d2_tpu.actor import make_act_fn
 from r2d2_tpu.checkpoint import Checkpointer
 from r2d2_tpu.config import Config
 from r2d2_tpu.models.network import R2D2Network, create_network
+from r2d2_tpu.models.state import zero_state
 
 
 def run_episodes(cfg: Config, net: R2D2Network, params: Any,
@@ -46,7 +47,7 @@ def run_episodes(cfg: Config, net: R2D2Network, params: Any,
     obs = np.zeros((N, *cfg.stored_obs_shape), np.uint8)
     last_action = np.zeros((N, action_dim), np.float32)
     last_reward = np.zeros(N, np.float32)
-    hidden = np.zeros((N, 2, cfg.lstm_layers, cfg.hidden_dim), np.float32)
+    hidden = zero_state(cfg, N)
     for i, env in enumerate(envs):
         o, _ = env.reset()
         obs[i] = np.asarray(o, np.uint8)

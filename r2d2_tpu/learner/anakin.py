@@ -70,7 +70,18 @@ from r2d2_tpu.learner.step import (
     _loss_net,
     make_train_step,
 )
-from r2d2_tpu.models.network import R2D2Network
+from r2d2_tpu.models.network import (
+    R2D2Network,
+    counter_names,
+    read_counters,
+    zero_hidden,
+)
+from r2d2_tpu.models.state import (
+    reset_stream,
+    stream_entry,
+    stream_spec,
+    stream_states,
+)
 from r2d2_tpu.replay.device_ring import gather_batch, ring_slots
 from r2d2_tpu.utils.math import epsilon_ladder
 from r2d2_tpu.utils.resilience import Deadline
@@ -90,6 +101,9 @@ STATS_FIELDS = ("env_steps", "fill", "episodes", "reward_sum", "blocks")
 # in-graph greedy eval lane fields, appended after STATS_FIELDS when
 # cfg.anakin_eval_interval > 0 (zeros on off-cadence dispatches)
 EVAL_FIELDS = ("eval_episodes", "eval_return_sum")
+# the counters the model's own train step leaves for the host
+# (models/network.counter_names, none under the LSTM) follow the eval pair:
+# those of the dispatch's last update
 
 
 def _mesh_hooks(table):
@@ -123,7 +137,6 @@ def _make_eval_lane(cfg: Config, net: R2D2Network, env: Any,
     env.  Greedy argmax + per-lane env draws are elementwise in the lane
     axis, so the lane needs no extra layout pins."""
     N, A = cfg.num_actors, action_dim
-    layers, H = cfg.lstm_layers, cfg.hidden_dim
     act_net = _loss_net(cfg, net)
     interval = cfg.anakin_eval_interval
     steps = cfg.anakin_episode_len
@@ -135,7 +148,7 @@ def _make_eval_lane(cfg: Config, net: R2D2Network, env: Any,
         est = env.init_state(key)
         carry0 = (est, env.observe(est),
                   jnp.zeros((N, A), jnp.float32), jnp.zeros(N, jnp.float32),
-                  jnp.zeros((N, 2, layers, H), jnp.float32),
+                  zero_hidden(cfg, N),
                   jnp.zeros(N, jnp.float32), jnp.zeros(N, bool))
 
         def estep(c, _):
@@ -232,8 +245,9 @@ def _make_assemble(cfg: Config, action_dim: int, done: bool):
         # reference's seq-start indexing under the compat switch)
         hidx = seq * L if seq_start_mode else c + seq * L - burn
         hidx = jnp.clip(hidx, 0, cap - 1)
-        hiddens = jnp.where(valid[:, None, None, None],
-                            bufs["hidden"][hidx], 0.0)
+        hiddens = stream_states(cfg, bufs["hidden"], hidx)
+        hiddens = jnp.where(valid[:, None, None, None], hiddens,
+                            jnp.zeros((), hiddens.dtype))
 
         # actor-side initial priorities (block.py:104-110: plain max-Q
         # n-step TD, replicating the reference's asymmetry vs the learner)
@@ -349,6 +363,7 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                                       cfg.eps_alpha)
                        for i in range(cfg.num_actors)], jnp.float32)
     act_net = _loss_net(cfg, net)  # the scan recurrence, grad-safe twin
+    hist = stream_spec(cfg)[0]
     emit_boundary = _make_emit(cfg, action_dim, done=False)
     emit_done = _make_emit(cfg, action_dim, done=True)
     env_keys = tuple(env.STATE_KEYS)
@@ -407,7 +422,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                    ast["buf_last_action"].at[lanes, p].set(one_hot),
                "buf_last_reward":
                    ast["buf_last_reward"].at[lanes, p].set(reward),
-               "buf_hidden": ast["buf_hidden"].at[lanes, p].set(new_hidden),
+               "buf_hidden": ast["buf_hidden"].at[lanes, p + hist].set(
+                   stream_entry(cfg, new_hidden)),
                "buf_action":
                    ast["buf_action"].at[lanes, s].set(
                        actions.astype(jnp.uint8)),
@@ -453,7 +469,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "obs": obs_next,
                "last_action": jnp.where(trc, 0.0, ast["last_action"]),
                "last_reward": jnp.where(tr, 0.0, ast["last_reward"]),
-               "hidden": jnp.where(tr[:, None, None, None], 0.0,
+               "hidden": jnp.where(tr[:, None, None, None],
+                                   jnp.zeros((), ast["hidden"].dtype),
                                    ast["hidden"]),
                "episode_steps": jnp.where(tr, 0, ast["episode_steps"]),
                "sum_reward": jnp.where(tr, 0.0, ast["sum_reward"]),
@@ -466,9 +483,7 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                    jnp.where(trc, noop, ast["buf_last_action"][:, 0])),
                "buf_last_reward": ast["buf_last_reward"].at[:, 0].set(
                    jnp.where(tr, 0.0, ast["buf_last_reward"][:, 0])),
-               "buf_hidden": ast["buf_hidden"].at[:, 0].set(
-                   jnp.where(tr[:, None, None, None], 0.0,
-                             ast["buf_hidden"][:, 0])),
+               "buf_hidden": reset_stream(cfg, ast["buf_hidden"], tr),
                **{f"env_{k}": env_state[k] for k in env_keys}}
 
         # 7) deferred boundary cut next step (worker.py block-cut rule)
@@ -499,17 +514,26 @@ def _retain_prefix(cfg: Config, ast: dict, cut: jnp.ndarray) -> dict:
                     j[None, :])                                 # (N, cap)
     rows = jnp.arange(N)[:, None]
 
-    def shift(name):
+    def shift(name, src=src):
         arr = ast[name]
         shifted = arr[rows, src]
         return jnp.where(cut.reshape((N, 1) + (1,) * (arr.ndim - 2)),
                          shifted, arr)
 
+    # the state stream keeps its history (models/state.stream_spec) in
+    # front of the same entries: ``hist`` more are kept, from the same lo
+    hist = stream_spec(cfg)[0]
+    src_h = src
+    if hist:
+        jh = jnp.arange(cap + hist, dtype=jnp.int32)
+        src_h = jnp.where(jh[None, :] < (keep + hist)[:, None],
+                          jh[None, :] + lo[:, None], jh[None, :])
+
     return {**ast,
             "buf_obs": shift("buf_obs"),
             "buf_last_action": shift("buf_last_action"),
             "buf_last_reward": shift("buf_last_reward"),
-            "buf_hidden": shift("buf_hidden"),
+            "buf_hidden": shift("buf_hidden", src_h),
             "prefix": jnp.where(cut, keep - 1, ast["prefix"]),
             "size": jnp.where(cut, 0, ast["size"])}
 
@@ -544,7 +568,7 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
     N, A, BL = cfg.num_actors, action_dim, cfg.block_length
     cap = cfg.max_block_steps
     obs_shape = cfg.stored_obs_shape
-    layers, H = cfg.lstm_layers, cfg.hidden_dim
+    hist, entry_shape, state_dtype = stream_spec(cfg)
 
     env_key, act_key = jax.random.split(key)
     env_state = env.init_state(env_key)
@@ -557,7 +581,7 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
         obs=obs0,
         last_action=jnp.zeros((N, A), jnp.float32),
         last_reward=jnp.zeros(N, jnp.float32),
-        hidden=jnp.zeros((N, 2, layers, H), jnp.float32),
+        hidden=zero_hidden(cfg, N),
         # frames as flat byte rows, a staged slot's format
         # (replay/device_ring._slot_shapes): the cut packs them into the
         # ring's words
@@ -565,7 +589,7 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
                           ).at[:, 0].set(obs0.reshape(N, -1)),
         buf_last_action=jnp.asarray(buf_la),
         buf_last_reward=jnp.zeros((N, cap), jnp.float32),
-        buf_hidden=jnp.zeros((N, cap, 2, layers, H), jnp.float32),
+        buf_hidden=jnp.zeros((N, cap + hist) + entry_shape, state_dtype),
         buf_action=jnp.zeros((N, BL), jnp.uint8),
         buf_reward=jnp.zeros((N, BL), jnp.float32),
         buf_qval=jnp.zeros((N, BL + 1, A), jnp.float32),
@@ -690,6 +714,8 @@ def make_anakin_super_step(cfg: Config, net: R2D2Network,
         parts = [losses, _stats_vec(ast)]
         if eval_lane is not None:
             parts.append(eval_lane(train_state.params, dispatch_idx))
+        if counter_names(cfg):
+            parts.append(read_counters(cfg, train_state.params))
         if diags is not None:
             parts.append(diags.reshape(-1))
         flat = jnp.concatenate(parts)
@@ -883,12 +909,24 @@ class AnakinPlane:
         self.eval_episodes_total = 0
         self.eval_return_total = 0.0
         self.last_eval_return = float("nan")
+        # the newest dispatch's model counters, by name
+        self.model_counters: Dict[str, float] = {}
         # interval accumulators, reset by stats() (ReplayBuffer.stats
         # semantics so the log loop code is shared-shaped)
         self._interval_episodes = 0
         self._interval_reward = 0.0
         self._interval_loss = 0.0
         self._interval_eval_episodes = 0
+
+    def release(self) -> None:
+        """Delete every device buffer the loop holds (carry, ring, PER
+        leaves): after the last harvest, so that a caller that goes on to
+        use the device finds it empty."""
+        meta = self.ring.per_meta()
+        for leaf in jax.tree.leaves((self.state, self.ring.arrays,
+                                     self.ring.take_prios(), meta)):
+            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                leaf.delete()
 
     # ----------------------------------------------------------- dispatch
     def _handles(self):
@@ -957,6 +995,11 @@ class AnakinPlane:
                     self.eval_return_total += rsum
                     self.last_eval_return = rsum / ep
                     self._interval_eval_episodes += int(ep)
+        names = counter_names(self.cfg)
+        if names:
+            self.model_counters = dict(zip(
+                names, v[off:off + len(names)].tolist()))
+            off += len(names)
         if self.monitor is not None:
             # the monitor owns non-finite handling (trips a clean fabric
             # stop + the nonfinite alert) and absorbs the diag rows the
@@ -1002,7 +1045,8 @@ class AnakinPlane:
                        episodes_total=self.episodes_total,
                        eval_episodes=self.eval_episodes_total,
                        interval_eval_episodes=self._interval_eval_episodes,
-                       eval_return=self.last_eval_return)
+                       eval_return=self.last_eval_return,
+                       model_counters=dict(self.model_counters))
             self._interval_episodes = 0
             self._interval_reward = 0.0
             self._interval_loss = 0.0

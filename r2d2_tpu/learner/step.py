@@ -34,7 +34,7 @@ import optax
 from flax import struct
 
 from r2d2_tpu.config import Config
-from r2d2_tpu.models.network import R2D2Network
+from r2d2_tpu.models.network import R2D2Network, step_buffers
 
 
 def value_rescale(x: jnp.ndarray, eps: float = 1e-3) -> jnp.ndarray:
@@ -112,7 +112,10 @@ def mixed_priorities(abs_td, mask, learning, eta=0.9):
 
 def _double_unroll(cfg: Config, net: R2D2Network, params, target_params,
                    batch) -> tuple:
-    """(q_online, q_target_seq), each (B, T, A).
+    """(q_online, q_target_seq, stats): the Q sequences, each (B, T, A),
+    and what the online pass sowed under "stats" for the model's own
+    ``step_buffers`` (models/network.py) — an empty tree where the model
+    sows nothing.
 
     Default: two independent unrolls (reference semantics — worker.py's
     separate online/target forwards).  With ``cfg.fused_double_unroll``,
@@ -127,16 +130,18 @@ def _double_unroll(cfg: Config, net: R2D2Network, params, target_params,
     inference-only since r5 and would fail under the surrounding
     grad / vmap)."""
     if not cfg.fused_double_unroll:
-        q_online, _ = net.apply(params, batch["obs"], batch["last_action"],
-                                batch["last_reward"], batch["hidden"],
-                                method=R2D2Network.unroll)      # (B, T, A)
+        (q_online, _), sown = net.apply(
+            params, batch["obs"], batch["last_action"],
+            batch["last_reward"], batch["hidden"],
+            method=R2D2Network.unroll, mutable=["stats"])       # (B, T, A)
         with jax.named_scope("target_forward"):
             q_target_seq, _ = net.apply(target_params, batch["obs"],
                                         batch["last_action"],
                                         batch["last_reward"],
                                         batch["hidden"],
                                         method=R2D2Network.unroll)
-        return q_online, jax.lax.stop_gradient(q_target_seq)
+        return (q_online, jax.lax.stop_gradient(q_target_seq),
+                sown.get("stats", {}))
 
     stacked = jax.tree.map(
         lambda p, t: jnp.stack([p, t]),
@@ -145,7 +150,7 @@ def _double_unroll(cfg: Config, net: R2D2Network, params, target_params,
         lambda p: net.apply(p, batch["obs"], batch["last_action"],
                             batch["last_reward"], batch["hidden"],
                             method=R2D2Network.unroll))(stacked)
-    return q_both[0], jax.lax.stop_gradient(q_both[1])
+    return q_both[0], jax.lax.stop_gradient(q_both[1]), {}
 
 
 def _loss_net(cfg: Config, net: R2D2Network) -> R2D2Network:
@@ -168,9 +173,19 @@ def loss_and_priorities(cfg: Config, net: R2D2Network, params, target_params,
     """``with_aux`` additionally returns the forward-pass intermediates
     the learnhealth diagnostics consume ``(td, mask, q_learn, max_abs_q)``
     — stop-gradiented values, never a second forward."""
-    q_online, q_target_seq = _double_unroll(cfg, net, params, target_params,
-                                            batch)
+    q_online, q_target_seq, _ = _double_unroll(cfg, net, params,
+                                               target_params, batch)
     return _td_loss(cfg, batch, q_online, q_target_seq, with_aux)
+
+
+def _loss_and_stats(cfg: Config, net: R2D2Network, params, target_params,
+                    batch, with_aux: bool):
+    """:func:`loss_and_priorities` with what the online pass sowed beside
+    its auxiliary output: ``(loss, (priorities[, aux], stats))``."""
+    q_online, q_target_seq, stats = _double_unroll(
+        cfg, net, params, target_params, batch)
+    loss, out = _td_loss(cfg, batch, q_online, q_target_seq, with_aux)
+    return loss, (out, stats)
 
 
 @jax.named_scope("loss")
@@ -233,17 +248,26 @@ def make_train_step(cfg: Config, net: R2D2Network,
         diag_fn = make_diag_fn(cfg, net)
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray]):
+        # only the "params" collection is differentiated.  What else a
+        # model keeps (models/network.py: "buffers", state that is no
+        # gradient leaf) the optimizer sees zero gradients for, and the
+        # model's own step_buffers writes it after the update
+        rest = {c: v for c, v in state.params.items() if c != "params"}
         grad_fn = jax.value_and_grad(
-            lambda p: loss_and_priorities(cfg, net, p, state.target_params,
-                                          batch, with_aux=lh),
-            has_aux=True)
-        (loss, priorities), grads = grad_fn(state.params)
+            lambda p: _loss_and_stats(
+                cfg, net, {**rest, "params": p}, state.target_params,
+                batch, with_aux=lh), has_aux=True)
+        (loss, (priorities, stats)), grads = grad_fn(state.params["params"])
+        grads = {**jax.tree.map(jnp.zeros_like, rest), "params": grads}
         if lh:
             priorities, aux = priorities
         with jax.named_scope("optimizer"):
             updates, new_opt_state = opt.update(grads, state.opt_state,
                                                 state.params)
             new_params = optax.apply_updates(state.params, updates)
+            if "buffers" in rest:
+                new_params = {**new_params, "buffers": step_buffers(
+                    cfg, rest["buffers"], stats)}
 
             step = state.step + 1
             sync = (step % cfg.target_net_update_interval) == 0

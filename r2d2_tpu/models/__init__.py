@@ -7,5 +7,7 @@ from r2d2_tpu.models.network import (
     DuelingHead,
     create_network,
     init_params,
+    state_spec,
+    zero_state,
     zero_hidden,
 )

@@ -20,8 +20,11 @@ TPU-first redesign:
   scaled-model benchmark config; ``mlp`` torso supports fast tests.
 - Optional rematerialisation of the scan body for long unrolls.
 
-Recurrent state wire format everywhere: ``(B, 2, layers, H)`` float32 where
-axis 1 is (h, c).
+Recurrent state: one array a sequence, whose shape and dtype the model
+owns (models/state.py, :func:`state_spec`) and every other module derives — for the LSTM
+``(2, layers, H)`` float32 where axis 0 is (h, c); for ``core="xing4"``
+(models/xing4.py) the latent cache ``(layers, W, latent)`` in the compute
+dtype.  Zeros are the initial state of both.
 """
 from __future__ import annotations
 
@@ -33,6 +36,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec, zero_state  # noqa: F401
 
 
 def _dtype(name: str):
@@ -237,14 +241,19 @@ class R2D2Network(nn.Module):
         if cfg.torso == "nature":
             torso_kw["s2d_input"] = cfg.obs_space_to_depth
         self.torso = torso_cls(**torso_kw)
-        impl = resolve_lstm_impl(cfg)
-        self.lstm_layers_ = [
-            LSTMLayer(hidden_dim=cfg.hidden_dim, compute_dtype=cd,
-                      param_dtype=pd, remat=cfg.remat, impl=impl,
-                      interpret=cfg.pallas_interpret,
-                      name=f"lstm_{i}")
-            for i in range(cfg.lstm_layers)
-        ]
+        if cfg.core == "xing4":
+            from r2d2_tpu.models.xing4 import Xing4Core
+
+            self.core = Xing4Core(cfg=cfg, compute_dtype=cd, param_dtype=pd)
+        else:
+            impl = resolve_lstm_impl(cfg)
+            self.lstm_layers_ = [
+                LSTMLayer(hidden_dim=cfg.hidden_dim, compute_dtype=cd,
+                          param_dtype=pd, remat=cfg.remat, impl=impl,
+                          interpret=cfg.pallas_interpret,
+                          name=f"lstm_{i}")
+                for i in range(cfg.lstm_layers)
+            ]
         self.head = DuelingHead(hidden_dim=cfg.hidden_dim,
                                 action_dim=self.action_dim,
                                 compute_dtype=cd, param_dtype=pd)
@@ -275,7 +284,9 @@ class R2D2Network(nn.Module):
         with jax.named_scope("torso"):
             feats = self._features(obs, last_action, last_reward)
         with jax.named_scope("core"):
-            outs, new_hidden = self._lstm_stack(feats, hidden)
+            outs, new_hidden = (self.core(feats, hidden)
+                                if self.cfg.core == "xing4"
+                                else self._lstm_stack(feats, hidden))
         with jax.named_scope("heads"):
             B, T = outs.shape[:2]
             q = self.head(outs.reshape(B * T, -1)).reshape(B, T, -1)
@@ -313,9 +324,57 @@ def init_params(cfg: Config, net: R2D2Network, key: jax.Array):
     obs = jnp.zeros((B, T, *cfg.stored_obs_shape), jnp.uint8)
     la = jnp.zeros((B, T, net.action_dim), jnp.float32)
     lr = jnp.zeros((B, T), jnp.float32)
-    hidden = jnp.zeros((B, 2, cfg.lstm_layers, cfg.hidden_dim), jnp.float32)
-    return net.init(key, obs, la, lr, hidden, method=R2D2Network.unroll)
+
+    def init(key):
+        variables = net.init(key, obs, la, lr, zero_hidden(cfg, B),
+                             method=R2D2Network.unroll)
+        # what a forward pass sows is not part of the weights
+        return {k: v for k, v in variables.items() if k != "stats"}
+
+    if cfg.core == "xing4":
+        # one program that draws the weights and nothing else: op by op,
+        # the tracing pass over blocks this wide takes minutes.  The LSTM
+        # networks keep drawing op by op: on the TPU one fused program
+        # rounds the scaled normal draws differently (every conv, dense
+        # and head kernel of both benchmark configurations came out as
+        # other bytes; my chip run, PR 28), and a seed's weights are what
+        # every earlier measurement of them rests on
+        return jax.jit(init)(key)
+    return init(key)
 
 
 def zero_hidden(cfg: Config, batch: int) -> jnp.ndarray:
-    return jnp.zeros((batch, 2, cfg.lstm_layers, cfg.hidden_dim), jnp.float32)
+    shape, dtype = state_spec(cfg)
+    return jnp.zeros((batch,) + shape, dtype)
+
+
+# What a model keeps beside its weights.  A core may declare a "buffers"
+# collection: state that the train step writes and no gradient reaches
+# (learner/step.py differentiates the "params" collection alone), copied
+# with the target network and checkpointed like any leaf.  Its online pass
+# sows under "stats" what the step needs to write them.  The LSTM declares
+# neither, and the three hooks below are then the identity, () and nothing.
+
+def step_buffers(cfg: Config, buffers, stats):
+    """The "buffers" collection after an update whose online pass sowed
+    ``stats``."""
+    if cfg.core == "xing4":
+        from r2d2_tpu.models import xing4
+
+        return xing4.step_buffers(cfg, buffers, stats)
+    return buffers
+
+
+def counter_names(cfg: Config) -> tuple:
+    """Names of the float32 scalars the model's train step leaves in its
+    buffers for the host to log, in :func:`read_counters`' order."""
+    if cfg.core == "xing4":
+        from r2d2_tpu.models import xing4
+
+        return xing4.COUNTERS
+    return ()
+
+
+def read_counters(cfg: Config, variables) -> jnp.ndarray:
+    """``(len(counter_names(cfg)),)`` float32 from the model's variables."""
+    return variables["buffers"]["core"]["counters"]

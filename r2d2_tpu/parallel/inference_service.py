@@ -101,6 +101,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec, zero_state
 from r2d2_tpu.parallel.actor_procs import FleetStopped
 from r2d2_tpu.replay.block import payload_crc32, slot_layout, slot_views
 from r2d2_tpu.telemetry.tracing import EVENTS
@@ -146,17 +147,17 @@ def act_slot_spec(cfg: Config, action_dim: int, num_lanes: int):
     q row per lane, the post-step hidden rows for block recording, and
     the response CRC32 (written last)."""
     n = num_lanes
+    state_shape, state_dtype = state_spec(cfg)
     return (
         ("obs", (n, *cfg.stored_obs_shape), np.uint8),
         ("last_action", (n, action_dim), np.float32),
         ("last_reward", (n,), np.float32),
         ("reset_mask", (n,), np.uint8),
-        ("sync_hidden", (n, 2, cfg.lstm_layers, cfg.hidden_dim),
-         np.float32),
+        ("sync_hidden", (n,) + state_shape, state_dtype),
         ("req_seq", (1,), np.int64),
         ("req_crc", (1,), np.uint32),
         ("q", (n, action_dim), np.float32),
-        ("rsp_hidden", (n, 2, cfg.lstm_layers, cfg.hidden_dim), np.float32),
+        ("rsp_hidden", (n,) + state_shape, state_dtype),
         ("rsp_crc", (1,), np.uint32),
     )
 
@@ -509,8 +510,7 @@ class InferenceService:
         self.channels: List[Optional[ActChannel]] = [None] * F
         self._graveyard: List[ActChannel] = []
         N = cfg.num_actors
-        self.hidden = np.zeros((N, 2, cfg.lstm_layers, cfg.hidden_dim),
-                               np.float32)
+        self.hidden = zero_state(cfg, N)
         self._hidden_lock = threading.Lock()
         # full-batch request scratch, indexed by global lane id
         self.obs = np.zeros((N, *cfg.stored_obs_shape), np.uint8)

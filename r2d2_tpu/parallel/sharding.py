@@ -97,6 +97,16 @@ DEFAULT_TABLE: Dict[str, Tuple[Optional[str], ...]] = {
     # divisibility guard wherever tp does not divide them
     "head.*.kernel": ("fsdp", "tp"),
     "head.*.bias": ("tp",),
+    # the xing4 memory core (models/xing4.py): every leaf replicated —
+    # parameters stacked by block, and the router's buffers.  A chip
+    # holds its share of a layer's heads and experts by configuration
+    # (core_heads_held, core_experts_held); there is no expert axis and no
+    # exchange yet (ROADMAP Queue 2), so a mesh only adds dp.  One entry
+    # a depth of the core's tree
+    "core.*": (),
+    "core.*.*": (),
+    "core.*.*.*": (),
+    "core.*.*.*.*": (),
     # device-replay plane: ring slots and PER leaves shard over dp when
     # the ring layout asks for it (DeviceRing consumes these entries)
     "ring.*": ("dp",),

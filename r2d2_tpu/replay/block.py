@@ -29,6 +29,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec, zero_state
 from r2d2_tpu.utils.math import mixed_td_errors, n_step_gamma_tail, n_step_return
 
 
@@ -37,7 +38,8 @@ class Block:
     """One actor-produced chunk of experience.
 
     Array shapes (S = env steps in the block, P = burn-in prefix length,
-    K = number of sequences, A = action dim, layers/H = LSTM geometry):
+    K = number of sequences, A = action dim; one recurrent state has the
+    shape and dtype of ``models.state.state_spec(cfg)``):
 
     - ``obs``:          (P + S + 1, *obs_shape) uint8 — includes next-obs tail
     - ``last_action``:  (P + S + 1, A) bool (one-hot)
@@ -45,7 +47,7 @@ class Block:
     - ``action``:       (S,) uint8
     - ``n_step_reward``:(S,) float32
     - ``n_step_gamma``: (S,) float32 — 0 tail encodes terminal
-    - ``hidden``:       (K, 2, layers, H) float32 — state at burn-in start
+    - ``hidden``:       (K, *state) — state at burn-in start
     - ``burn_in_steps``/``learning_steps``/``forward_steps``: (K,) uint8
     """
     obs: np.ndarray
@@ -113,7 +115,7 @@ def assemble_block(cfg: Config, *, obs: np.ndarray, last_action: np.ndarray,
         hidden_idx = seq_ids * L
     else:
         hidden_idx = c + seq_ids * L - burn_in.astype(np.int64)
-    hiddens = np.asarray(hidden_stream[hidden_idx], np.float32)
+    hiddens = np.asarray(hidden_stream[hidden_idx], state_spec(cfg)[1])
 
     max_forward = min(size, n)
     max_q = qvals[max_forward:size + 1].max(axis=1)
@@ -206,11 +208,12 @@ def batch_slot_spec(cfg: Config, action_dim: int, batch_size: int):
     ``rsp_crc`` — written LAST, the block channel's torn-write
     discipline."""
     B, T, L = batch_size, cfg.seq_len, cfg.learning_steps
+    state_shape, state_dtype = state_spec(cfg)
     return (
         ("obs", (B, T, *cfg.stored_obs_shape), np.uint8),
         ("last_action", (B, T, action_dim), np.float32),
         ("last_reward", (B, T), np.float32),
-        ("hidden", (B, 2, cfg.lstm_layers, cfg.hidden_dim), np.float32),
+        ("hidden", (B,) + state_shape, state_dtype),
         ("action", (B, L), np.int32),
         ("n_step_reward", (B, L), np.float32),
         ("n_step_gamma", (B, L), np.float32),
@@ -379,7 +382,7 @@ class LocalBuffer:
     def __init__(self, cfg: Config, action_dim: int):
         self.cfg = cfg
         self.action_dim = action_dim
-        self.hidden_shape = (2, cfg.lstm_layers, cfg.hidden_dim)
+        self.hidden_shape, self.hidden_dtype = state_spec(cfg)
         self.curr_burn_in_steps = 0
         self.size = 0
 
@@ -392,7 +395,7 @@ class LocalBuffer:
         self.obs_buffer: List[np.ndarray] = [np.asarray(init_obs, dtype=np.uint8)]
         self.last_action_buffer: List[np.ndarray] = [noop_one_hot]
         self.last_reward_buffer: List[float] = [0.0]
-        self.hidden_buffer: List[np.ndarray] = [np.zeros(self.hidden_shape, np.float32)]
+        self.hidden_buffer: List[np.ndarray] = [np.zeros(self.hidden_shape, self.hidden_dtype)]
         self.action_buffer: List[int] = []
         self.reward_buffer: List[float] = []
         self.qval_buffer: List[np.ndarray] = []
@@ -413,7 +416,7 @@ class LocalBuffer:
         self.obs_buffer.append(np.asarray(next_obs, dtype=np.uint8))
         self.last_action_buffer.append(one_hot)
         self.last_reward_buffer.append(reward)
-        self.hidden_buffer.append(np.asarray(hidden, np.float32).reshape(self.hidden_shape))
+        self.hidden_buffer.append(np.asarray(hidden, self.hidden_dtype).reshape(self.hidden_shape))
         self.qval_buffer.append(np.asarray(q_value, np.float32).reshape(self.action_dim))
         self.sum_reward += reward
         self.size += 1
@@ -494,8 +497,7 @@ class VectorLocalBuffer:
         self.obs = np.zeros((N, cap, *cfg.stored_obs_shape), np.uint8)
         self.last_action = np.zeros((N, cap, action_dim), bool)
         self.last_reward = np.zeros((N, cap), np.float32)
-        self.hidden = np.zeros(
-            (N, cap, 2, cfg.lstm_layers, cfg.hidden_dim), np.float32)
+        self.hidden = zero_state(cfg, N, cap)
         self.action = np.zeros((N, B), np.uint8)
         self.reward = np.zeros((N, B), np.float32)
         self.qval = np.zeros((N, B + 1, action_dim), np.float32)

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec
 from r2d2_tpu.replay.block import Block
 
 # data arrays mirrored on device; the count arrays (burn_in/learning/
@@ -172,7 +173,8 @@ def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
     (my chip runs, PR 26).  :func:`gather_batch` restores
     ``cfg.stored_obs_shape`` bytes."""
     MS, BL = cfg.max_block_steps, cfg.block_length
-    K, layers, H = cfg.seqs_per_block, cfg.lstm_layers, cfg.hidden_dim
+    K = cfg.seqs_per_block
+    state_shape, state_dtype = state_spec(cfg)
     obs_rows = -(-MS // _OBS_ROW_TILE) * _OBS_ROW_TILE
     rows = MS + window_tail(cfg)
     return dict(
@@ -182,7 +184,7 @@ def _slot_shapes(cfg: Config, action_dim: int) -> Dict[str, Any]:
         action=((BL,), np.uint8),
         n_step_reward=((BL,), np.float32),
         n_step_gamma=((BL,), np.float32),
-        hidden=((K, 2, layers, H), np.float32),
+        hidden=((K,) + state_shape, state_dtype),
     )
 
 

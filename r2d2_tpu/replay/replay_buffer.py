@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec
 from r2d2_tpu.replay.block import Block, slot_layout, slot_views
 from r2d2_tpu.replay.sum_tree import SumTree
 from r2d2_tpu.telemetry.tracing import EVENTS
@@ -54,7 +55,8 @@ def _data_spec(cfg: Config, action_dim: int):
     """(name, shape, dtype) of the bulk experience arrays.  These are the
     arrays that can live on-device instead (replay/device_ring.py)."""
     NB, K, MS = cfg.num_blocks, cfg.seqs_per_block, cfg.max_block_steps
-    BL, layers, H = cfg.block_length, cfg.lstm_layers, cfg.hidden_dim
+    BL = cfg.block_length
+    state_shape, state_dtype = state_spec(cfg)
     return (
         ("obs", (NB, MS, *cfg.stored_obs_shape), np.uint8),
         ("last_action", (NB, MS, action_dim), bool),
@@ -62,7 +64,7 @@ def _data_spec(cfg: Config, action_dim: int):
         ("action", (NB, BL), np.uint8),
         ("n_step_reward", (NB, BL), np.float32),
         ("n_step_gamma", (NB, BL), np.float32),
-        ("hidden", (NB, K, 2, layers, H), np.float32),
+        ("hidden", (NB, K) + state_shape, state_dtype),
     )
 
 

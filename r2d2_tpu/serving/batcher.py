@@ -29,6 +29,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import state_spec, zero_state
 from r2d2_tpu.utils.trace import HOST_TRANSFERS, TRANSFER_GUARD
 
 
@@ -127,8 +128,8 @@ class ContinuousBatcher:
         la = np.zeros((n, self.action_dim), np.float32)
         la[np.arange(n), rng.integers(self.action_dim, size=n)] = 1.0
         lr = rng.normal(size=n).astype(np.float32)
-        hid = (rng.normal(size=(n, 2, cfg.lstm_layers, cfg.hidden_dim))
-               .astype(np.float32) * 0.1)
+        hid = (rng.normal(size=(n,) + state_spec(cfg)[0]) * 0.1).astype(
+            state_spec(cfg)[1])
         params = jax.device_put(params, self._act.device)
         q_ref, _ = self._act(params, obs, la, lr, hid)
         q_bf16, _ = self._act(self._quantize(params), obs, la, lr, hid)
@@ -162,8 +163,7 @@ class ContinuousBatcher:
                 obs=np.zeros((b, *cfg.stored_obs_shape), np.uint8),
                 last_action=np.zeros((b, self.action_dim), np.float32),
                 last_reward=np.zeros(b, np.float32),
-                hidden=np.zeros((b, 2, cfg.lstm_layers, cfg.hidden_dim),
-                                np.float32))
+                hidden=zero_state(cfg, b))
         return s
 
     def act(self, obs: np.ndarray, last_action: np.ndarray,

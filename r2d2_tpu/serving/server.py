@@ -643,6 +643,13 @@ def run_server(cfg: Config, checkpoint_dir: str,
 
     from r2d2_tpu.checkpoint import Checkpointer, check_arch_compat
 
+    if cfg.core != "lstm":
+        raise ValueError(
+            f"the session tier does not serve core={cfg.core!r}: its pool "
+            "(serving/store.py) keeps one whole recurrent state a session "
+            "on the host and moves it to the device and back every act — "
+            "a latent cache would need to stay on the device (ROADMAP "
+            "Queue 2)")
     ckpt = Checkpointer(checkpoint_dir)
     step = ckpt.latest_step()
     if step is None and not follow:

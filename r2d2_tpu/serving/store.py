@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.models.state import zero_state
 
 
 class _Session:
@@ -62,9 +63,7 @@ class SessionStore:
     def __init__(self, cfg: Config):
         self.cfg = cfg
         self.max_sessions = int(cfg.serve_max_sessions)
-        self.hidden = np.zeros(
-            (self.max_sessions, 2, cfg.lstm_layers, cfg.hidden_dim),
-            np.float32)
+        self.hidden = zero_state(cfg, self.max_sessions)
         self._lock = threading.Lock()
         self._sessions: "OrderedDict[int, _Session]" = OrderedDict()
         self._free: List[int] = list(range(self.max_sessions - 1, -1, -1))

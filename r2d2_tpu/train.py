@@ -330,13 +330,21 @@ def _device_memory_bytes() -> Optional[int]:
     return min(limits) if limits else None
 
 
-def _checked_ring_layout(cfg: Config, action_dim: int, mesh) -> str:
+def _tree_bytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _checked_ring_layout(cfg: Config, action_dim: int, mesh,
+                         state_bytes: int = 0) -> str:
     """Resolve ``cfg.device_ring_layout`` for this bring-up and REFUSE a
     ring that does not fit: the caller asked for the device-replay
     drivetrain, and quietly running host replay under its name would
     hide the device from every metric the run reports.  The budget is 80%
     of the device's limit (headroom for params, activations and staged
-    slots)."""
+    slots), less what a train state that is itself most of a chip takes
+    with its gradients (``state_bytes``: weights, target, Adam moments;
+    gradients a quarter more) — with the LSTM networks that is under a
+    hundredth of the device and 80% stands."""
     from r2d2_tpu.replay.device_ring import device_bytes, resolve_layout
     from r2d2_tpu.replay.replay_buffer import _available_host_bytes
 
@@ -350,14 +358,18 @@ def _checked_ring_layout(cfg: Config, action_dim: int, mesh) -> str:
     shards = (mesh.shape["dp"]
               if layout == "dp" and dev_cap is not None else 1)
     cap = dev_cap if dev_cap is not None else _available_host_bytes()
-    if cap is not None and need // shards > 0.8 * cap:
-        fits = (int(0.8 * cap * shards // (need // cfg.num_blocks))
+    budget = None if cap is None else min(0.8 * cap,
+                                          cap - 1.25 * state_bytes)
+    if cap is not None and need // shards > budget:
+        fits = (int(max(budget, 0) * shards // (need // cfg.num_blocks))
                 * cfg.block_length)
         raise ValueError(
             f"device_replay ring needs {need // shards / 1e9:.2f} GB per "
             f"device (layout={layout}, buffer_capacity="
             f"{cfg.buffer_capacity}) but the device's limit is "
-            f"{cap / 1e9:.2f} GB and the ring may take 80% of it — "
+            f"{cap / 1e9:.2f} GB and the ring may take "
+            f"{max(budget, 0) / 1e9:.2f} GB of it (80%, less a train "
+            f"state of {state_bytes / 1e9:.2f} GB with its gradients) — "
             f"buffer_capacity={fits} fits (or shard the ring over more "
             "devices with --mesh)")
     return layout
@@ -732,6 +744,7 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     net = create_network(cfg, action_dim)
     params = init_params(cfg, net, jax.random.PRNGKey(cfg.seed))
     state = create_train_state(cfg, params)
+    del params      # the state holds copies; a large model's would not fit
     checkpointer = (Checkpointer(checkpoint_dir, keep=cfg.keep_checkpoints)
                     if checkpoint_dir else None)
     start_env_steps, start_minutes = 0, 0.0
@@ -752,7 +765,8 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     # single-device path is unchanged.
     mesh = make_mesh(cfg) if use_mesh else None
     table = None
-    layout = _checked_ring_layout(cfg, action_dim, mesh)
+    layout = _checked_ring_layout(cfg, action_dim, mesh,
+                                  state_bytes=_tree_bytes(state))
     if mesh is not None:
         from r2d2_tpu.parallel.sharding import ShardingTable
 
@@ -846,6 +860,14 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
                 continue
             s = plane.stats()
             dt = now - last_time
+            lc = s["model_counters"]
+            if "held_pair_share" in lc:
+                # the routed experts' counters (models/xing4.COUNTERS) of
+                # the newest harvested dispatch: they ride its result vector
+                tracer.gauge("core.held_pair_share", lc["held_pair_share"])
+                tracer.gauge("core.held_load_max_over_mean",
+                             lc["held_load_max_over_mean"])
+                tracer.gauge("core.router_bias_max", lc["router_bias_max"])
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"],
@@ -870,6 +892,8 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
                             eval_episodes=s["eval_episodes"],
                             eval_return=s["eval_return"]),
             )
+            if lc:
+                entry["core"] = lc
             # learnhealth + alerts: the anakin PER leaves live in-graph
             # (no host tree to walk), so no replay data-health here —
             # the in-graph diag bundle covers the learner side
@@ -931,9 +955,18 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
         if want_full_save and not metrics.get("dispatch_wedged"):
             save_anakin_snapshot(learner.num_updates)
 
+        # the run is over: hand the weights back on the host and leave the
+        # device empty (the loop's carry and ring, the train state), so
+        # that what the caller does next has the chip to itself — a model
+        # whose train state is most of a chip leaves room for nothing else
+        final_params = jax.device_get(learner.state.params)
+        plane.release()
+        for leaf in jax.tree.leaves(learner.state):
+            if not leaf.is_deleted():
+                leaf.delete()
         metrics.update(buffer_size=plane.fill, logs=list(logs),
                        buffer_training_steps=plane.training_steps,
-                       final_params=learner.state.params,
+                       final_params=final_params,
                        # acting is in-graph: it ran where the loss did
                        act_platform=jax.local_devices()[0].platform,
                        device_memory=device_memory(),

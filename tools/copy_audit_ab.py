@@ -59,6 +59,7 @@ def act_fetch_cell() -> dict:
 
     from r2d2_tpu.actor import make_act_fn
     from r2d2_tpu.models.network import create_network, init_params
+    from r2d2_tpu.models.state import state_spec
 
     cfg = _cfg()
     net = create_network(cfg, A)
@@ -69,8 +70,8 @@ def act_fetch_cell() -> dict:
     obs = rng.integers(0, 256, (n, *cfg.stored_obs_shape)).astype(np.uint8)
     la = rng.random((n, A)).astype(np.float32)
     lr = rng.random(n).astype(np.float32)
-    hid = (rng.normal(size=(n, 2, cfg.lstm_layers, cfg.hidden_dim))
-           * 0.1).astype(np.float32)
+    hid = (rng.normal(size=(n,) + state_spec(cfg)[0]) * 0.1).astype(
+        state_spec(cfg)[1])
     act(params, obs, la, lr, hid)  # compile outside the timed region
 
     old_ns, new_ns = [], []

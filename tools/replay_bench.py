@@ -41,6 +41,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np  # noqa: E402
 
 from r2d2_tpu.config import Config  # noqa: E402
+from r2d2_tpu.models.state import state_spec  # noqa: E402
 from r2d2_tpu.parallel.replay_net import NetShardedReplayPlane  # noqa: E402
 from r2d2_tpu.parallel.replay_shards import ShardedReplayPlane  # noqa: E402
 from r2d2_tpu.replay.block import LocalBuffer  # noqa: E402
@@ -78,8 +79,8 @@ def build_blocks(cfg, n, seed=0):
             local.add(int(rng.integers(A)), float(rng.normal()),
                       rng.integers(0, 256, cfg.stored_obs_shape, np.uint8),
                       rng.normal(size=A).astype(np.float32),
-                      rng.normal(size=(2, cfg.lstm_layers,
-                                       cfg.hidden_dim)).astype(np.float32))
+                      rng.normal(size=state_spec(cfg)[0]).astype(
+                          state_spec(cfg)[1]))
         block, prios, ep = local.finish(None)
         out.append((block, prios, ep))
     return out

@@ -21,114 +21,78 @@ only, so the file is read as the XSpace protocol buffer it is, with the
 ``xplane_pb2`` that ships beside the installed profiler plugin.  Self times
 and the busy union are ``benchmark/xplane.py``'s.
 
+``--inner`` splits the memory core further: an operation that lies under
+one of the core's own scopes (``attention``, ``residual_mix``, ``router``,
+``experts``, ``shared_expert``, ``dense_ffn``: models/xing4.py) is given to
+the innermost of them, whichever pass it runs in (online, target, acting),
+and the outer scopes keep what is left; the shares still sum to 100 %.
+
 A program loaded from a compile cache written before the scopes existed
 carries none (the cache key leaves metadata out): clear the cache once.
 """
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import os
-import re
 import sys
-from typing import Any, Collection, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import xplane  # noqa: E402
+from benchmark.reader_kinds.scope_anywhere import components  # noqa: E402
 
-SCOPES = ("torso", "core", "heads", "target_forward", "ring_gather",
-          "per_sample", "per_scatter", "loss", "optimizer",
-          "env_step", "act", "ring_write")
-SCOPE_STAT = "tf_op"
-NO_SCOPE = "(none)"
-_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+# the reduction is the benchmark's own (benchmark/xplane.py): the scope
+# metrics and this tool read the same split
+SCOPES, NO_SCOPE = xplane.SCOPES, xplane.NO_SCOPE
+scope_of = xplane.scope_of
+_xplane_pb2 = xplane._xplane_pb2
+INNER = ("attention", "residual_mix", "router", "experts", "shared_expert",
+         "dense_ffn")
 
 
-def scope_of(path: Optional[str],
-             scopes: Collection[str] = frozenset(SCOPES)) -> str:
-    """The outermost component of an ``op_name`` path that names a scope,
-    with ``.bwd`` where a ``transpose(...)`` wraps it or a component
-    outside it; :data:`NO_SCOPE` where none does."""
-    if not path:
-        return NO_SCOPE
-    backward = False
-    for part in path.split(":")[0].split("/"):
-        m = _WRAPPED.match(part)
-        while m:                      # jvp(x), transpose(jvp(x)), vmap(x)
-            backward = backward or m.group(1) == "transpose"
-            part = m.group(2)
-            m = _WRAPPED.match(part)
-        if part in scopes:
-            return part + (".bwd" if backward else "")
-    return NO_SCOPE
+def inner_scope_of(path: Optional[str]) -> str:
+    """The innermost component that names one of :data:`INNER`, with
+    ``.bwd`` as :func:`scope_of` gives it; else :func:`scope_of`'s
+    answer."""
+    outer = scope_of(path)
+    inner = [c for c in components(path or "") if c in INNER]
+    if not inner:
+        return outer
+    return inner[-1] + (".bwd" if outer.endswith(".bwd") else "")
 
 
 def split(events: List[Dict[str, Any]],
-          scopes: Collection[str] = frozenset(SCOPES)) -> Dict[str, float]:
-    """Percent of the busy time by scope.  ``events``: one device's
-    ``XLA Ops`` line as ``benchmark.xplane`` events, each with the
-    operation's ``op_name`` path under ``"path"``."""
-    busy_ns = 1e9 * xplane.busy_seconds(events)
-    if busy_ns <= 0:
-        return {}
-    by_path: Dict[Optional[str], float] = {}   # a step runs each op often
-    for ev, ns in xplane.self_times(events):
-        path = ev.get("path")
-        by_path[path] = by_path.get(path, 0.0) + ns
-    by_scope: Dict[str, float] = {}
-    for path, ns in by_path.items():
-        key = scope_of(path, scopes)
-        by_scope[key] = by_scope.get(key, 0.0) + ns
-    return {k: 100.0 * ns / busy_ns
-            for k, ns in sorted(by_scope.items(), key=lambda kv: -kv[1])}
+          scope_of: Optional[Callable[[Optional[str]], str]] = None
+          ) -> Dict[str, float]:
+    """Percent of the busy time by scope (``xplane.scope_split``).
+    ``events``: one device's ``XLA Ops`` line, each with the operation's
+    ``op_name`` path under ``"path"``.  Another ``scope_of`` files the
+    operations its own way: each is handed on under the one-component path
+    of the scope it names."""
+    if scope_of is None:
+        return xplane.scope_split(events)
 
+    def as_path(scope: str) -> Optional[str]:
+        if scope == NO_SCOPE:
+            return None
+        name, _, bwd = scope.partition(".")
+        return f"transpose({name})" if bwd else name
 
-def _xplane_pb2():
-    """``xplane_pb2`` of the installed tsl, loaded from its file: importing
-    the package around it would start all of TensorFlow."""
-    found = importlib.util.find_spec("tensorflow")
-    if found is None or not found.submodule_search_locations:
-        raise SystemExit("step_split: no xplane_pb2 is installed here "
-                         "(it ships with the profiler plugin's tensorflow)")
-    path = os.path.join(found.submodule_search_locations[0],
-                        "tsl", "profiler", "protobuf", "xplane_pb2.py")
-    spec = importlib.util.spec_from_file_location("xplane_pb2", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return xplane.scope_split(
+        [dict(ev, path=as_path(scope_of(ev.get("path")))) for ev in events],
+        frozenset(SCOPES) | frozenset(INNER))
 
 
 def load_ops(path: str, device: int = 0) -> List[Dict[str, Any]]:
-    """The ``XLA Ops`` events of one chip, each with its scope path."""
-    space = _xplane_pb2().XSpace()
-    with open(path, "rb") as f:
-        space.ParseFromString(f.read())
-    for plane in space.planes:
-        m = xplane.DEVICE_PLANE.match(plane.name)
-        if not m or int(m.group(1)) != device:
-            continue
-        stat_ids = {i for i, s in plane.stat_metadata.items()
-                    if s.name == SCOPE_STAT}
-        paths: Dict[int, str] = {}
-        for mid, md in plane.event_metadata.items():
-            for st in md.stats:
-                if st.metadata_id not in stat_ids:
-                    continue
-                if st.WhichOneof("value") == "ref_value":
-                    paths[mid] = plane.stat_metadata[st.ref_value].name
-                else:
-                    paths[mid] = st.str_value
-        for line in plane.lines:
-            if line.name != xplane.OPS_LINE:
-                continue
-            t0 = line.timestamp_ns
-            return [dict(name=plane.event_metadata[ev.metadata_id].name,
-                         start_ns=t0 + ev.offset_ps // 1000,
-                         dur_ns=ev.duration_ps // 1000,
-                         path=paths.get(ev.metadata_id))
-                    for ev in line.events]
+    """The ``XLA Ops`` events of one chip, each with its scope path (None
+    where the operation carries none)."""
+    trace = xplane.load(path, planes=r"^/device:", lines=f"^{xplane.OPS_LINE}$")
+    for plane in xplane.device_planes(trace):
+        if int(xplane.DEVICE_PLANE.match(plane["name"]).group(1)) == device:
+            return [dict(ev, path=ev.get("path"))
+                    for ev in xplane.line_events(plane, xplane.OPS_LINE)]
     return []
 
 
@@ -137,6 +101,8 @@ def main(argv=None) -> int:
     p.add_argument("profile", help="a profile directory or an .xplane.pb")
     p.add_argument("--device", type=int, default=0)
     p.add_argument("--json", action="store_true")
+    p.add_argument("--inner", action="store_true",
+                   help="split the memory core by its own scopes")
     args = p.parse_args(argv)
     path = (xplane.find_xplane(args.profile)
             if os.path.isdir(args.profile) else args.profile)
@@ -145,7 +111,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     events = load_ops(path, args.device)
-    shares = split(events)
+    shares = split(events, inner_scope_of if args.inner else None)
     if not shares:
         print("step_split: no device operation in the profile",
               file=sys.stderr)
