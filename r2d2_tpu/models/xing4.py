@@ -21,7 +21,9 @@ in residual streams.
   and computes their part of the result, as one grouped product over the
   rows routed to them (``jax.lax.ragged_dot``): no row is dropped and none
   is multiplied for an expert it was not routed to.  What absent experts
-  would add is left out.  The leading ``core_dense_layers`` blocks have a
+  would add is left out.  The rows are laid out at the smallest static
+  count that holds those routed here (:func:`row_ladder`: all pairs, halved
+  down to this chip's fair share), never for all pairs unless they are.  The leading ``core_dense_layers`` blocks have a
   dense SwiGLU instead.
 
 Blocks of a kind are one scanned body over stacked parameters, each
@@ -209,45 +211,98 @@ def attention(cfg: Config, p, u, cache, cd):
     return out, slots[:, T:]
 
 
+# the grouped products' row tile: the TPU compiler gives ragged_dot's kernel
+# 256 rows a tile on a v5e, or 512 where they divide the rows
+# (``ragged_dot_tiling`` in its text; tests/test_tpu_compile.py holds the two
+# together).  A rung of the row ladder is a whole number of them
+ROW_TILE = 256
+
+
+def row_ladder(cfg: Config, pairs: int) -> tuple:
+    """The static row counts at which the held experts' rows may be laid
+    out for ``pairs`` routed (token, expert) pairs, ascending: ``pairs``
+    halved down to this chip's fair share ``core_experts_held /
+    core_experts`` of them, each a whole number of row tiles.  The last is
+    ``pairs`` itself, which holds whatever the router does; a chip that
+    holds every expert has that rung alone."""
+    halvings = int(math.log2(cfg.core_experts // cfg.core_experts_held))
+    return tuple(sorted({
+        min(-(-pairs // (ROW_TILE << j)) * ROW_TILE, pairs)
+        for j in range(halvings + 1)}))
+
+
+def rung_of(ladder: tuple, live):
+    """Index of the smallest rung that holds ``live`` rows."""
+    return sum((live > r).astype(jnp.int32) for r in ladder[:-1])
+
+
+def rows_laid_out(cfg: Config, pairs: int, load):
+    """The rung at which :func:`routed_experts` lays out the rows of
+    ``pairs`` routed pairs whose loads by expert are ``load`` (E,)."""
+    ladder = row_ladder(cfg, pairs)
+    live = load[:cfg.core_experts_held].sum()
+    return jnp.asarray(ladder, jnp.float32)[rung_of(ladder, live)]
+
+
+def _gather_sum(y, w, at):
+    """``out[n] = sum_j w[n, j] * y[slot[n, j]]`` in float32: rows (R, d)
+    summed into their tokens (N, d) as k gathers of N rows, so that
+    nothing (N k, d) is laid out."""
+    slot = at["slot"]
+    return sum(w[:, j, None].astype(jnp.float32)
+               * y[slot[:, j]].astype(jnp.float32)
+               for j in range(slot.shape[1]))
+
+
+# Where the sorted pairs' rows lie, ``at``: of row r its flat pair ``pair``
+# (R,) and token ``tok`` (R,), whether it is ``live`` (R, 1); of a token's
+# k pairs their rows ``slot`` (N, k) and whether their expert is held
+# ``here`` (N, k), so that they lie among the rows at all.
+
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is known: both
-    directions are gathers (autodiff's transpose of a gather is a
-    scatter-add)."""
-    return x[perm]
+def _token_rows(x, at):
+    """Row r is the token of the sorted pair r: ``x[tok]``, (N, d) ->
+    (R, d).  Backward, a token sums the rows of its own pairs: a gather
+    (autodiff's transpose of a gather is a scatter-add).  The rows past
+    the live ones are selected away first: no product defined them."""
+    return x[at["tok"]]
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
+def _token_rows_fwd(x, at):
+    return x[at["tok"]], at
 
 
-def _permute_bwd(saved, g):
-    perm, inverse = saved
-    return g[inverse], None, None
+def _token_rows_bwd(at, g):
+    g = jnp.where(at["live"], g, jnp.zeros((), g.dtype))
+    return _gather_sum(g, at["here"], at).astype(g.dtype), None
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _pair_rows(u, order, inverse, live, k: int):
-    """Row j is the token of the sorted pair j: ``repeat(u, k)[order]``.
-    Backward, the rows past the ``live`` ones are selected away before
-    they are summed into their tokens: no product defined them."""
-    return u[order // k]
+@jax.custom_vjp
+def _combine(y, w, at):
+    """The tokens' results from the sorted pairs' rows: ``out[n] = sum_j
+    w[n, j] * y[slot[n, j]]``, (R, d) -> (N, d) f32; ``w`` is 0 where a
+    pair's expert is absent, ``y`` where no product defined a row.
+    Backward, row r takes its token's cotangent times its pair's weight:
+    gathers of R rows."""
+    return _gather_sum(y, w, at)
 
 
-def _pair_rows_fwd(u, order, inverse, live, k):
-    return u[order // k], (inverse, live, u.shape)
+def _combine_fwd(y, w, at):
+    return _gather_sum(y, w, at), (y, w, at)
 
 
-def _pair_rows_bwd(k, saved, g):
-    inverse, live, (n, d) = saved
-    g = jnp.where(live, g, jnp.zeros((), g.dtype))
-    return g[inverse].reshape(n, k, d).sum(axis=1), None, None, None
+def _combine_bwd(saved, g):
+    y, w, at = saved
+    g = g.astype(y.dtype)[at["tok"]].astype(jnp.float32)
+    g_w = jnp.sum(g * y.astype(jnp.float32), axis=-1)[at["slot"]]
+    g_y = w.reshape(-1)[at["pair"]][:, None].astype(jnp.float32) * g
+    return g_y.astype(y.dtype), g_w.astype(w.dtype), None
 
 
-_pair_rows.defvjp(_pair_rows_fwd, _pair_rows_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route(cfg: Config, scores, bias):
@@ -263,7 +318,7 @@ def route(cfg: Config, scores, bias):
 def routed_experts(cfg: Config, p, u, bias, cd):
     """Shared expert + this chip's part of the routed experts' result for
     tokens u (N, d); also the pairs routed to every expert (E,)."""
-    N, d = u.shape
+    N = u.shape[0]
     k, E, held = cfg.core_top_k, cfg.core_experts, cfg.core_experts_held
     with jax.named_scope("router"):
         scores = jax.nn.sigmoid(jnp.dot(
@@ -274,32 +329,51 @@ def routed_experts(cfg: Config, p, u, bias, cd):
             axis=(0, 1)).astype(jnp.float32)
     with jax.named_scope("experts"):
         # pairs sorted by expert; those of absent experts go last, as one
-        # group that is given no product
-        flat = chosen.reshape(-1)
-        key = jnp.minimum(flat, held)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # group that is given no product.  The live pairs come first, so
+        # the first R sorted pairs hold them all whenever there are at
+        # most R: the rows are laid out at the smallest rung of the ladder
+        # that does, and the last rung is every pair
+        order = jnp.argsort(jnp.minimum(chosen.reshape(-1), held),
+                            stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32))
+            jnp.arange(N * k, dtype=jnp.int32)).reshape(N, k)
         sizes = load[:held].astype(jnp.int32)
-        # a pair of an absent expert sits past the held groups, where no
-        # product defines a row, forward or backward: what a grouped
-        # product returns there is selected away, never multiplied by
-        # zero, so that it reaches no result and no gradient
-        live = (jnp.arange(N * k) < sizes.sum())[:, None]
+        ladder = row_ladder(cfg, N * k)
 
-        def grouped(rows, w):
-            out = jax.lax.ragged_dot(rows, w.astype(cd), sizes)
-            return jnp.where(live, out, jnp.zeros((), out.dtype))
+        def laid_out(R, u, ex, w, here, order, inverse, sizes):
+            # a pair of an absent expert sits past the held groups, where
+            # no product defines a row, forward or backward: what a
+            # grouped product returns there is selected away, never
+            # multiplied by zero, so that it reaches no result and no
+            # gradient
+            live = (jnp.arange(R) < sizes.sum())[:, None]
 
-        rows = _pair_rows(u.astype(cd), order, inverse, live, k)
-        ex = p["experts"]
-        hid = (jax.nn.silu(grouped(rows, ex["w_gate"]))
-               * grouped(rows, ex["w_up"]))
-        y = _permute(grouped(hid, ex["w_down"]), inverse, order)
-        out = jnp.einsum("nk,nkd->nd",
-                         jnp.where(chosen < held, weights, 0.0).astype(cd),
-                         y.reshape(N, k, d),
-                         preferred_element_type=jnp.float32)
+            def grouped(rows, w):
+                out = jax.lax.ragged_dot(rows, w.astype(cd), sizes)
+                return jnp.where(live, out, jnp.zeros((), out.dtype))
+
+            pair = order[:R]
+            at = dict(pair=pair, tok=pair // k, live=live, here=here,
+                      slot=jnp.minimum(inverse, R - 1))
+            rows = _token_rows(u, at)
+            hid = (jax.nn.silu(grouped(rows, ex["w_gate"]))
+                   * grouped(rows, ex["w_up"]))
+            return _combine(grouped(hid, ex["w_down"]), w, at)
+
+        here = chosen < held
+        operands = (u.astype(cd), p["experts"],
+                    jnp.where(here, weights, 0.0).astype(cd), here,
+                    order, inverse, sizes)
+        if len(ladder) == 1:
+            out = laid_out(ladder[0], *operands)
+        else:
+            # each rung rematerialised on its own: what a branch keeps for
+            # its backward would else be laid out, as zeros, by every
+            # other branch too
+            out = jax.lax.switch(
+                rung_of(ladder, sizes.sum()),
+                [jax.checkpoint(functools.partial(laid_out, R))
+                 for R in ladder], *operands)
     with jax.named_scope("shared_expert"):
         out = out + _swiglu(u, p["shared"], cd)
     return out, load
@@ -308,7 +382,7 @@ def routed_experts(cfg: Config, p, u, bias, cd):
 def block(cfg: Config, p, X, cache, bias, cd):
     """One block over the streams X (n arrays (B T, d)) and its cache (B,
     W, latent); ``bias`` None marks a dense block.  Returns (X', cache',
-    load (E,))."""
+    load (E,), rows laid out for the experts)."""
     B, d = cache.shape[0], cfg.core_dim
     pre, post, res = stream_maps(cfg, p["attn_mix"], X, cd)
     u = _rms(_streams_read(pre, X), p["attn_norm"], RMS_NORM_EPS)
@@ -320,15 +394,16 @@ def block(cfg: Config, p, X, cache, bias, cd):
     if bias is None:
         with jax.named_scope("dense_ffn"):
             y = _swiglu(u, p["dense"], cd)
-        load = jnp.zeros(cfg.core_experts, jnp.float32)
+        load, rows = jnp.zeros(cfg.core_experts, jnp.float32), 0.0
     else:
         y, load = routed_experts(cfg, p["moe"], u, bias, cd)
-    return _streams_write(res, post, X, y), cache, load
+        rows = rows_laid_out(cfg, u.shape[0] * cfg.core_top_k, load)
+    return _streams_write(res, post, X, y), cache, load, rows
 
 
 def run(cfg: Config, params, router_bias, feats, hidden, cd):
     """feats (B, T, F), hidden (B, layers, W, latent) -> (out (B, T, d),
-    hidden', loads (moe layers, E))."""
+    hidden', loads (moe layers, E), rows laid out (moe layers,))."""
     B, T, _ = feats.shape
     x = _mm(feats, params["in_proj"]["kernel"], cd) + params["in_proj"]["bias"]
     x = x.astype(cd).reshape(B * T, cfg.core_dim)
@@ -339,26 +414,27 @@ def run(cfg: Config, params, router_bias, feats, hidden, cd):
     def body(dense):
         def step(X, xs):
             p, cache, bias = xs
-            X, cache, load = block(cfg, p, X, cache,
-                                   None if dense else bias, cd)
-            return X, (cache, load)
+            X, cache, load, rows = block(cfg, p, X, cache,
+                                         None if dense else bias, cd)
+            return X, (cache, load, rows)
         return jax.checkpoint(step) if cfg.remat else step
 
-    new_caches, loads = [], jnp.zeros((0, cfg.core_experts), jnp.float32)
+    new_caches = []
+    loads, rows = jnp.zeros((0, cfg.core_experts), jnp.float32), jnp.zeros(0)
     if nd:
-        X, (c, _) = jax.lax.scan(
+        X, (c, _, _) = jax.lax.scan(
             body(True), X, (params["dense_layers"], caches[:nd],
                             jnp.zeros((nd, 0), jnp.float32)))
         new_caches.append(c)
     if cfg.core_layers > nd:
-        X, (c, loads) = jax.lax.scan(
+        X, (c, loads, rows) = jax.lax.scan(
             body(False), X, (params["moe_layers"], caches[nd:], router_bias))
         new_caches.append(c)
     with jax.named_scope("residual_mix"):
         out = _rms(sum(x.astype(jnp.float32) for x in X),
                    params["final_norm"], RMS_NORM_EPS)
     return (out.reshape(B, T, cfg.core_dim),
-            jnp.concatenate(new_caches).swapaxes(0, 1), loads)
+            jnp.concatenate(new_caches).swapaxes(0, 1), loads, rows)
 
 
 def bias_update(cfg: Config, router_bias, loads):
@@ -368,17 +444,25 @@ def bias_update(cfg: Config, router_bias, loads):
     return router_bias + cfg.core_bias_rate * jnp.sign(mean - loads)
 
 
-def load_counters(cfg: Config, router_bias, loads):
-    """(3,) f32: the share of routed pairs that fell to experts held here,
-    the held experts' largest load over their mean, the largest |bias|."""
+def load_counters(cfg: Config, router_bias, loads, rows):
+    """COUNTERS, (5,) f32: the share of routed pairs that fell to experts
+    held here, the held experts' largest load over their mean, the largest
+    |bias|; the rows the blocks laid out for their held pairs over all
+    routed pairs (1 when every block took the last rung), and the largest
+    single block's held pairs over its routed pairs, which decides that
+    block's rung."""
     held = loads[:, :cfg.core_experts_held]
+    pairs = jnp.maximum(loads.sum(axis=1), 1.0)
     return jnp.stack([
         held.sum() / jnp.maximum(loads.sum(), 1.0),
         (held.max(axis=1) / jnp.maximum(held.mean(axis=1), 1e-9)).max(),
-        jnp.abs(router_bias).max()])
+        jnp.abs(router_bias).max(),
+        rows.sum() / pairs.sum(),
+        (held.sum(axis=1) / pairs).max()])
 
 
-COUNTERS = ("held_pair_share", "held_load_max_over_mean", "router_bias_max")
+COUNTERS = ("held_pair_share", "held_load_max_over_mean", "router_bias_max",
+            "expert_rows_share", "held_rows_max_share")
 
 
 def step_buffers(cfg: Config, buffers, stats):
@@ -389,7 +473,8 @@ def step_buffers(cfg: Config, buffers, stats):
     bias = bias_update(cfg, buffers["core"]["router_bias"], loads)
     return {**buffers, "core": {
         **buffers["core"], "router_bias": bias,
-        "counters": load_counters(cfg, bias, loads)}}
+        "counters": load_counters(cfg, bias, loads,
+                                  stats["core"]["expert_rows"])}}
 
 
 def _mix_init(cfg: Config, key, layers: int, pd):
@@ -489,8 +574,9 @@ class Xing4Core(nn.Module):
         # the last update's COUNTERS (step_buffers writes them)
         self.variable("buffers", "counters", jnp.zeros,
                       (len(COUNTERS),), jnp.float32)
-        out, hidden, loads = run(cfg, params, bias.value, feats, hidden,
-                                 self.compute_dtype)
-        self.sow("stats", "expert_load", loads,
-                 reduce_fn=lambda _, new: new, init_fn=lambda: None)
+        out, hidden, loads, rows = run(cfg, params, bias.value, feats,
+                                       hidden, self.compute_dtype)
+        for name, value in (("expert_load", loads), ("expert_rows", rows)):
+            self.sow("stats", name, value,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
         return out, hidden

@@ -868,6 +868,10 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
                 tracer.gauge("core.held_load_max_over_mean",
                              lc["held_load_max_over_mean"])
                 tracer.gauge("core.router_bias_max", lc["router_bias_max"])
+                tracer.gauge("core.expert_rows_share",
+                             lc["expert_rows_share"])
+                tracer.gauge("core.held_rows_max_share",
+                             lc["held_rows_max_share"])
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"],

@@ -1,5 +1,6 @@
-"""The flagship cell's super-step compiled for a described (not attached)
-v5e: what only the TPU compiler decides about the device ring, checked
+"""The flagship cell's super-step, and the ``xing4`` core's expert layer,
+compiled for a described (not attached) v5e: what only the TPU compiler
+decides about the device ring and about the routed rows' buffers, checked
 without a chip.
 
 The compiler has twice chosen a layout for the frame ring under which the
@@ -110,3 +111,85 @@ def test_fabric_super_step_reads_the_frames_as_windows(fabric_super_step):
     words = frame_words(int(np.prod(cfg.stored_obs_shape)))
     assert re.search(rf"u32\[{B},1,{T},{words}\]", text)
     assert not re.search(rf"u8\[{B * T},\d+\]\S* fusion\(", text)
+
+
+# ------------------------------------------- the routed experts' row ladder
+
+@pytest.fixture(scope="module")
+def expert_layer(one_chip):
+    """(cfg, routed pairs, optimized HLO text) of one expert block of
+    ``nature_xing4_l5e8h4`` alone, forward and backward, at the cell's
+    widths and tokens (models/xing4.routed_experts; ~20 s)."""
+    from benchmark.drivers.train import build_config
+    from benchmark.manifest import Manifest
+    from r2d2_tpu.models import xing4
+
+    _, sharding = one_chip
+    cfg = build_config(Manifest().cell("nature_xing4_l5e8h4.anakin"), False)
+    tokens = cfg.batch_size * cfg.seq_len
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    p = jax.tree.map(
+        lambda x: sds(x.shape[1:]),
+        jax.eval_shape(lambda k: xing4.init_blocks(
+            k, cfg, 1, False, jnp.float32)["moe"], jax.random.PRNGKey(0)))
+
+    def forward_backward(p, u, bias, g):
+        def f(p, u):
+            out, load = xing4.routed_experts(cfg, p, u, bias, jnp.bfloat16)
+            return jnp.sum(out * g), load
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, u)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(forward_backward).lower(
+            p, sds((tokens, cfg.core_dim)), sds((cfg.core_experts,)),
+            sds((tokens, cfg.core_dim))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    return cfg, tokens * cfg.core_top_k, compiled.as_text()
+
+
+def _computation(text, name):
+    """The body of the HLO computation ``name``."""
+    m = re.search(rf"^%?{re.escape(name)} \(.*?^}}", text, re.M | re.S)
+    assert m, name
+    return m.group(0)
+
+
+def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
+    """21,760 routed pairs of which this chip's experts take an eighth: in
+    the branch of the smallest rung, forward and backward, nothing has
+    N k rows of the core's width (each is 156 MB; PERF.md Findings, PR 29),
+    and the grouped products are still the compiler's Mosaic calls, which
+    skip the tiles past the live rows."""
+    from r2d2_tpu.models import xing4
+
+    cfg, pairs, text = expert_layer
+    ladder = xing4.row_ladder(cfg, pairs)
+    assert len(ladder) == 4 and ladder[-1] == pairs == 21760
+    switches = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}",
+                          text)
+    assert len(switches) == 2                   # forward, backward
+    worst = rf"\[{pairs},{cfg.core_dim}\]"
+    for names in switches:
+        branches = [_computation(text, n.strip().lstrip("%"))
+                    for n in names.split(",")]
+        assert len(branches) == len(ladder)
+        # the last rung is every pair: there the worst case is laid out
+        assert re.search(worst, branches[-1])
+        for rows, branch in zip(ladder[:-1], branches):
+            assert not re.search(rf"\[{pairs},\d+\]", branch), rows
+            products = re.findall(
+                rf"= bf16\[(?:{rows},\d+|\d+,\d+,\d+)\]\S* custom-call\("
+                r".*custom_call_target=\"tpu_custom_call\".*ragged-dot",
+                branch)
+            # forward 3; backward those 3 again, 3 dX, 3 dW
+            assert len(products) in (3, 9), (rows, len(products))
+            # a rung is a whole number of the kernel's row tiles
+            tiles = set(re.findall(r'ragged_dot_tiling="(\d+),', branch))
+            assert tiles and all(int(t) % xing4.ROW_TILE == 0
+                                 and rows % int(t) == 0 for t in tiles)

@@ -217,6 +217,147 @@ def test_no_routed_pair_is_lost_when_every_token_goes_to_one_held_expert():
     assert (np.abs(np.asarray(out - without)).max(axis=1) > 1e-4).all()
 
 
+def ladder_case(held, tokens, experts=None):
+    """(cfg with ``held`` of ``experts`` experts, 8 for each held unless
+    given; one block's parameters; tokens u; a router matrix of zeros):
+    with such a router every score is 0.5, so the bias alone chooses,
+    whatever the weights of the experts are."""
+    cfg = tiny_cfg(core_experts=experts or 8 * held, core_experts_held=held)
+    p = dict(moe_params(cfg, 6), w_router=jnp.zeros(
+        (cfg.core_dim, cfg.core_experts), jnp.float32))
+    u = jnp.asarray(np.random.default_rng(7).normal(
+        size=(tokens, cfg.core_dim)), jnp.float32)
+    return cfg, p, u
+
+
+def routed_by(cfg, live, tokens):
+    """A (tokens, E) bias under which exactly ``live`` of the tokens * k
+    routed pairs fall to held experts: token n sends ``per[n]`` of its k
+    pairs to the held experts (the lowest first), the rest to absent
+    ones."""
+    k, held, E = cfg.core_top_k, cfg.core_experts_held, cfg.core_experts
+    per = np.full(tokens, live // tokens)
+    per[:live % tokens] += 1
+    assert per.max() <= min(k, held) and per.sum() == live
+    bias = np.zeros((tokens, E), np.float32)
+    for n, c in enumerate(per):
+        # staggered, so that the held experts' groups differ in size
+        bias[n, (n + np.arange(c)) % held] = 10.0
+        bias[n, held + (n + np.arange(k - c)) % (E - held)] = 10.0
+    return jnp.asarray(bias)
+
+
+def layer_and_gradients(cfg, p, u, bias):
+    """(out, load, gradient of every parameter and of u) of one expert
+    layer under a fixed cotangent."""
+    g = jnp.asarray(np.random.default_rng(8).normal(size=u.shape),
+                    jnp.float32)
+
+    def f(p, u):
+        out, load = xing4.routed_experts(cfg, p, u, bias, jnp.float32)
+        return jnp.sum(out * g), (out, load)
+
+    (_, (out, load)), grads = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1), has_aux=True))(p, u)
+    return out, load, grads
+
+
+# one chip of 8 (the cell's share: four rungs), holding 2 experts or 4
+LADDERS = [(2, 1024, (256, 512, 1024, 2048)), (4, 384, (256, 512, 768))]
+
+
+@pytest.mark.parametrize("held,tokens,ladder", LADDERS,
+                         ids=["2_of_16", "4_of_32"])
+def test_the_ladder_halves_down_to_the_fair_share(held, tokens, ladder):
+    cfg = tiny_cfg(core_experts=8 * held, core_experts_held=held)
+    assert xing4.row_ladder(cfg, tokens * cfg.core_top_k) == ladder
+    # a chip that holds every expert, and a layer too small to halve, lay
+    # out every pair: the traced program has no branch
+    whole = cfg.replace(core_experts_held=cfg.core_experts)
+    assert xing4.row_ladder(whole, 4096) == (4096,)
+    assert xing4.row_ladder(cfg, 80) == (80,)
+    for c, pairs in ((cfg, 80), (whole, tokens * cfg.core_top_k)):
+        _, p, u = ladder_case(c.core_experts_held, pairs // c.core_top_k,
+                              c.core_experts)
+        text = str(jax.make_jaxpr(lambda u: xing4.routed_experts(
+            c, p, u, jnp.zeros(c.core_experts), jnp.float32))(u))
+        assert "cond[" not in text
+    # the full-width cell: 5,440 tokens, top 4, 8 experts of 64; its 64
+    # lanes acting are too few to halve
+    full = training.config_from_file(DOC["config"])
+    cell = (2816, 5632, 11008, 21760)
+    assert xing4.row_ladder(full, 5440 * 4) == cell
+    assert xing4.row_ladder(full, 64 * 4) == (256,)
+    live = jnp.asarray([0, 2816, 2817, 5632, 5633, 11009, 21760])
+    np.testing.assert_array_equal(
+        jax.vmap(lambda n: xing4.rung_of(cell, n))(live),
+        [0, 0, 1, 1, 2, 3, 3])
+
+
+def ladder_edges():
+    """(held, tokens, ladder, live) at every rung's edges: no live pair,
+    R - 1, R, R + 1 for each rung R below the last, and every pair on a
+    held expert."""
+    for held, tokens, ladder in LADDERS:
+        most = tokens * min(TINY["core_top_k"], held)
+        lives = {0, most} | {R + e for R in ladder[:-1] for e in (-1, 0, 1)}
+        for live in sorted(n for n in lives if n <= most):
+            yield pytest.param(held, tokens, ladder, live,
+                               id=f"{held}_of_{8 * held}-live_{live}")
+
+
+@pytest.mark.parametrize("held,tokens,ladder,live", ladder_edges())
+def test_every_rung_equals_the_last_rung_alone(held, tokens, ladder, live,
+                                               monkeypatch):
+    """Whatever rung the live count chooses, the layer's output, its load
+    and the gradient of every parameter and of u are those of the N k
+    rung alone: no pair is dropped or re-routed at any live count."""
+    cfg, p, u = ladder_case(held, tokens)
+    assert xing4.row_ladder(cfg, tokens * cfg.core_top_k) == ladder
+    bias = routed_by(cfg, live, tokens)
+    out, load, grads = layer_and_gradients(cfg, p, u, bias)
+    assert float(load[:held].sum()) == live
+    assert float(xing4.rows_laid_out(cfg, ladder[-1], load)) == next(
+        R for R in ladder if R >= live)
+    monkeypatch.setattr(xing4, "row_ladder", lambda cfg, pairs: ladder[-1:])
+    want_out, want_load, want = layer_and_gradients(cfg, p, u, bias)
+    np.testing.assert_array_equal(load, want_load)
+    assert rel(out, want_out) < 1e-6
+    flat, flat_want = jax.tree.leaves(grads), jax.tree.leaves(want)
+    assert len(flat) == len(flat_want) == 8
+    for g, g_want in zip(flat, flat_want):
+        assert np.isfinite(np.asarray(g)).all()
+        assert rel(g, g_want) < 1e-5
+    # and both are the plain reference's
+    hp = ref.hyper_parameters(cfg.core_dim)
+    assert rel(out, ref.experts(hp, p, u, bias, held)) < 1e-5
+
+
+def test_the_row_counters_read_what_the_routing_implies():
+    cfg = tiny_cfg(core_experts_held=1)
+    E, pairs, ladder = cfg.core_experts, 2048, (256, 512, 1024, 2048)
+    assert xing4.row_ladder(cfg, pairs) == ladder
+    # three expert blocks: 100, 600 and 2,000 of 2,048 pairs on the one
+    # held expert, the others' pairs spread over the absent ones
+    held = np.array([100., 600., 2000.])
+    loads = np.concatenate(
+        [held[:, None], np.repeat((pairs - held)[:, None] / (E - 1),
+                                  E - 1, axis=1)], axis=1)
+    rows = jnp.stack([xing4.rows_laid_out(cfg, pairs, jnp.asarray(load))
+                      for load in loads])
+    np.testing.assert_array_equal(rows, [256, 1024, 2048])
+    counters = dict(zip(xing4.COUNTERS, np.asarray(xing4.load_counters(
+        cfg, jnp.zeros((3, E)), jnp.asarray(loads, jnp.float32), rows))))
+    assert counters["expert_rows_share"] == pytest.approx(
+        (256 + 1024 + 2048) / (3 * pairs))
+    assert counters["held_rows_max_share"] == pytest.approx(2000 / pairs)
+    assert counters["held_pair_share"] == pytest.approx(2700 / (3 * pairs))
+    # a chip that holds every expert lays out every pair
+    whole = tiny_cfg(core_experts_held=E)
+    assert float(xing4.rows_laid_out(whole, pairs,
+                                     jnp.asarray(loads[0]))) == pairs
+
+
 def test_the_bias_moves_towards_balance_and_takes_no_gradient(small):
     cfg, net, params, target, batch = small
     loads = jnp.asarray([[9., 1., 4., 4., 0., 6., 4., 4.]])
@@ -394,8 +535,12 @@ def test_each_core_trains_through_the_fabric_and_the_fused_loop(
         last = m["logs"][-1]
         assert set(last["core"]) == set(xing4.COUNTERS)
         assert 0 < last["core"]["held_pair_share"] < 1
-        assert last["trace"]["gauge.core.held_pair_share"] == \
-            last["core"]["held_pair_share"]
+        for name in xing4.COUNTERS:
+            assert last["trace"]["gauge.core." + name] == last["core"][name]
+        # a layer too small to halve lays out every pair
+        assert last["core"]["expert_rows_share"] == 1
+        assert last["core"]["held_pair_share"] <= \
+            last["core"]["held_rows_max_share"] <= 1
 
 
 def test_the_fused_loop_cuts_the_states_the_host_cutter_cuts():
