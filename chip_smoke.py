@@ -73,9 +73,8 @@ def _child_device() -> dict:
     import jax
     import jaxlib
 
-    from r2d2_tpu.bench import _device_facts
     from r2d2_tpu.utils.compile_cache import enable
-    from r2d2_tpu.utils.trace import device_memory
+    from r2d2_tpu.utils.trace import device_facts, device_memory
 
     cache_dir = enable()
     try:
@@ -83,7 +82,7 @@ def _child_device() -> dict:
     except md.PackageNotFoundError:
         libtpu = None
     mem = device_memory()
-    return dict(_device_facts(),
+    return dict(device_facts(),
                 bytes_limit=mem[0]["bytes_limit"] if mem else None,
                 jax=jax.__version__, jaxlib=jaxlib.__version__,
                 libtpu=libtpu, flax=md.version("flax"),

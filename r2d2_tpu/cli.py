@@ -365,9 +365,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="with --follow: exit after this many seconds "
                          "without a new checkpoint (default 600)")
 
-    pb = sub.add_parser("bench", help="single-chip learner throughput")
-    pb.add_argument("--steps", type=int, default=100)
-
     ps = sub.add_parser("sweep",
                         help="train+eval a game ladder (Atari-57 default)")
     _add_common(ps)
@@ -381,13 +378,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ps.add_argument("--quiet", action="store_true")
 
     args = parser.parse_args(argv)
-
-    if args.cmd == "bench":
-        from r2d2_tpu import bench
-
-        # phase-isolated path (same as `python bench.py`): each phase
-        # holds the chip alone and a hung one times out, bounded
-        return bench._script_main([str(args.steps)])
 
     try:
         cfg = build_config(args)

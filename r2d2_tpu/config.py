@@ -371,10 +371,10 @@ class Config:
                                       # recurrence for NO-GRAD paths
                                       # (acting/eval).  Training always
                                       # runs the scan (the Pallas backward
-                                      # kernel was retired in r5 — on-chip
-                                      # it measured 0.96x scan; the fused
-                                      # kernel keeps its 1.07x inference
-                                      # edge, ops/lstm.py)
+                                      # kernel was retired in r5; the
+                                      # forward kernel, ops/lstm.py, is
+                                      # not measured on the chip: ROADMAP
+                                      # Queue 3, Design 4)
     pallas_interpret: bool = False    # run pallas kernels interpreted (CPU tests)
     transfer_guard: bool = False      # arm jax.transfer_guard("disallow")
                                       # windows around every declared
@@ -1214,18 +1214,15 @@ def pong_config(**kw) -> Config:
     updates — the reference's own lag envelope (8-batch queue + 4-batch
     staging, worker.py:300-316).  k=16 (lag 48) showed a measurable
     late-curve tax in the 4-run fabric A/B (CURVES_AB_PIPELINE_r04*:
-    late-mean 22.9 vs 27.7 baseline, k=4 at parity 26.1); k=16 remains a
-    throughput-bench knob, not a learning default.
+    late-mean 22.9 vs 27.7 baseline, k=4 at parity 26.1); k=16 is not a
+    learning default.
 
-    in_graph_per=True (flipped r5): the CPU A/B measured 2.2× the
-    host-sampled update rate at learning parity (2 seeds × 3 network
-    families, CURVES_*_INGRAPH_r04, 60-min soak SOAK_INGRAPH_LONG_r04)
-    — a CPU-host timing, not a device number: the feature removes a
-    per-harvest host round trip, and whether that pays on the chip is not
-    measured (ROADMAP Speed 2).  bench.py reports the host-path and
-    in-graph cells side by side (system_env_frames_per_sec vs
-    system_ingraph_env_frames_per_sec) so the choice can be re-checked on
-    real hardware."""
+    in_graph_per=True (flipped r5): learning parity with the host-sampled
+    path (2 seeds × 3 network families, CURVES_*_INGRAPH_r04, 60-min soak
+    SOAK_INGRAPH_LONG_r04), and it is the path the benchmark's fabric cell
+    runs, device idle 0.075 % (ledger, PR 29).  The host-PER path has no
+    cell: which of the two is faster is not measured on the chip (ROADMAP
+    Queue 3, Design 1)."""
     base = dict(game_name="Pong", num_actors=64, env_workers=8,
                 device_replay=True, in_graph_per=True,
                 superstep_k=4, superstep_pipeline=2)

@@ -24,9 +24,9 @@ multi-node story at all.  The TPU-native equivalent splits cleanly:
   :func:`local_rows`, which reads only this host's addressable shards, so
   each host's priority feedback aligns with the indexes it sampled.
 
-Single-process (tests, the one-chip bench) is the degenerate case: every
-helper reduces to the identity / a sharded ``device_put``, which is how the
-whole path is unit tested on the 8-device CPU mesh — the single-process
+Single-process (tests, the one-chip benchmark cells) is the degenerate case:
+every helper reduces to the identity / a sharded ``device_put``, which is how
+the whole path is unit tested on the 8-device CPU mesh — the single-process
 code path IS the multi-host code path.
 
 Topology assumption (asserted): each host's devices cover whole dp groups,

@@ -364,9 +364,8 @@ def pjit_train_step(cfg, net, table: Optional[ShardingTable] = None,
     is no separate variant.
 
     ``donate_batch=False`` keeps the batch alive across calls — ONLY for
-    diagnostics that deliberately re-step one device-resident batch
-    (bench.py's timing loop); the training drivetrains
-    always donate.
+    checks that deliberately re-step one device-resident batch
+    (tests/_mp_worker.py); the training drivetrains always donate.
 
     ``state_template`` (a live TrainState or its avals) derives the
     per-leaf shardings; retrace-guarded as ``learner.train_step``.

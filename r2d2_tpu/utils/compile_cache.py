@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache.
 
 The flagship train step / super-step are multi-second XLA compiles; every
-bench run, smoke run and restarted trainer pays them again.  JAX ships a
+benchmark run, smoke run and restarted trainer pays them again.  JAX ships a
 persistent on-disk compilation cache — this module decides where it lives.
 The reference has no analogue (torch eager); for a jitted framework it is
 the difference between a cold and a warm start on repeat runs.

@@ -503,6 +503,17 @@ def device_profile(log_dir: Optional[str]) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
+def device_facts() -> dict:
+    """The default backend as JAX reports it: platform, device kind and
+    device count."""
+    import jax
+
+    devices = jax.devices()
+    return dict(platform=devices[0].platform,
+                device_kind=devices[0].device_kind,
+                device_count=len(devices))
+
+
 def device_memory() -> list:
     """Per local device, what the backend reports of its memory:
     ``[{id, bytes_in_use, peak_bytes_in_use, bytes_limit}, ...]`` — how a

@@ -6,7 +6,7 @@ import time
 import pytest
 
 from r2d2_tpu.utils.supervisor import Supervisor
-from r2d2_tpu.utils.trace import Tracer, device_profile
+from r2d2_tpu.utils.trace import Tracer, device_facts, device_profile
 
 
 def test_tracer_spans_and_gauges():
@@ -285,3 +285,15 @@ def test_supervisor_start_duplicate_name_raises():
     finally:
         stop.set()
         sup.join_all(timeout=2.0)
+
+
+def test_device_facts_names_platform_kind_and_count():
+    """What `chip_smoke.py` names its device by, on the CPU client the
+    tests run on (conftest provides the virtual devices)."""
+    import jax
+
+    facts = device_facts()
+    assert set(facts) == {"platform", "device_kind", "device_count"}
+    assert facts["platform"] == "cpu"
+    assert facts["device_kind"] == jax.devices()[0].device_kind
+    assert facts["device_count"] == jax.device_count() >= 1

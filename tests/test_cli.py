@@ -94,17 +94,14 @@ def test_cli_eval_env_uses_noop_start(tmp_path, monkeypatch):
 
 
 
-def test_cli_bench_routes_to_isolated_script_main(monkeypatch):
-    """`r2d2 bench` must go through the phase-isolated script path (each
-    phase holds the chip alone and a hung one times out bounded), not the
-    in-process bench.main()."""
-    from r2d2_tpu import bench
-
-    calls = []
-    monkeypatch.setattr(bench, "_script_main",
-                        lambda argv: calls.append(argv) or 0)
-    assert main(["bench", "--steps", "7"]) == 0
-    assert calls == [["7"]]
+def test_cli_has_no_bench_subcommand(capsys):
+    """Speed is measured by `benchmark/run.py` alone (PR 30): `r2d2_tpu
+    bench` is an argparse error, so a second entry cannot come back
+    unnoticed."""
+    with pytest.raises(SystemExit) as e:
+        main(["bench"])
+    assert e.value.code == 2
+    assert "invalid choice: 'bench'" in capsys.readouterr().err
 
 
 def test_cli_train_exit_code_reports_a_failed_run(monkeypatch, capsys):

@@ -136,8 +136,8 @@ def test_act_fn_uses_scan_twin_off_tpu():
     ("Only interpret mode is supported on CPU backend").  make_act_fn must
     therefore build a scan-impl twin whenever the resolved act device is
     not a TPU — reproduced here with an explicit impl=pallas config and
-    act_device="cpu" (the exact combination the real-TPU bench hits with
-    lstm_impl="auto", act_device="auto")."""
+    act_device="cpu" (the exact combination the fabric cell hits on the
+    chip with lstm_impl="auto", act_device="auto")."""
     from r2d2_tpu.actor import make_act_fn
     from r2d2_tpu.config import test_config
     from r2d2_tpu.models.network import R2D2Network, create_network, init_params

@@ -19,17 +19,17 @@ Chaos sites (the session tier's failure drills, ``utils/chaos.py``):
 - ``slow_session_client`` — one session freezes ``dur`` seconds
   mid-episode; continuous batching must keep serving everyone else.
 
-Run (also the r12 bench artifact producer):
+Run:
 
     python tools/session_load_gen.py [--sessions N] [--workers W]
         [--steps-mean M] [--think-ms T] [--seconds S] [--seed K]
-        [--chaos SPEC] [--out artifacts/r12/SERVE_BENCH_r12.json]
-        [--doc docs/perf/SERVE_r12.md]
+        [--chaos SPEC] [--out FILE]
 
-Without ``--out`` it prints the summary JSON only.  The bench cells run
-an untrained default-geometry network (nature torso, LSTM-512) — the
-tier serves latency and throughput identically either way; learning
-quality is the trainer's bench, not this one.
+Without ``--out`` it prints the summary JSON only.  The cells run an
+untrained default-geometry network (nature torso, LSTM-512) — the tier
+serves identically either way.  Its numbers name the device they were
+taken on (``device``); a speed of the session tier is the benchmark's to
+state (PERF.md), and there is no serving cell yet.
 """
 import argparse
 import datetime
@@ -167,7 +167,7 @@ def _run_worker(cfg, action_dim, host, port, widx, sids, args, chaos,
                     elif status == STATUS_GONE:
                         # evicted under the LRU budget: a real frontend
                         # would re-open and restart the episode; the
-                        # bench just retires the session
+                        # load generator just retires the session
                         s.done, s.outcome = True, "gone"
                         stats["gone"] += 1
                     elif status in (STATUS_SHED, STATUS_EXPIRED):
@@ -296,7 +296,6 @@ def main() -> int:
                     help="serve_max_sessions (default: --sessions, so "
                          "no evictions; set lower to exercise the LRU)")
     ap.add_argument("--out", default=None)
-    ap.add_argument("--doc", default=None)
     args = ap.parse_args()
 
     import jax
@@ -304,6 +303,7 @@ def main() -> int:
     from r2d2_tpu.models.network import create_network, init_params
     from r2d2_tpu.serving.server import SessionServer
     from r2d2_tpu.utils.chaos import ChaosInjector
+    from r2d2_tpu.utils.trace import device_facts
 
     A = 9  # MsPacman's action count — the default geometry's real head
     cells = []
@@ -343,9 +343,7 @@ def main() -> int:
 
     payload = dict(
         generated=datetime.datetime.now().strftime("%Y-%m-%d %H:%M:%S"),
-        host_note="CPU host cells (the standing accelerator side-quest "
-                  "applies: re-run with a chip visible for the real act "
-                  "latency floor)",
+        device=device_facts(),
         config=dict(sessions=args.sessions, workers=args.workers,
                     steps_mean=args.steps_mean, think_ms=args.think_ms,
                     max_batch=args.max_batch, chaos=args.chaos,
@@ -360,54 +358,8 @@ def main() -> int:
         with open(args.out, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"wrote {args.out}")
-    if args.doc:
-        _write_doc(args.doc, payload)
-        print(f"wrote {args.doc}")
     return 1 if any(not c["accounting_ok"] or c["health"] == "failing"
                     for c in cells) else 0
-
-
-def _write_doc(path: str, payload: dict) -> None:
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    cfg = payload["config"]
-    lines = [
-        "# SERVE_r12 — session-serving tier bench (CPU host)",
-        "",
-        f"Generated {payload['generated']} by `tools/session_load_gen.py"
-        f"` — {cfg['sessions']} concurrent synthetic sessions over "
-        f"{cfg['workers']} client connections, seeded episode lengths "
-        f"(mean {cfg['steps_mean']} steps) and think-times "
-        f"(~{cfg['think_ms']} ms), continuous batching capped at "
-        f"{cfg['max_batch']}.",
-        "",
-        payload["host_note"] + ".",
-        "",
-        "| serve_dtype | acts/s | sessions/s | p50 ms | p95 ms | p99 ms "
-        "| batches | mean batch | sheds | health |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for c in payload["cells"]:
-        cl, srv = c["client"], c["server"]
-        lines.append(
-            f"| {c['serve_dtype']} | {cl.get('acts_per_sec')} | "
-            f"{cl.get('sessions_per_sec')} | {cl.get('act_p50_ms')} | "
-            f"{cl.get('act_p95_ms')} | {cl.get('act_p99_ms')} | "
-            f"{srv['batches']} | {srv['mean_batch']} | "
-            f"{srv['rejected']} | {c['health']} |")
-    lines += [
-        "",
-        "Client-side latency is send→reply (queueing + wire + act); the "
-        "server's own `serving.act_latency_s` histogram on `/metrics` "
-        "measures enqueue→reply.  The bf16 cell runs the QuaRL "
-        "weights-quantized publish path (greedy-action parity is gated "
-        "in tests/test_serving.py, not here).",
-        "",
-        "Accounting invariant held in every cell: "
-        "`admitted == completed + reaped + evicted + live`.",
-        "",
-    ]
-    with open(path, "w") as f:
-        f.write("\n".join(lines))
 
 
 if __name__ == "__main__":
