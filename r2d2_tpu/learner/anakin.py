@@ -341,14 +341,28 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
     scan discards ``trace`` (XLA dead-code-eliminates it), the parity
     tests keep it to drive the host LocalBuffer oracle.
 
-    ``cut_cond`` (default on) wraps each emit/retention block in a
-    ``lax.cond`` on ``jnp.any(cut)``: on the (block_length-1)/block_length
-    majority of steps where NO lane cuts, the full-buffer block assembly,
-    retention gathers, and ring scatters are skipped entirely instead of
-    executing as all-masked no-ops.  Bit-exact by construction — a no-cut
-    emit writes only to the dropped sentinel slot and a no-cut retention
-    is the identity — and pinned vs the ``cut_cond=False`` path in
-    tests/test_anakin.py.
+    ``cut_cond`` (default on) wraps each cut — emit and retention at a
+    block boundary, emit and the cut lanes' stream resets at an episode's
+    end — in a ``lax.cond`` on ``jnp.any(cut)``: on the
+    (block_length-1)/block_length majority of steps where NO lane cuts,
+    the full-buffer block assembly, the retention and the ring scatters
+    are skipped entirely instead of executing as all-masked no-ops.
+    Bit-exact by construction — a no-cut emit writes only to the dropped
+    sentinel slot, a no-cut retention or reset is the identity — and
+    pinned vs the ``cut_cond=False`` path in tests/test_anakin.py.
+
+    What the conditionals guarantee the compiler: both branches hand back
+    their operand's own buffer for every lane buffer and for the ring.
+    The false branch is the identity; the true branch changes a lane
+    buffer only by in-place updates of a few rows (a boundary cut writes
+    ``burn_in_steps + 1`` rows (+ the state stream's history),
+    :func:`_retain_prefix`; an episode's end writes row 0 and that
+    history) that are ordered AFTER every read of it
+    (:func:`_after_reads`).  An update of a whole buffer in a true branch,
+    or a reset left outside where nothing orders it against the cut's
+    read, costs a copy of the whole buffer on EVERY env step, cut or no
+    cut (four of 186-199 MB a step in the benchmark's widest fused cell
+    before PR 31; tests/test_tpu_compile.py asks the compiled rollout).
 
     ``replicate`` (mesh mode) pins the fleet-wide exploration draws to a
     replicated layout: with non-partitionable threefry, GSPMD
@@ -382,6 +396,7 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
         def _boundary(ops):
             a, arr, p, sm, fb = ops
             a, arr, p, sm, fb = emit_boundary(a, arr, p, sm, fb, pend, q)
+            a, arr = _after_reads(a, arr)
             return _retain_prefix(cfg, a, pend), arr, p, sm, fb
 
         if cut_cond:
@@ -439,32 +454,49 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "act_key": key,
                **{f"env_{k}": env_state[k] for k in env_keys}}
 
-        # 5) episode-end cuts (terminal: zero bootstrap); same cond fast
-        #    path — episode ends are rarer still than block boundaries
+        # 5) episode-end cuts (terminal: zero bootstrap), and the reset of
+        #    the cut lanes' streams (vbuf.reset_lane) AFTER the cut has
+        #    read them; same cond fast path — episode ends are rarer
+        #    still than block boundaries
+        tr = truncated
+        trc = tr[:, None]
+        env_state = env.reset_lanes(env_state, tr)
+        obs_reset = env.observe(env_state)
+
         def _done_cut(ops):
-            return emit_done(*ops, truncated, jnp.zeros((N, A), jnp.float32))
+            a, arr, p, sm, fb = emit_done(
+                *ops, tr, jnp.zeros((N, A), jnp.float32))
+            a, arr = _after_reads(a, arr)
+            # row 0 of the cut lanes' streams, as the step's own kind of
+            # update (one row a lane, the others' dropped)
+            row0 = jnp.where(tr, 0, cap)
+            noop = jnp.zeros((N, A), bool).at[:, 0].set(True)
+            a = {**a,
+                 "buf_obs": a["buf_obs"].at[lanes, row0].set(
+                     obs_reset.reshape(N, -1), mode="drop"),
+                 "buf_last_action": a["buf_last_action"].at[
+                     lanes, row0].set(noop, mode="drop"),
+                 "buf_last_reward": a["buf_last_reward"].at[
+                     lanes, row0].set(0.0, mode="drop"),
+                 "buf_hidden": reset_stream(cfg, a["buf_hidden"], tr)}
+            return a, arr, p, sm, fb
 
         if cut_cond:
             ast, arrays, prios, seq_meta, first = jax.lax.cond(
-                jnp.any(truncated), _done_cut, lambda ops: ops,
+                jnp.any(tr), _done_cut, lambda ops: ops,
                 (ast, arrays, prios, seq_meta, first))
         else:
             ast, arrays, prios, seq_meta, first = _done_cut(
                 (ast, arrays, prios, seq_meta, first))
 
         # 6) episode accounting, env reset, lane reset (VectorActor
-        #    ._reset_lane: fresh obs, zero agent state, vbuf.reset_lane)
+        #    ._reset_lane: fresh obs, zero agent state)
         ast = {**ast,
-               "episodes_d": ast["episodes_d"] + truncated.sum(),
+               "episodes_d": ast["episodes_d"] + tr.sum(),
                "reward_d": ast["reward_d"]
-               + jnp.where(truncated, ast["sum_reward"], 0.0).sum()}
-        env_state = env.reset_lanes(env_state, truncated)
-        obs_reset = env.observe(env_state)
-        tr = truncated
-        trc = tr[:, None]
+               + jnp.where(tr, ast["sum_reward"], 0.0).sum()}
         obs_next = jnp.where(tr.reshape((N,) + (1,) * (obs_step.ndim - 1)),
                              obs_reset, obs_step)
-        noop = jnp.zeros((N, A), bool).at[:, 0].set(True)
         ast = {**ast,
                "obs": obs_next,
                "last_action": jnp.where(trc, 0.0, ast["last_action"]),
@@ -476,14 +508,6 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "sum_reward": jnp.where(tr, 0.0, ast["sum_reward"]),
                "prefix": jnp.where(tr, 0, ast["prefix"]),
                "size": jnp.where(tr, 0, ast["size"]),
-               "buf_obs": ast["buf_obs"].at[:, 0].set(
-                   jnp.where(tr[:, None], obs_reset.reshape(N, -1),
-                             ast["buf_obs"][:, 0])),
-               "buf_last_action": ast["buf_last_action"].at[:, 0].set(
-                   jnp.where(trc, noop, ast["buf_last_action"][:, 0])),
-               "buf_last_reward": ast["buf_last_reward"].at[:, 0].set(
-                   jnp.where(tr, 0.0, ast["buf_last_reward"][:, 0])),
-               "buf_hidden": reset_stream(cfg, ast["buf_hidden"], tr),
                **{f"env_{k}": env_state[k] for k in env_keys}}
 
         # 7) deferred boundary cut next step (worker.py block-cut rule)
@@ -499,41 +523,59 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
     return actor_step
 
 
+# the per-lane streams a cut reads whole and then updates in a few rows
+LANE_BUFFERS = ("buf_obs", "buf_last_action", "buf_last_reward",
+                "buf_hidden")
+
+
+def _after_reads(ast: dict, arrays: dict):
+    """Order the in-place updates that follow after a cut's reads of the
+    lane buffers.  The ring a cut has written holds everything the cut
+    read from them, and past this barrier neither is there before the
+    other: without it nothing orders an update of a few rows against the
+    block assembly's read of the same buffer, and the compiler protects
+    the read with a copy of the whole buffer."""
+    bufs, arrays = jax.lax.optimization_barrier(
+        ({k: ast[k] for k in LANE_BUFFERS}, arrays))
+    return {**ast, **bufs}, arrays
+
+
 def _retain_prefix(cfg: Config, ast: dict, cut: jnp.ndarray) -> dict:
     """Post-boundary-cut retention: keep the trailing ``burn_in + 1``
     stream entries in place as the next block's warm prefix
-    (VectorLocalBuffer.finish), realised as a per-lane index-shift gather
-    applied only to cut lanes."""
-    cap = cfg.max_block_steps
-    N = ast["size"].shape[0]
-    entries = ast["prefix"] + ast["size"] + 1
-    keep = jnp.minimum(cfg.burn_in_steps + 1, entries)
-    lo = entries - keep
-    j = jnp.arange(cap, dtype=jnp.int32)
-    src = jnp.where(j[None, :] < keep[:, None], j[None, :] + lo[:, None],
-                    j[None, :])                                 # (N, cap)
-    rows = jnp.arange(N)[:, None]
-
-    def shift(name, src=src):
-        arr = ast[name]
-        shifted = arr[rows, src]
-        return jnp.where(cut.reshape((N, 1) + (1,) * (arr.ndim - 2)),
-                         shifted, arr)
-
-    # the state stream keeps its history (models/state.stream_spec) in
-    # front of the same entries: ``hist`` more are kept, from the same lo
+    (VectorLocalBuffer.finish).  A cut lane keeps ``keep <= keep_max =
+    burn_in_steps + 1`` entries from row ``lo`` on, so only the first
+    ``keep_max`` rows of a stream can change (``keep_max + hist`` of the
+    state stream, whose history lies in front of the same entries,
+    models/state.stream_spec): the window of that many rows from ``lo`` is
+    read first (it may overlap the rows written), selected per lane and
+    per row against the old first rows (``cut`` lanes, ``j < keep``), and
+    set as ONE slice update on the stream's own buffer.  Rows past the
+    window are not touched, and the result is the operand's buffer updated
+    in place — which is what lets the cut's ``lax.cond`` hand its operand
+    back uncopied on the steps where no lane cuts."""
+    keep_max = cfg.burn_in_steps + 1
     hist = stream_spec(cfg)[0]
-    src_h = src
-    if hist:
-        jh = jnp.arange(cap + hist, dtype=jnp.int32)
-        src_h = jnp.where(jh[None, :] < (keep + hist)[:, None],
-                          jh[None, :] + lo[:, None], jh[None, :])
+    entries = ast["prefix"] + ast["size"] + 1
+    keep = jnp.minimum(keep_max, entries)
+    lo = entries - keep         # lo + keep_max <= max_block_steps: no clamp
+
+    def shift(name, extra=0):
+        arr = ast[name]
+        width = keep_max + extra
+        window = jax.vmap(lambda a, l: jax.lax.dynamic_slice_in_dim(
+            a, l, width, 0))(arr, lo)
+        take = cut[:, None] & (jnp.arange(width)[None, :]
+                               < (keep + extra)[:, None])
+        take = take.reshape(take.shape + (1,) * (arr.ndim - 2))
+        return arr.at[:, :width].set(
+            jnp.where(take, window, arr[:, :width]))
 
     return {**ast,
             "buf_obs": shift("buf_obs"),
             "buf_last_action": shift("buf_last_action"),
             "buf_last_reward": shift("buf_last_reward"),
-            "buf_hidden": shift("buf_hidden", src_h),
+            "buf_hidden": shift("buf_hidden", hist),
             "prefix": jnp.where(cut, keep - 1, ast["prefix"]),
             "size": jnp.where(cut, 0, ast["size"])}
 

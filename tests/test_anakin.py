@@ -291,14 +291,36 @@ def test_anakin_blocks_match_local_buffer_oracle(mode):
                                    rtol=0, atol=2e-5)
 
 
-def test_anakin_cut_cond_fast_path_bit_exact():
-    """The r9 lax.cond fast path (skip block emit/retention gathers on
-    no-cut steps — the (block_length-1)/block_length majority) must be
-    BIT-EXACT vs the always-emit variant across a trajectory containing
-    both boundary and episode-end cuts: identical final actor state,
-    ring arrays, PER state, and per-step traces."""
-    cfg = anakin_config(num_actors=3, anakin_episode_len=13,
-                        buffer_capacity=30 * 8)
+def cut_cond_cfg(core, episode):
+    """The fast-path pin's configurations.  ``long``: episodes of 13 steps
+    over blocks of 8 with a burn-in of 4, so a boundary cut keeps its full
+    ``burn_in_steps + 1`` entries.  ``short``: a burn-in of 12 and episodes
+    of 11 steps, shorter than ``burn_in_steps + 1``: the one boundary cut
+    of an episode finds 9 entries, keeps them all (``keep < keep_max``)
+    and the select inside the written window leaves its last rows as
+    they were."""
+    kw = dict(num_actors=3, buffer_capacity=30 * 8,
+              **(dict(anakin_episode_len=13) if episode == "long" else
+                 dict(anakin_episode_len=11, burn_in_steps=12)))
+    if core == "lstm":
+        return anakin_config(**kw)
+    from test_xing4_core import tiny_cfg  # the core's widths at test size
+
+    return tiny_cfg(actor_transport="anakin", device_replay=True,
+                    in_graph_per=True, **kw)
+
+
+@pytest.mark.parametrize("episode", ["long", "short"])
+@pytest.mark.parametrize("core", ["lstm", "xing4"])
+def test_anakin_cut_cond_fast_path_bit_exact(core, episode):
+    """The r9 lax.cond fast path (skip block emit/retention and the cut
+    lanes' stream resets on no-cut steps — the (block_length-1)/
+    block_length majority) must be BIT-EXACT vs the always-emit variant
+    across a trajectory containing both boundary and episode-end cuts:
+    identical final actor state, ring arrays, PER state, and per-step
+    traces — for the LSTM's stream of whole states and for the ``xing4``
+    core's row stream, which keeps a history in front of its entries."""
+    cfg = cut_cond_cfg(core, episode)
     net = create_network(cfg, A)
     params = init_params(cfg, net, jax.random.PRNGKey(0))
     env = AnakinFakeEnv(obs_shape=cfg.stored_obs_shape, action_dim=A,
@@ -319,6 +341,10 @@ def test_anakin_cut_cond_fast_path_bit_exact():
     # the trajectory must actually exercise both cut sites
     assert np.asarray(slow[1]["pending"]).any()
     assert np.asarray(slow[1]["truncated"]).any()
+    assert (cfg.anakin_episode_len < cfg.burn_in_steps + 1) \
+        == (episode == "short")
+    # ... and leave something in the streams to compare
+    assert np.asarray(slow[0][0]["buf_hidden"]).any()
     flat_f, tdef_f = jax.tree_util.tree_flatten(fast)
     flat_s, tdef_s = jax.tree_util.tree_flatten(slow)
     assert tdef_f == tdef_s

@@ -1,7 +1,7 @@
-"""The flagship cell's super-step, and the ``xing4`` core's expert layer,
-compiled for a described (not attached) v5e: what only the TPU compiler
-decides about the device ring and about the routed rows' buffers, checked
-without a chip.
+"""The flagship cell's super-step, the ``xing4`` core's expert layer and
+the fused cells' rollout, compiled for a described (not attached) v5e: what
+only the TPU compiler decides about the device ring, about the routed rows'
+buffers and about the fused loop's lane buffers, checked without a chip.
 
 The compiler has twice chosen a layout for the frame ring under which the
 super-step copies all of it on every dispatch (36 % of device time at a
@@ -31,6 +31,34 @@ def one_chip():
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return topo.devices[0], SingleDeviceSharding(topo.devices[0])
+
+
+def compile_uncached(lowered):
+    """Compile for the described chip with the persistent cache off: what
+    is compiled for a chip that is not attached can be written to it and
+    never read back."""
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _computations(text):
+    """({name: its instructions' lines}, the entry computation's name)."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            entry = name if m.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps, entry
 
 
 @pytest.fixture(scope="module")
@@ -73,12 +101,7 @@ def fabric_super_step(one_chip):
         sds((NB, K, 3), jnp.int32, per["seq_meta"]),
         sds((NB,), jnp.int32, per["first"]),
         sds((), jnp.uint32, table.replicated()))
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        compiled = fn.lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
+    compiled = compile_uncached(fn.lower(*args))
     return cfg, compiled.as_text(), compiled.memory_analysis()
 
 
@@ -142,22 +165,10 @@ def expert_layer(one_chip):
             return jnp.sum(out * g), load
         return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, u)
 
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        compiled = jax.jit(forward_backward).lower(
-            p, sds((tokens, cfg.core_dim)), sds((cfg.core_experts,)),
-            sds((tokens, cfg.core_dim))).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
+    compiled = compile_uncached(jax.jit(forward_backward).lower(
+        p, sds((tokens, cfg.core_dim)), sds((cfg.core_experts,)),
+        sds((tokens, cfg.core_dim))))
     return cfg, tokens * cfg.core_top_k, compiled.as_text()
-
-
-def _computation(text, name):
-    """The body of the HLO computation ``name``."""
-    m = re.search(rf"^%?{re.escape(name)} \(.*?^}}", text, re.M | re.S)
-    assert m, name
-    return m.group(0)
 
 
 def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
@@ -169,6 +180,7 @@ def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
     from r2d2_tpu.models import xing4
 
     cfg, pairs, text = expert_layer
+    comps, _ = _computations(text)
     ladder = xing4.row_ladder(cfg, pairs)
     assert len(ladder) == 4 and ladder[-1] == pairs == 21760
     switches = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}",
@@ -176,7 +188,7 @@ def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
     assert len(switches) == 2                   # forward, backward
     worst = rf"\[{pairs},{cfg.core_dim}\]"
     for names in switches:
-        branches = [_computation(text, n.strip().lstrip("%"))
+        branches = ["\n".join(comps[n.strip().lstrip("%")])
                     for n in names.split(",")]
         assert len(branches) == len(ladder)
         # the last rung is every pair: there the worst case is laid out
@@ -193,3 +205,88 @@ def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
             tiles = set(re.findall(r'ragged_dot_tiling="(\d+),', branch))
             assert tiles and all(int(t) % xing4.ROW_TILE == 0
                                  and rows % int(t) == 0 for t in tiles)
+
+
+# ------------------------------------------------ the fused loop's lane buffers
+
+def _every_step(text):
+    """The computations that run on every env step, cut or no cut: all
+    that the entry computation reaches through loop bodies, fusions and
+    calls and through the FIRST branch of each conditional (a
+    ``lax.cond``'s false branch: the identity of a cut that did not
+    happen), without the entry computation itself, which runs once a
+    dispatch."""
+    comps, entry = _computations(text)
+    seen, stack = set(), [entry]
+    while stack:
+        name = stack.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            called = re.findall(
+                r"\b(?:calls|body|condition|to_apply|false_computation)"
+                r"=%?([\w.\-]+)", line)
+            m = re.search(r"branch_computations=\{%?([\w.\-]+)", line)
+            if m:
+                called.append(m.group(1))
+            stack.extend(c for c in called if c in comps)
+    return {name: comps[name] for name in seen - {entry}}
+
+
+@pytest.mark.parametrize("cell", ["nature_xing4_l5e8h4.anakin",
+                                  "impala_deep_lstm2.anakin"])
+def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
+    """A cut writes only the rows it keeps and a reset follows its cut's
+    read, both in place (learner/anakin._retain_prefix, ``_done_cut``):
+    so in the cell's 4-step rollout no ``copy`` of the state stream's or
+    the frame stream's shape — 186 MB and 199 MB a piece in the ``xing4``
+    cell, 2.96 % of its device time before PR 31 (PERF.md Findings) —
+    stands in the env-step loop's body or in the identity branch of a
+    cut's conditional.  (~30 s and ~12 s.)"""
+    from benchmark.drivers.train import ACTION_DIM, build_config
+    from benchmark.manifest import Manifest
+    from r2d2_tpu.envs.anakin import make_anakin_env
+    from r2d2_tpu.learner.anakin import (
+        make_anakin_rollout,
+        make_anakin_state,
+    )
+    from r2d2_tpu.models.network import create_network, init_params
+    from r2d2_tpu.replay.device_ring import _ring_shapes
+
+    _, sharding = one_chip
+    cfg = build_config(Manifest().cell(cell), False)
+    net = create_network(cfg, ACTION_DIM)
+    env = make_anakin_env(cfg, ACTION_DIM)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    key = jax.random.PRNGKey(0)
+    NB, K = cfg.num_blocks, cfg.seqs_per_block
+    sds = jax.ShapeDtypeStruct
+    ast = jax.eval_shape(
+        lambda k: make_anakin_state(cfg, ACTION_DIM, env, k), key)
+    args = on_chip((
+        jax.eval_shape(lambda k: init_params(cfg, net, k), key), ast,
+        {k: sds((NB, *shape), dtype)
+         for k, (shape, dtype) in _ring_shapes(cfg, ACTION_DIM).items()},
+        sds((NB * K,), jnp.float32), sds((NB, K, 3), jnp.int32),
+        sds((NB,), jnp.int32)))
+    every_step = _every_step(compile_uncached(make_anakin_rollout(
+        cfg, net, env, ACTION_DIM, 4).lower(*args)).as_text())
+
+    names = {"bfloat16": "bf16", "float32": "f32", "uint8": "u8"}
+    lines = [(name, line) for name, body in every_step.items()
+             for line in body]
+    copies = []
+    for buf in ("buf_hidden", "buf_obs"):
+        shape = "{}[{}]".format(names[ast[buf].dtype.name],
+                                ",".join(map(str, ast[buf].shape)))
+        assert any(shape in line for _, line in lines), \
+            f"{buf} {shape} is not in the env-step loop"
+        made = re.compile(rf"= {re.escape(shape)}\S* copy\(")
+        copies += [(buf, name, line.split("=")[0].strip())
+                   for name, line in lines if made.search(line)]
+    assert copies == []
