@@ -6,6 +6,10 @@ in residual streams.
   maps): ``core_streams`` copies of the residual, read by a learned
   sigmoid map, written by a learned 2·sigmoid map and mixed by a
   Sinkhorn-normalised matrix, all three functions of the streams themselves.
+  The expressions here define that arithmetic; a pass of at least a tile of
+  tokens at a width of whole lanes runs it as the kernels of
+  ``ops/streams.py``, one pass over the streams a sublayer
+  (:func:`streams_fused`).
 - **Latent attention**: queries through a low-rank latent; keys and values
   expanded from a shared low-rank latent plus one rotary key shared by the
   heads.  What the recurrent state keeps is that latent, not keys and
@@ -42,6 +46,7 @@ import numpy as np
 from flax import linen as nn
 
 from r2d2_tpu.config import Config
+from r2d2_tpu.ops import streams
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -379,26 +384,65 @@ def routed_experts(cfg: Config, p, u, bias, cd):
     return out, load
 
 
+def streams_fused(cfg: Config, tokens: int) -> bool:
+    """Whether a pass over ``tokens`` tokens takes the streams' kernels
+    (ops/streams.py): decided by the shapes and by where the program is
+    traced for, not by a knob.  The train step and the target forward of a
+    width of whole lanes do; acting's few tokens, and the CPU at test
+    widths unless ``pallas_interpret``, keep the expressions above."""
+    return streams.fits(tokens, cfg.core_dim, cfg.core_streams) and (
+        cfg.pallas_interpret or jax.default_backend() == "tpu")
+
+
+def _attend(cfg: Config, cd, u, args):
+    """The attention sublayer of the normed read u (N, d)."""
+    p, cache = args
+    y, cache = attention(cfg, p, u.reshape(cache.shape[0], -1, cfg.core_dim),
+                         cache, cd)
+    return y.reshape(-1, cfg.core_dim), cache
+
+
+def _feed(cfg: Config, cd, u, args):
+    """The feed-forward sublayer of u (N, d): dense for ``(p,)``, routed
+    for ``(p, bias)``; with the pairs routed to every expert."""
+    if len(args) == 1:
+        with jax.named_scope("dense_ffn"):
+            return (_swiglu(u, args[0], cd),
+                    jnp.zeros(cfg.core_experts, jnp.float32))
+    return routed_experts(cfg, args[0], u, args[1], cd)
+
+
 def block(cfg: Config, p, X, cache, bias, cd):
     """One block over the streams X (n arrays (B T, d)) and its cache (B,
     W, latent); ``bias`` None marks a dense block.  Returns (X', cache',
     load (E,), rows laid out for the experts)."""
-    B, d = cache.shape[0], cfg.core_dim
-    pre, post, res = stream_maps(cfg, p["attn_mix"], X, cd)
-    u = _rms(_streams_read(pre, X), p["attn_norm"], RMS_NORM_EPS)
-    y, cache = attention(cfg, p["attn"], u.reshape(B, -1, d), cache, cd)
-    X = _streams_write(res, post, X, y.reshape(-1, d))
-
-    pre, post, res = stream_maps(cfg, p["ffn_mix"], X, cd)
-    u = _rms(_streams_read(pre, X), p["ffn_norm"], RMS_NORM_EPS)
-    if bias is None:
-        with jax.named_scope("dense_ffn"):
-            y = _swiglu(u, p["dense"], cd)
-        load, rows = jnp.zeros(cfg.core_experts, jnp.float32), 0.0
+    feed_args = (p["dense"],) if bias is None else (p["moe"], bias)
+    if streams_fused(cfg, X[0].shape[0]):
+        # the router multiplies u in float32 at HIGHEST precision: a
+        # routed feed-forward reads it unrounded
+        X, cache, load = streams.block(
+            streams.Spec(cfg.core_streams, cfg.core_sinkhorn_iters,
+                         RMS_NORM_EPS, HC_EPS, H_RES_CLAMP, cd,
+                         cfg.pallas_interpret),
+            functools.partial(_attend, cfg, cd),
+            functools.partial(_feed, cfg, cd),
+            cd if bias is None else jnp.float32,
+            {k: p[k] for k in ("attn_mix", "attn_norm", "ffn_mix",
+                               "ffn_norm")},
+            X, (p["attn"], cache), feed_args)
     else:
-        y, load = routed_experts(cfg, p["moe"], u, bias, cd)
-        rows = rows_laid_out(cfg, u.shape[0] * cfg.core_top_k, load)
-    return _streams_write(res, post, X, y), cache, load, rows
+        pre, post, res = stream_maps(cfg, p["attn_mix"], X, cd)
+        u = _rms(_streams_read(pre, X), p["attn_norm"], RMS_NORM_EPS)
+        y, cache = _attend(cfg, cd, u, (p["attn"], cache))
+        X = _streams_write(res, post, X, y)
+
+        pre, post, res = stream_maps(cfg, p["ffn_mix"], X, cd)
+        u = _rms(_streams_read(pre, X), p["ffn_norm"], RMS_NORM_EPS)
+        y, load = _feed(cfg, cd, u, feed_args)
+        X = _streams_write(res, post, X, y)
+    rows = 0.0 if bias is None else rows_laid_out(
+        cfg, X[0].shape[0] * cfg.core_top_k, load)
+    return X, cache, load, rows
 
 
 def run(cfg: Config, params, router_bias, feats, hidden, cd):
@@ -444,13 +488,15 @@ def bias_update(cfg: Config, router_bias, loads):
     return router_bias + cfg.core_bias_rate * jnp.sign(mean - loads)
 
 
-def load_counters(cfg: Config, router_bias, loads, rows):
-    """COUNTERS, (5,) f32: the share of routed pairs that fell to experts
+def load_counters(cfg: Config, router_bias, loads, rows, fused=0.0):
+    """COUNTERS, (6,) f32: the share of routed pairs that fell to experts
     held here, the held experts' largest load over their mean, the largest
     |bias|; the rows the blocks laid out for their held pairs over all
     routed pairs (1 when every block took the last rung), and the largest
     single block's held pairs over its routed pairs, which decides that
-    block's rung."""
+    block's rung; and ``fused``, the share of the online pass's sublayer
+    passes over the streams that took the kernels (:func:`streams_fused`:
+    all of them or none, fixed when the step is traced)."""
     held = loads[:, :cfg.core_experts_held]
     pairs = jnp.maximum(loads.sum(axis=1), 1.0)
     return jnp.stack([
@@ -458,11 +504,12 @@ def load_counters(cfg: Config, router_bias, loads, rows):
         (held.max(axis=1) / jnp.maximum(held.mean(axis=1), 1e-9)).max(),
         jnp.abs(router_bias).max(),
         rows.sum() / pairs.sum(),
-        (held.sum(axis=1) / pairs).max()])
+        (held.sum(axis=1) / pairs).max(),
+        jnp.asarray(fused, jnp.float32)])
 
 
 COUNTERS = ("held_pair_share", "held_load_max_over_mean", "router_bias_max",
-            "expert_rows_share", "held_rows_max_share")
+            "expert_rows_share", "held_rows_max_share", "stream_passes_fused")
 
 
 def step_buffers(cfg: Config, buffers, stats):
@@ -474,7 +521,8 @@ def step_buffers(cfg: Config, buffers, stats):
     return {**buffers, "core": {
         **buffers["core"], "router_bias": bias,
         "counters": load_counters(cfg, bias, loads,
-                                  stats["core"]["expert_rows"])}}
+                                  stats["core"]["expert_rows"],
+                                  stats["core"]["stream_passes_fused"])}}
 
 
 def _mix_init(cfg: Config, key, layers: int, pd):
@@ -576,7 +624,10 @@ class Xing4Core(nn.Module):
                       (len(COUNTERS),), jnp.float32)
         out, hidden, loads, rows = run(cfg, params, bias.value, feats,
                                        hidden, self.compute_dtype)
-        for name, value in (("expert_load", loads), ("expert_rows", rows)):
+        fused = jnp.float32(streams_fused(
+            cfg, feats.shape[0] * feats.shape[1]))
+        for name, value in (("expert_load", loads), ("expert_rows", rows),
+                            ("stream_passes_fused", fused)):
             self.sow("stats", name, value,
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
         return out, hidden

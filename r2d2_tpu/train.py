@@ -872,6 +872,8 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
                              lc["expert_rows_share"])
                 tracer.gauge("core.held_rows_max_share",
                              lc["held_rows_max_share"])
+                tracer.gauge("core.stream_passes_fused",
+                             lc["stream_passes_fused"])
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"],

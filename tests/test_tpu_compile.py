@@ -1,7 +1,8 @@
-"""The flagship cell's super-step, the ``xing4`` core's expert layer and
-the fused cells' rollout, compiled for a described (not attached) v5e: what
-only the TPU compiler decides about the device ring, about the routed rows'
-buffers and about the fused loop's lane buffers, checked without a chip.
+"""The flagship cell's super-step, the ``xing4`` core's expert layer, its
+blocks' residual streams and the fused cells' rollout, compiled for a
+described (not attached) v5e: what only the TPU compiler decides about the
+device ring, about the routed rows' buffers, about the passes over the
+streams and about the fused loop's lane buffers, checked without a chip.
 
 The compiler has twice chosen a layout for the frame ring under which the
 super-step copies all of it on every dispatch (36 % of device time at a
@@ -205,6 +206,112 @@ def test_the_smallest_rung_lays_out_no_worst_case_rows(expert_layer):
             tiles = set(re.findall(r'ragged_dot_tiling="(\d+),', branch))
             assert tiles and all(int(t) % xing4.ROW_TILE == 0
                                  and rows % int(t) == 0 for t in tiles)
+
+
+# ------------------------------------------- the residual streams' passes
+
+def _entry_operations(text):
+    """(name, result's type, operation, ``op_name``) of the entry
+    computation's instructions: what runs once a call, in order."""
+    comps, entry = _computations(text)
+    out = []
+    for line in comps[entry]:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        path = re.search(r'op_name="([^"]*)"', line)
+        if m:
+            out.append((m.group(1), m.group(2), m.group(3),
+                        path.group(1) if path else ""))
+    return out
+
+
+@pytest.fixture(scope="module")
+def xing4_block(one_chip):
+    """``compiled(dense, backward)``: the optimized HLO text of one block
+    of ``nature_xing4_l5e8h4`` alone at the cell's widths and tokens,
+    ``xing4.block`` forward or ``jax.grad`` of its ``jax.checkpoint``
+    (dense: ~15 s and ~35 s; an expert block forward ~55 s).  The program
+    asks ``jax.default_backend()`` whether its kernels can be lowered, and
+    here that is the CPU: the test answers for the described chip."""
+    from benchmark.drivers.train import build_config
+    from benchmark.manifest import Manifest
+    from r2d2_tpu.models import xing4
+
+    _, sharding = one_chip
+    cfg = build_config(Manifest().cell("nature_xing4_l5e8h4.anakin"), False)
+    tokens, d, cd = cfg.batch_size * cfg.seq_len, cfg.core_dim, jnp.bfloat16
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    X = tuple(sds((tokens, d), cd) for _ in range(cfg.core_streams))
+    cache = sds((cfg.batch_size, cfg.core_context, xing4.latent_dim(cfg)), cd)
+
+    def compiled(dense, backward):
+        p = jax.tree.map(
+            lambda x: sds(x.shape[1:]),
+            jax.eval_shape(lambda k: xing4.init_blocks(
+                k, cfg, 1, dense, jnp.float32), jax.random.PRNGKey(0)))
+        bias = None if dense else sds((cfg.core_experts,))
+
+        def forward(p, X, cache, bias):
+            return xing4.block(cfg, p, X, cache, bias, cd)
+
+        def gradient(p, X, cache, bias):
+            def loss(p, X):
+                out = jax.checkpoint(forward)(p, X, cache, bias)[0]
+                return sum(jnp.sum(o.astype(jnp.float32)) for o in out)
+            return jax.grad(loss, argnums=(0, 1))(p, X)
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            assert xing4.streams_fused(cfg, tokens)
+            lowered = jax.jit(gradient if backward else forward).lower(
+                p, X, cache, bias)
+        return compile_uncached(lowered).as_text()
+
+    return cfg, tokens, compiled
+
+
+@pytest.mark.parametrize("feed,backward,launches", [
+    ("dense", False, ["streams_read", "streams_write_read", "streams_write"]),
+    # the checkpoint's forward again without its last write, whose result
+    # is no residual; then, a sublayer, the write's dy and the one pass
+    ("dense", True, ["streams_read", "streams_write_read",
+                     "streams_dy", "streams_backward",
+                     "streams_dy", "streams_backward"]),
+    ("routed", False, ["streams_read", "streams_write_read",
+                       "streams_write"]),
+])
+def test_a_block_passes_over_its_streams_once_a_sublayer(
+        xing4_block, feed, backward, launches):
+    """5,440 tokens of four streams of 3,584: one stream is 39 MB, and the
+    plain expressions' forward reads all four five times a sublayer and
+    lays ``u`` out in float32 twice (PERF.md Findings, PR 34).  Under
+    ``residual_mix`` the compiled block holds the kernels' launches and
+    nothing else that yields a stream; the only float32 arrays of that
+    size there are ``y``'s cotangents, in the type its product returns,
+    and before a routed feed-forward the ``u`` its router multiplies."""
+    cfg, tokens, compiled = xing4_block
+    ops = _entry_operations(compiled(feed == "dense", backward))
+    mix = [op for op in ops if "residual_mix" in op[3]
+           and op[2] not in ("get-tuple-element", "bitcast")]
+    calls = [name.split(".")[0] for name, _, kind, _ in mix
+             if kind == "custom-call"]
+    assert calls == launches
+    stream = f"bf16[{tokens},{cfg.core_dim}]"
+    wide = f"f32[{tokens},{cfg.core_dim}]"
+    assert any(stream in kind for _, kind, _, _ in mix)
+    for name, result, kind, _ in mix:
+        if stream in result or wide in result:
+            assert kind == "custom-call", (name, result)
+    made_wide = [name.split(".")[0] for name, result, _, _ in mix
+                 if wide in result]
+    assert made_wide == (["streams_dy"] * 2 if backward else
+                         [] if feed == "dense" else ["streams_write_read"])
+    # and u's norm is inside: no reduction over a read stands under no
+    # scope, as the expressions' ``multiply_reduce_fusion`` did
+    assert not [name for name, result, _, path in ops
+                if wide in result and path.endswith("reduce_sum")]
 
 
 # ------------------------------------------------ the fused loop's lane buffers

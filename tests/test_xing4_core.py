@@ -541,6 +541,8 @@ def test_each_core_trains_through_the_fabric_and_the_fused_loop(
         assert last["core"]["expert_rows_share"] == 1
         assert last["core"]["held_pair_share"] <= \
             last["core"]["held_rows_max_share"] <= 1
+        # and a width of 32 keeps the streams' plain expressions
+        assert last["core"]["stream_passes_fused"] == 0
 
 
 def test_the_fused_loop_cuts_the_states_the_host_cutter_cuts():
