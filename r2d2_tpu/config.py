@@ -283,15 +283,21 @@ class Config:
     torso: str = "nature"       # "nature" (model.py:39-49) or "impala" (BASELINE configs[4])
     lstm_layers: int = 1        # BASELINE configs[4] uses 2
     # the memory core between torso and heads (models/network.py): "lstm"
-    # (the stacked LSTM above) or "xing4" (models/xing4.py: latent
+    # (the stacked LSTM above), "xing4" (models/xing4.py: latent
     # attention over a stored latent cache, routed experts, residual
-    # streams mixed by Sinkhorn-normalised matrices).  The core_* fields
-    # below are read only under core="xing4"; their defaults are the
-    # published sizes of the block (xing4_core_config's docstring; the
-    # source's constants that nothing varies are models/xing4.py's), and
-    # ``*_held`` say how many of a layer's heads / routed experts THIS
-    # chip holds of a deployment that divides each layer over chips (the
-    # router keeps its core_experts outputs either way)
+    # streams mixed by Sinkhorn-normalised matrices) or "olmo_hybrid"
+    # (models/olmo_hybrid.py: periods of three gated-delta-rule layers,
+    # whose state is a matrix a head, and one softmax-attention layer
+    # over stored keys and values).  The core_* fields below are read
+    # only under those two; their defaults are the published sizes of
+    # xing4's block (xing4_core_config's docstring; olmo_hybrid_core_config
+    # sets the other's; the sources' constants that nothing varies are
+    # the modules'), and ``*_held`` say how many of a layer's heads /
+    # routed experts THIS chip holds of a deployment that divides each
+    # layer over chips (the router keeps its core_experts outputs either
+    # way).  olmo_hybrid reads core_dim, core_layers, core_context,
+    # core_heads_held, core_dense_dim (its feed-forward) and its own
+    # three head sizes
     core: str = "lstm"
     core_dim: int = 3584            # width of the residual streams
     core_layers: int = 40           # blocks, the leading dense ones included
@@ -314,6 +320,9 @@ class Config:
     core_bias_rate: float = 0.001   # step of the router's correction bias
     core_streams: int = 4           # residual streams
     core_sinkhorn_iters: int = 20
+    core_head_dim: int = 128        # olmo_hybrid: a softmax head
+    core_linear_key_dim: int = 96   # olmo_hybrid: a delta-rule head's keys
+    core_linear_value_dim: int = 192  # ... and its values
 
     # --- evaluation -------------------------------------------------------
     test_epsilon: float = 0.001  # reference: config.py:37
@@ -1013,8 +1022,13 @@ class Config:
             raise ValueError(f"unknown torso {self.torso!r}")
         if self.lstm_layers < 1:
             raise ValueError("lstm_layers must be >= 1")
-        if self.core not in ("lstm", "xing4"):
+        if self.core not in ("lstm", "xing4", "olmo_hybrid"):
             raise ValueError(f"unknown core {self.core!r}")
+        if self.core == "olmo_hybrid" and self.core_layers % 4:
+            raise ValueError(
+                "core='olmo_hybrid' takes whole periods of its layer_types "
+                "(linear_attention x 3, full_attention): core_layers must "
+                "be a multiple of 4")
         if self.core == "xing4":
             if not 0 <= self.core_dense_layers <= self.core_layers:
                 raise ValueError("core_dense_layers must lie in "
@@ -1026,34 +1040,37 @@ class Config:
                 raise ValueError("core_top_k must lie in [1, core_experts]")
             if self.core_rope_dim % 2:
                 raise ValueError("core_rope_dim must be even")
+        if self.core != "lstm":
             if self.core_context < 1 or self.core_heads_held < 1:
                 raise ValueError("core_context and core_heads_held must "
                                  "be >= 1")
-            # paths that would carry one latent cache a lane, a session or
+            # paths that would carry one wide state a lane, a session or
             # a sequence over a wire sized for the LSTM's 4 kB state
-            # (369 kB in bfloat16 at the published widths): not built,
+            # (xing4's latent cache is 369 kB in bfloat16 at the published
+            # widths, olmo_hybrid's matrices and rows 602 kB): not built,
             # refused by name (ROADMAP Queue 2)
             if self.actor_transport == "process":
                 raise ValueError(
-                    "core='xing4' does not run under actor_transport="
-                    "'process': the act slabs and the inference service "
-                    "(parallel/inference_service.py) would carry a whole "
-                    "latent cache a lane a step; use 'thread' or 'anakin'")
+                    f"core={self.core!r} does not run under "
+                    "actor_transport='process': the act slabs and the "
+                    "inference service (parallel/inference_service.py) "
+                    "would carry a whole state a lane a step; use "
+                    "'thread' or 'anakin'")
             if self.replay_shards > 1 or self.replay_transport == "socket":
                 raise ValueError(
-                    "core='xing4' does not run over the sharded replay "
-                    "plane or the net wire (replay/netwire.py): a frame "
-                    "would carry a latent cache a sequence; use the "
+                    f"core={self.core!r} does not run over the sharded "
+                    "replay plane or the net wire (replay/netwire.py): a "
+                    "frame would carry a whole state a sequence; use the "
                     "in-process device ring (replay_shards=1, "
                     "replay_transport='shm')")
             if self.fused_double_unroll:
                 raise ValueError(
-                    "core='xing4' unrolls online and target networks "
-                    "apart (fused_double_unroll stacks parameters, and "
-                    "the router's buffer is not one)")
+                    f"core={self.core!r} unrolls online and target "
+                    "networks apart (fused_double_unroll stacks "
+                    "parameters, and the core's buffers are not ones)")
             if self.stored_hidden_mode != "burn_in_start":
                 raise ValueError(
-                    "core='xing4' stores the latent cache at a sequence's "
+                    f"core={self.core!r} stores its state at a sequence's "
                     "burn-in start only (stored_hidden_mode="
                     "'burn_in_start')")
         if self.lstm_impl not in ("auto", "scan", "pallas"):
@@ -1282,6 +1299,26 @@ def xing4_core_config(game: str = "MsPacman", **kw) -> Config:
                 device_replay=True, in_graph_per=True,
                 superstep_k=4, superstep_pipeline=2,
                 core="xing4", remat=True)
+    base.update(kw)
+    return Config(**base)
+
+
+def olmo_hybrid_core_config(game: str = "MsPacman", **kw) -> Config:
+    """R2D2 with the layers of Olmo-Hybrid-7B
+    (huggingface.co/allenai/Olmo-Hybrid-7B, ``config.json``) as its memory
+    core, at the published sizes: 32 layers of width 3,840 in periods of
+    three gated-delta-rule layers (30 heads of 96 key and 192 value
+    dimensions, a convolution of 4 steps on q, k and v) and one
+    softmax-attention layer (30 heads of 128), each followed by a SwiGLU
+    feed-forward of 11,008.  Torso, heads, windows and optimizer are
+    R2D2's (``pong_config``).  Whole, it fits no chip: a run states its
+    share (``core_layers``, ``core_heads_held``)."""
+    base = dict(game_name=game, num_actors=64,
+                device_replay=True, in_graph_per=True,
+                superstep_k=4, superstep_pipeline=2,
+                core="olmo_hybrid", remat=True,
+                core_dim=3840, core_layers=32, core_heads_held=30,
+                core_dense_dim=11008)
     base.update(kw)
     return Config(**base)
 

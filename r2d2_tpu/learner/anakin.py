@@ -45,6 +45,13 @@ and priorities match to float32 round-off (the host accumulates those in
 float64, which CPU-jax cannot reproduce without x64 mode — the divergence
 is ≤ a few f32 ulps and covered by tolerance assertions).
 
+What a lane keeps of its recurrent states is the model's to say
+(models/state.stream_spec): one entry a step of the part that is a window
+of rows (``buf_hidden``), and — where a state has a part that is no such
+window, as a delta-rule matrix is not — whole snapshots of that part at the
+only steps a stored sequence can start (``buf_snapshot``,
+:func:`_snapshot_slots`), never a whole state a step.
+
 Unlike the host ring writer, block slots keep whatever bytes the lane's
 stream buffer held past the used window instead of zero-padding: the
 sampling clamp invariant (replay_buffer.py) already guarantees those
@@ -77,8 +84,10 @@ from r2d2_tpu.models.network import (
     zero_hidden,
 )
 from r2d2_tpu.models.state import (
+    lanes_like,
     reset_stream,
     stream_entry,
+    stream_snapshot,
     stream_spec,
     stream_states,
 )
@@ -245,8 +254,13 @@ def _make_assemble(cfg: Config, action_dim: int, done: bool):
         # reference's seq-start indexing under the compat switch)
         hidx = seq * L if seq_start_mode else c + seq * L - burn
         hidx = jnp.clip(hidx, 0, cap - 1)
-        hiddens = stream_states(cfg, bufs["hidden"], hidx)
-        hiddens = jnp.where(valid[:, None, None, None], hiddens,
+        # sequence j's snapshot, where the state has a part kept whole,
+        # is slot j: _snapshot_positions' first K are these hidx
+        with jax.named_scope("state_snapshot"):
+            hiddens = stream_states(
+                cfg, bufs["hidden"], hidx,
+                bufs["snapshot"][:K] if "snapshot" in bufs else None)
+        hiddens = jnp.where(lanes_like(valid, hiddens), hiddens,
                             jnp.zeros((), hiddens.dtype))
 
         # actor-side initial priorities (block.py:104-110: plain max-Q
@@ -295,6 +309,8 @@ def _make_emit(cfg: Config, action_dim: int, done: bool):
                     last_reward=ast["buf_last_reward"],
                     hidden=ast["buf_hidden"], action=ast["buf_action"],
                     reward=ast["buf_reward"], qval=ast["buf_qval"])
+        if "buf_snapshot" in ast:
+            bufs["snapshot"] = ast["buf_snapshot"]
         blocks = assemble(bufs, ast["prefix"], ast["size"], last_q)
 
         cut_i = cut.astype(jnp.int32)
@@ -355,9 +371,10 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
     their operand's own buffer for every lane buffer and for the ring.
     The false branch is the identity; the true branch changes a lane
     buffer only by in-place updates of a few rows (a boundary cut writes
-    ``burn_in_steps + 1`` rows (+ the state stream's history),
-    :func:`_retain_prefix`; an episode's end writes row 0 and that
-    history) that are ordered AFTER every read of it
+    ``burn_in_steps + 1`` rows (+ the state stream's history) and the
+    snapshot slots a block hands the next, :func:`_retain_prefix`; an
+    episode's end writes row 0, that history and as many slots) that are
+    ordered AFTER every read of it
     (:func:`_after_reads`).  An update of a whole buffer in a true branch,
     or a reset left outside where nothing orders it against the cut's
     read, costs a copy of the whole buffer on EVERY env step, cut or no
@@ -377,7 +394,7 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                                       cfg.eps_alpha)
                        for i in range(cfg.num_actors)], jnp.float32)
     act_net = _loss_net(cfg, net)  # the scan recurrence, grad-safe twin
-    hist = stream_spec(cfg)[0]
+    hist = stream_spec(cfg).history
     emit_boundary = _make_emit(cfg, action_dim, done=False)
     emit_done = _make_emit(cfg, action_dim, done=True)
     env_keys = tuple(env.STATE_KEYS)
@@ -453,6 +470,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "episode_steps": ast["episode_steps"] + 1,
                "act_key": key,
                **{f"env_{k}": env_state[k] for k in env_keys}}
+        if "buf_snapshot" in ast:
+            ast = _write_snapshots(cfg, ast, p, new_hidden)
 
         # 5) episode-end cuts (terminal: zero bootstrap), and the reset of
         #    the cut lanes' streams (vbuf.reset_lane) AFTER the cut has
@@ -479,6 +498,8 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                  "buf_last_reward": a["buf_last_reward"].at[
                      lanes, row0].set(0.0, mode="drop"),
                  "buf_hidden": reset_stream(cfg, a["buf_hidden"], tr)}
+            if "buf_snapshot" in a:
+                a = _reset_snapshots(cfg, a, tr)
             return a, arr, p, sm, fb
 
         if cut_cond:
@@ -501,7 +522,7 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
                "obs": obs_next,
                "last_action": jnp.where(trc, 0.0, ast["last_action"]),
                "last_reward": jnp.where(tr, 0.0, ast["last_reward"]),
-               "hidden": jnp.where(tr[:, None, None, None],
+               "hidden": jnp.where(lanes_like(tr, ast["hidden"]),
                                    jnp.zeros((), ast["hidden"].dtype),
                                    ast["hidden"]),
                "episode_steps": jnp.where(tr, 0, ast["episode_steps"]),
@@ -524,8 +545,9 @@ def _make_actor_step(cfg: Config, net: R2D2Network, env: Any,
 
 
 # the per-lane streams a cut reads whole and then updates in a few rows
+# (``buf_snapshot`` only where the state has a part kept whole)
 LANE_BUFFERS = ("buf_obs", "buf_last_action", "buf_last_reward",
-                "buf_hidden")
+                "buf_hidden", "buf_snapshot")
 
 
 def _after_reads(ast: dict, arrays: dict):
@@ -536,8 +558,55 @@ def _after_reads(ast: dict, arrays: dict):
     block assembly's read of the same buffer, and the compiler protects
     the read with a copy of the whole buffer."""
     bufs, arrays = jax.lax.optimization_barrier(
-        ({k: ast[k] for k in LANE_BUFFERS}, arrays))
+        ({k: ast[k] for k in LANE_BUFFERS if k in ast}, arrays))
     return {**ast, **bufs}, arrays
+
+
+def _snapshot_slots(cfg: Config):
+    """(slots a lane keeps, how many of them one block hands the next).
+    A stored sequence can start its burn-in at the episode steps ``m *
+    learning_steps - burn_in_steps`` (and at 0) and nowhere else.  Slot m
+    of a block is that step of the block's own sequence m
+    (:func:`_make_assemble`'s ``hidx``); the slots from K on lie in the
+    rows that :func:`_retain_prefix` makes the next block's warm prefix,
+    where that block's first ``burn_in_steps // learning_steps + 1``
+    sequences start."""
+    carried = cfg.burn_in_steps // cfg.learning_steps + 1
+    return cfg.seqs_per_block + carried, carried
+
+
+def _snapshot_positions(cfg: Config, prefix):
+    """The stream positions (N, slots) at which a lane whose block began
+    with ``prefix`` (N,) warm entries keeps a snapshot."""
+    m = jnp.arange(_snapshot_slots(cfg)[0], dtype=jnp.int32)
+    return jnp.maximum(
+        prefix[:, None] + m[None, :] * cfg.learning_steps
+        - cfg.burn_in_steps, 0)
+
+
+@jax.named_scope("state_snapshot")
+def _write_snapshots(cfg: Config, ast: dict, p, states) -> dict:
+    """Keep the snapshot part of ``states`` (N, ...), the lanes' states at
+    stream positions ``p`` (N,), in the slot whose position that is: one
+    row a lane, dropped where ``p`` is none of the lane's positions."""
+    hit = _snapshot_positions(cfg, ast["prefix"]) == p[:, None]
+    slot = jnp.where(hit.any(axis=1), jnp.argmax(hit, axis=1),
+                     hit.shape[1])
+    return {**ast, "buf_snapshot": ast["buf_snapshot"].at[
+        jnp.arange(p.shape[0]), slot].set(
+            stream_snapshot(cfg, states), mode="drop")}
+
+
+@jax.named_scope("state_snapshot")
+def _reset_snapshots(cfg: Config, ast: dict, reset) -> dict:
+    """Zero, for the ``reset`` (N,) lanes, the slots whose position in an
+    episode's first block is row 0 (the initial state): as many as a block
+    hands the next.  A step writes every other slot before a cut reads
+    it."""
+    n0 = _snapshot_slots(cfg)[1]
+    old = ast["buf_snapshot"][:, :n0]
+    return {**ast, "buf_snapshot": ast["buf_snapshot"].at[:, :n0].set(
+        jnp.where(lanes_like(reset, old), jnp.zeros((), old.dtype), old))}
 
 
 def _retain_prefix(cfg: Config, ast: dict, cut: jnp.ndarray) -> dict:
@@ -555,7 +624,7 @@ def _retain_prefix(cfg: Config, ast: dict, cut: jnp.ndarray) -> dict:
     in place — which is what lets the cut's ``lax.cond`` hand its operand
     back uncopied on the steps where no lane cuts."""
     keep_max = cfg.burn_in_steps + 1
-    hist = stream_spec(cfg)[0]
+    hist = stream_spec(cfg).history
     entries = ast["prefix"] + ast["size"] + 1
     keep = jnp.minimum(keep_max, entries)
     lo = entries - keep         # lo + keep_max <= max_block_steps: no clamp
@@ -571,6 +640,13 @@ def _retain_prefix(cfg: Config, ast: dict, cut: jnp.ndarray) -> dict:
         return arr.at[:, :width].set(
             jnp.where(take, window, arr[:, :width]))
 
+    if "buf_snapshot" in ast:
+        # slot K of a full block is its row ``lo``, the next block's row
+        # 0, and so on (_snapshot_slots)
+        with jax.named_scope("state_snapshot"):
+            snaps, n = ast["buf_snapshot"], _snapshot_slots(cfg)[1]
+            ast = {**ast, "buf_snapshot": snaps.at[:, :n].set(jnp.where(
+                lanes_like(cut, snaps), snaps[:, -n:], snaps[:, :n]))}
     return {**ast,
             "buf_obs": shift("buf_obs"),
             "buf_last_action": shift("buf_last_action"),
@@ -610,7 +686,7 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
     N, A, BL = cfg.num_actors, action_dim, cfg.block_length
     cap = cfg.max_block_steps
     obs_shape = cfg.stored_obs_shape
-    hist, entry_shape, state_dtype = stream_spec(cfg)
+    hist, entry_shape, state_dtype, snapshot_shape = stream_spec(cfg)
 
     env_key, act_key = jax.random.split(key)
     env_state = env.init_state(env_key)
@@ -645,6 +721,14 @@ def make_anakin_state(cfg: Config, action_dim: int, env: Any,
         block_learning_total=jnp.zeros(cfg.num_blocks, jnp.int32),
         fill=jnp.zeros((), jnp.int32),
     )
+    if snapshot_shape is not None:
+        if cfg.burn_in_steps > cfg.block_length:
+            raise ValueError(
+                "a state with a part kept whole needs burn_in_steps <= "
+                "block_length: a block's last snapshot is the next "
+                "block's first (learner/anakin._snapshot_slots)")
+        ast["buf_snapshot"] = jnp.zeros(
+            (N, _snapshot_slots(cfg)[0]) + snapshot_shape, state_dtype)
     return _zero_deltas(ast)
 
 

@@ -24,11 +24,13 @@ Recurrent state: one array a sequence, whose shape and dtype the model
 owns (models/state.py, :func:`state_spec`) and every other module derives — for the LSTM
 ``(2, layers, H)`` float32 where axis 0 is (h, c); for ``core="xing4"``
 (models/xing4.py) the latent cache ``(layers, W, latent)`` in the compute
-dtype.  Zeros are the initial state of both.
+dtype; for ``core="olmo_hybrid"`` (models/olmo_hybrid.py) a flat vector of
+delta-rule matrices, convolution tails and stored keys and values.  Zeros
+are the initial state of all three.
 """
 from __future__ import annotations
 
-import functools
+import importlib
 from typing import Any, Tuple
 
 import jax
@@ -41,6 +43,21 @@ from r2d2_tpu.models.state import state_spec, zero_state  # noqa: F401
 
 def _dtype(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
+
+
+# The memory cores that are modules of their own, by ``cfg.core``: each
+# exports ``Core`` (a flax module ``(feats, hidden) -> (outs, hidden')``
+# built from ``cfg, compute_dtype, param_dtype``), ``COUNTERS`` and
+# ``step_buffers`` (the hooks at the end of this file).  The LSTM stack
+# lives here and has none of the three.
+_CORE_MODULES = {"xing4": "r2d2_tpu.models.xing4",
+                 "olmo_hybrid": "r2d2_tpu.models.olmo_hybrid"}
+
+
+def core_module(cfg: Config):
+    """The module of ``cfg.core``, or None for the LSTM stack."""
+    name = _CORE_MODULES.get(cfg.core)
+    return importlib.import_module(name) if name else None
 
 
 class NatureTorso(nn.Module):
@@ -241,10 +258,10 @@ class R2D2Network(nn.Module):
         if cfg.torso == "nature":
             torso_kw["s2d_input"] = cfg.obs_space_to_depth
         self.torso = torso_cls(**torso_kw)
-        if cfg.core == "xing4":
-            from r2d2_tpu.models.xing4 import Xing4Core
-
-            self.core = Xing4Core(cfg=cfg, compute_dtype=cd, param_dtype=pd)
+        module = core_module(cfg)
+        if module is not None:
+            self.core = module.Core(cfg=cfg, compute_dtype=cd,
+                                    param_dtype=pd)
         else:
             impl = resolve_lstm_impl(cfg)
             self.lstm_layers_ = [
@@ -284,9 +301,9 @@ class R2D2Network(nn.Module):
         with jax.named_scope("torso"):
             feats = self._features(obs, last_action, last_reward)
         with jax.named_scope("core"):
-            outs, new_hidden = (self.core(feats, hidden)
-                                if self.cfg.core == "xing4"
-                                else self._lstm_stack(feats, hidden))
+            outs, new_hidden = (self._lstm_stack(feats, hidden)
+                                if core_module(self.cfg) is None
+                                else self.core(feats, hidden))
         with jax.named_scope("heads"):
             B, T = outs.shape[:2]
             q = self.head(outs.reshape(B * T, -1)).reshape(B, T, -1)
@@ -331,7 +348,7 @@ def init_params(cfg: Config, net: R2D2Network, key: jax.Array):
         # what a forward pass sows is not part of the weights
         return {k: v for k, v in variables.items() if k != "stats"}
 
-    if cfg.core == "xing4":
+    if core_module(cfg) is not None:
         # one program that draws the weights and nothing else: op by op,
         # the tracing pass over blocks this wide takes minutes.  The LSTM
         # networks keep drawing op by op: on the TPU one fused program
@@ -358,21 +375,16 @@ def zero_hidden(cfg: Config, batch: int) -> jnp.ndarray:
 def step_buffers(cfg: Config, buffers, stats):
     """The "buffers" collection after an update whose online pass sowed
     ``stats``."""
-    if cfg.core == "xing4":
-        from r2d2_tpu.models import xing4
-
-        return xing4.step_buffers(cfg, buffers, stats)
-    return buffers
+    module = core_module(cfg)
+    return buffers if module is None else module.step_buffers(
+        cfg, buffers, stats)
 
 
 def counter_names(cfg: Config) -> tuple:
     """Names of the float32 scalars the model's train step leaves in its
     buffers for the host to log, in :func:`read_counters`' order."""
-    if cfg.core == "xing4":
-        from r2d2_tpu.models import xing4
-
-        return xing4.COUNTERS
-    return ()
+    module = core_module(cfg)
+    return () if module is None else module.COUNTERS
 
 
 def read_counters(cfg: Config, variables) -> jnp.ndarray:

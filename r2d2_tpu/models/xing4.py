@@ -589,7 +589,7 @@ def init_blocks(key, cfg: Config, layers: int, dense: bool, pd):
     return out
 
 
-class Xing4Core(nn.Module):
+class Core(nn.Module):
     """Declares the core's parameters (one tree a kind of block, stacked:
     the leading axis is the block) and its one buffer, the router's
     correction bias; the arithmetic is :func:`run`."""

@@ -97,12 +97,13 @@ DEFAULT_TABLE: Dict[str, Tuple[Optional[str], ...]] = {
     # divisibility guard wherever tp does not divide them
     "head.*.kernel": ("fsdp", "tp"),
     "head.*.bias": ("tp",),
-    # the xing4 memory core (models/xing4.py): every leaf replicated —
-    # parameters stacked by block, and the router's buffers.  A chip
-    # holds its share of a layer's heads and experts by configuration
-    # (core_heads_held, core_experts_held); there is no expert axis and no
-    # exchange yet (ROADMAP Queue 2), so a mesh only adds dp.  One entry
-    # a depth of the core's tree
+    # the memory cores that are modules of their own (models/xing4.py,
+    # models/olmo_hybrid.py): every leaf replicated — parameters stacked
+    # by block or by period, and the cores' buffers.  A chip holds its
+    # share of a layer's heads and experts by configuration
+    # (core_heads_held, core_experts_held); there is no expert axis, no
+    # axis over the heads and no exchange yet (ROADMAP Queue 2), so a
+    # mesh only adds dp.  One entry a depth of a core's tree
     "core.*": (),
     "core.*.*": (),
     "core.*.*.*": (),

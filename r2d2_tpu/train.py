@@ -861,19 +861,10 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
             s = plane.stats()
             dt = now - last_time
             lc = s["model_counters"]
-            if "held_pair_share" in lc:
-                # the routed experts' counters (models/xing4.COUNTERS) of
-                # the newest harvested dispatch: they ride its result vector
-                tracer.gauge("core.held_pair_share", lc["held_pair_share"])
-                tracer.gauge("core.held_load_max_over_mean",
-                             lc["held_load_max_over_mean"])
-                tracer.gauge("core.router_bias_max", lc["router_bias_max"])
-                tracer.gauge("core.expert_rows_share",
-                             lc["expert_rows_share"])
-                tracer.gauge("core.held_rows_max_share",
-                             lc["held_rows_max_share"])
-                tracer.gauge("core.stream_passes_fused",
-                             lc["stream_passes_fused"])
+            # the model's own counters (models/network.counter_names) of
+            # the newest harvested dispatch: they ride its result vector
+            for name, value in lc.items():
+                tracer.gauge("core." + name, value)  # graftlint: disable=telemetry-discipline -- the names are the model's own closed set (a core module's COUNTERS), not data
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"],

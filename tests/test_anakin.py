@@ -304,22 +304,26 @@ def cut_cond_cfg(core, episode):
                  dict(anakin_episode_len=11, burn_in_steps=12)))
     if core == "lstm":
         return anakin_config(**kw)
-    from test_xing4_core import tiny_cfg  # the core's widths at test size
+    from test_xing4_core import TINY_CFGS  # the cores' widths at test size
 
-    return tiny_cfg(actor_transport="anakin", device_replay=True,
-                    in_graph_per=True, **kw)
+    return TINY_CFGS[core](actor_transport="anakin", device_replay=True,
+                           in_graph_per=True, **kw)
 
 
-@pytest.mark.parametrize("episode", ["long", "short"])
-@pytest.mark.parametrize("core", ["lstm", "xing4"])
+@pytest.mark.parametrize("core,episode", [
+    ("lstm", "long"), ("lstm", "short"), ("xing4", "long"),
+    ("xing4", "short"),
+    # a state with a part kept whole needs burn_in_steps <= block_length
+    ("olmo_hybrid", "long")])
 def test_anakin_cut_cond_fast_path_bit_exact(core, episode):
     """The r9 lax.cond fast path (skip block emit/retention and the cut
     lanes' stream resets on no-cut steps — the (block_length-1)/
     block_length majority) must be BIT-EXACT vs the always-emit variant
     across a trajectory containing both boundary and episode-end cuts:
     identical final actor state, ring arrays, PER state, and per-step
-    traces — for the LSTM's stream of whole states and for the ``xing4``
-    core's row stream, which keeps a history in front of its entries."""
+    traces — for the LSTM's stream of whole states, for the ``xing4``
+    core's row stream, which keeps a history in front of its entries, and
+    for the ``olmo_hybrid`` core's rows and snapshot slots."""
     cfg = cut_cond_cfg(core, episode)
     net = create_network(cfg, A)
     params = init_params(cfg, net, jax.random.PRNGKey(0))

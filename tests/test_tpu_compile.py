@@ -341,23 +341,13 @@ def _every_step(text):
     return {name: comps[name] for name in seen - {entry}}
 
 
-@pytest.mark.parametrize("cell", ["nature_xing4_l5e8h4.anakin",
-                                  "impala_deep_lstm2.anakin"])
-def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
-    """A cut writes only the rows it keeps and a reset follows its cut's
-    read, both in place (learner/anakin._retain_prefix, ``_done_cut``):
-    so in the cell's 4-step rollout no ``copy`` of the state stream's or
-    the frame stream's shape — 186 MB and 199 MB a piece in the ``xing4``
-    cell, 2.96 % of its device time before PR 31 (PERF.md Findings) —
-    stands in the env-step loop's body or in the identity branch of a
-    cut's conditional.  (~30 s and ~12 s.)"""
+def _fused_cell(one_chip, cell):
+    """(cfg, net, env, abstract on-chip (params, carry, ring, prios,
+    seq_meta, first)) of a fused cell at its real shapes."""
     from benchmark.drivers.train import ACTION_DIM, build_config
     from benchmark.manifest import Manifest
     from r2d2_tpu.envs.anakin import make_anakin_env
-    from r2d2_tpu.learner.anakin import (
-        make_anakin_rollout,
-        make_anakin_state,
-    )
+    from r2d2_tpu.learner.anakin import make_anakin_state
     from r2d2_tpu.models.network import create_network, init_params
     from r2d2_tpu.replay.device_ring import _ring_shapes
 
@@ -373,14 +363,33 @@ def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
     key = jax.random.PRNGKey(0)
     NB, K = cfg.num_blocks, cfg.seqs_per_block
     sds = jax.ShapeDtypeStruct
-    ast = jax.eval_shape(
-        lambda k: make_anakin_state(cfg, ACTION_DIM, env, k), key)
-    args = on_chip((
-        jax.eval_shape(lambda k: init_params(cfg, net, k), key), ast,
+    return cfg, net, env, on_chip((
+        jax.eval_shape(lambda k: init_params(cfg, net, k), key),
+        jax.eval_shape(
+            lambda k: make_anakin_state(cfg, ACTION_DIM, env, k), key),
         {k: sds((NB, *shape), dtype)
          for k, (shape, dtype) in _ring_shapes(cfg, ACTION_DIM).items()},
         sds((NB * K,), jnp.float32), sds((NB, K, 3), jnp.int32),
         sds((NB,), jnp.int32)))
+
+
+@pytest.mark.parametrize("cell", ["nature_xing4_l5e8h4.anakin",
+                                  "impala_deep_lstm2.anakin",
+                                  "nature_olmohybrid_l4h4.anakin"])
+def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
+    """A cut writes only the rows it keeps and a reset follows its cut's
+    read, both in place (learner/anakin._retain_prefix, ``_done_cut``):
+    so in the cell's 4-step rollout no ``copy`` of the state stream's or
+    the frame stream's shape — 186 MB and 199 MB a piece in the ``xing4``
+    cell, 2.96 % of its device time before PR 31 (PERF.md Findings) —
+    stands in the env-step loop's body or in the identity branch of a
+    cut's conditional; nor, in the ``olmo_hybrid`` cell, one of the
+    snapshot slots' (331 MB).  (~30 s, ~12 s and ~40 s.)"""
+    from benchmark.drivers.train import ACTION_DIM
+    from r2d2_tpu.learner.anakin import make_anakin_rollout
+
+    cfg, net, env, args = _fused_cell(one_chip, cell)
+    ast = args[1]
     every_step = _every_step(compile_uncached(make_anakin_rollout(
         cfg, net, env, ACTION_DIM, 4).lower(*args)).as_text())
 
@@ -388,7 +397,9 @@ def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
     lines = [(name, line) for name, body in every_step.items()
              for line in body]
     copies = []
-    for buf in ("buf_hidden", "buf_obs"):
+    for buf in ("buf_hidden", "buf_obs", "buf_snapshot"):
+        if buf not in ast:          # only a state with a part kept whole
+            continue
         shape = "{}[{}]".format(names[ast[buf].dtype.name],
                                 ",".join(map(str, ast[buf].shape)))
         assert any(shape in line for _, line in lines), \
@@ -397,3 +408,42 @@ def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
         copies += [(buf, name, line.split("=")[0].strip())
                    for name, line in lines if made.search(line)]
     assert copies == []
+
+
+@pytest.mark.slow
+def test_the_olmo_hybrid_fused_step_holds_within_the_chip(one_chip):
+    """``nature_olmohybrid_l4h4.anakin``'s whole super-step at the ring its
+    file names compiles for the described v5e: the compiler itself refuses
+    a program that passes the chip's 15.75 GiB (it did, by 499 MB, while
+    the stored states were flat vectors that it padded 1.6 times and
+    copied whole, PERF.md Findings, PR 35; and it refuses 384 blocks by
+    1.53 GB).  Nor does the step copy the ring of stored states or the
+    snapshot slots: nothing but a parameter and the loops' own tuples has
+    their shapes.  (~75 s on every core of the host: ``slow``, so that
+    tier-1's 3-second rehearsal windows are not starved beside it.)"""
+    from benchmark.drivers.train import ACTION_DIM
+    from r2d2_tpu.learner.anakin import make_anakin_super_step
+    from r2d2_tpu.learner.step import create_train_state
+
+    _, sharding = one_chip
+    cfg, net, env, (params, *loop) = _fused_cell(
+        one_chip, "nature_olmohybrid_l4h4.anakin")
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda p: create_train_state(cfg, p), params))
+    compiled = compile_uncached(make_anakin_super_step(
+        cfg, net, env, ACTION_DIM).lower(
+            state, *loop, jax.ShapeDtypeStruct(
+                (), jnp.uint32, sharding=sharding)))
+    memory = compiled.memory_analysis()
+    assert cfg.num_blocks == 192
+    # weights, target, moments, ring and carry: 10.6 GiB of arguments,
+    # every byte of which the step hands back in place
+    assert 10.4 * 2 ** 30 < memory.argument_size_in_bytes < 10.8 * 2 ** 30
+    assert memory.alias_size_in_bytes > 0.999 * memory.argument_size_in_bytes
+    text = compiled.as_text()
+    for name, arr in (("hidden", loop[1]["hidden"]),
+                      ("buf_snapshot", loop[0]["buf_snapshot"])):
+        shape = "bf16[{}]".format(",".join(map(str, arr.shape)))
+        assert shape in text, (name, shape)
+        assert not re.findall(rf"= {re.escape(shape)}\S* copy\(", text), name

@@ -64,6 +64,17 @@ def tiny_cfg(**kw):
     return xing4_core_config(game="Fake", **dict(TINY, **kw))
 
 
+def olmo_tiny_cfg(**kw):
+    from test_olmo_hybrid_core import tiny_cfg as make
+
+    return make(**kw)
+
+
+# the cores that are modules of their own, at the tests' widths: what the
+# program's paths do with a state that is not the LSTM's
+TINY_CFGS = {"xing4": tiny_cfg, "olmo_hybrid": olmo_tiny_cfg}
+
+
 def shaken(tree, seed, scale=0.1):
     """Every leaf moved off its initial value, so that no gain is 1, no
     bias 0 and no two streams alike."""
@@ -485,7 +496,7 @@ def test_state_spec_is_the_lstm_state_bit_for_bit(cfg):
     shape, dtype = state_spec(cfg)
     assert shape == (2, cfg.lstm_layers, cfg.hidden_dim)
     assert dtype == np.float32
-    assert stream_spec(cfg) == (0, shape, dtype)
+    assert stream_spec(cfg) == (0, shape, dtype, None)
     zero = zero_hidden(cfg, 3)
     assert zero.shape == (3,) + shape and zero.dtype == jnp.float32
 
@@ -495,7 +506,7 @@ def test_state_spec_of_the_latent_cache(dtype):
     cfg = tiny_cfg(compute_dtype=dtype)
     shape, np_dtype = state_spec(cfg)
     assert shape == (3, 4, 16 + 4) and np_dtype.name == dtype
-    assert stream_spec(cfg) == (3, (3, 20), np_dtype)
+    assert stream_spec(cfg) == (3, (3, 20), np_dtype, None)
     full = training.config_from_file(DOC["config"])
     assert state_spec(full)[0] == (5, 64, 576)
     assert state_spec(full)[1].itemsize * int(np.prod(state_spec(full)[0])) \
@@ -510,7 +521,7 @@ def env_factory(cfg, seed):
 
 
 @pytest.mark.parametrize("drivetrain", ["thread_fabric", "fused_loop"])
-@pytest.mark.parametrize("core", ["lstm", "xing4"])
+@pytest.mark.parametrize("core", ["lstm", "xing4", "olmo_hybrid"])
 def test_each_core_trains_through_the_fabric_and_the_fused_loop(
         core, drivetrain):
     """A few updates end to end: host thread actors cutting blocks into the
@@ -521,7 +532,7 @@ def test_each_core_trains_through_the_fabric_and_the_fused_loop(
               superstep_k=2, log_interval=0.2, save_interval=10 ** 8)
     if drivetrain == "fused_loop":
         kw.update(actor_transport="anakin", anakin_episode_len=12)
-    cfg = (tiny_cfg(**kw) if core == "xing4" else
+    cfg = (TINY_CFGS[core](**kw) if core in TINY_CFGS else
            make_test_config(training_steps=8, **kw))
     m = train(cfg, verbose=False, max_wall_seconds=240,
               **({} if drivetrain == "fused_loop"
@@ -543,6 +554,17 @@ def test_each_core_trains_through_the_fabric_and_the_fused_loop(
             last["core"]["held_rows_max_share"] <= 1
         # and a width of 32 keeps the streams' plain expressions
         assert last["core"]["stream_passes_fused"] == 0
+    if core == "olmo_hybrid" and drivetrain == "fused_loop":
+        from r2d2_tpu.models import olmo_hybrid
+
+        # its three counters ride the dispatch's result vector likewise
+        last = m["logs"][-1]
+        assert set(last["core"]) == set(olmo_hybrid.COUNTERS)
+        assert 0 < last["core"]["state_abs_max"] < 100
+        assert 0 < last["core"]["decay_mean"] < 1
+        assert 0 < last["core"]["beta_mean"] < 2
+        for name in olmo_hybrid.COUNTERS:
+            assert last["trace"]["gauge.core." + name] == last["core"][name]
 
 
 def test_the_fused_loop_cuts_the_states_the_host_cutter_cuts():
@@ -604,11 +626,17 @@ def test_the_learner_asks_the_model_for_what_it_keeps_not_for_its_name():
     """learner/ derives the state's stream, the buffers and the counters
     from models/ (state.py, network.step_buffers / counter_names): neither
     module names a core or reaches into its tree."""
-    for name in ("step.py", "anakin.py", "learner.py"):
-        with open(os.path.join(ROOT, "r2d2_tpu", "learner", name)) as f:
+    sources = [("learner", name) for name in ("step.py", "anakin.py",
+                                              "learner.py")]
+    sources += [("replay", "device_ring.py"), ("replay", "block.py"),
+                (".", "actor.py"), (".", "train.py")]
+    for folder, name in sources:
+        with open(os.path.join(ROOT, "r2d2_tpu", folder, name)) as f:
             source = f.read()
         assert "xing4" not in source and "cfg.core" not in source, name
+        assert "olmo_hybrid" not in source, name
         assert "router_bias" not in source and "expert_load" not in source
+        assert "linear_stats" not in source, name
 
 
 def test_the_cores_constants_are_the_sources():
@@ -633,7 +661,11 @@ def test_the_cores_constants_are_the_sources():
         DOC["hc_mult"], DOC["hc_sinkhorn_iters"])
 
 
-def test_checkpoint_restore_and_eval_with_the_latent_cache(tmp_path):
+@pytest.mark.parametrize("core,stored", [
+    ("xing4", [[3, 4, 20], "float32"]),
+    ("olmo_hybrid", [[64, 128], "float32"])])
+def test_checkpoint_restore_and_eval_with_a_cores_state(tmp_path, core,
+                                                         stored):
     from r2d2_tpu.checkpoint import (
         Checkpointer,
         arch_meta,
@@ -641,7 +673,7 @@ def test_checkpoint_restore_and_eval_with_the_latent_cache(tmp_path):
     )
     from r2d2_tpu.evaluate import evaluate_params
 
-    cfg = tiny_cfg()
+    cfg = TINY_CFGS[core]()
     net = create_network(cfg, A)
     params = init_params(cfg, net, jax.random.PRNGKey(0))
     state = create_train_state(cfg, params)
@@ -655,11 +687,11 @@ def test_checkpoint_restore_and_eval_with_the_latent_cache(tmp_path):
     for a, b in zip(jax.tree.leaves(jax.device_get(state)),
                     jax.tree.leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert meta["core"] == "xing4"
-    assert meta["recurrent_state"] == [[3, 4, 20], "float32"]
+    assert meta["core"] == core
+    assert meta["recurrent_state"] == stored
     check_arch_compat(cfg, meta)
     with pytest.raises(ValueError, match="recurrent_state"):
-        check_arch_compat(cfg.replace(core_context=8), meta)
+        check_arch_compat(cfg.replace(core_context=64), meta)
     with pytest.raises(ValueError, match="core"):
         check_arch_compat(make_test_config(), meta)
     assert np.isfinite(evaluate_params(cfg, net, restored.params,
@@ -667,29 +699,34 @@ def test_checkpoint_restore_and_eval_with_the_latent_cache(tmp_path):
                                        epsilon=0.0, seed=0))
 
 
-def test_the_paths_not_built_refuse_the_core_by_name(tmp_path):
-    with pytest.raises(ValueError, match="inference_service"):
-        tiny_cfg(actor_transport="process")
-    with pytest.raises(ValueError, match="netwire"):
-        tiny_cfg(replay_transport="socket", replay_shards=2,
-                 device_replay=False, in_graph_per=False)
-    with pytest.raises(ValueError, match="netwire"):
-        tiny_cfg(replay_shards=2, device_replay=False, in_graph_per=False)
-    with pytest.raises(ValueError, match="stored_hidden_mode"):
-        tiny_cfg(stored_hidden_mode="seq_start")
+@pytest.mark.parametrize("core", sorted(TINY_CFGS))
+def test_the_paths_not_built_refuse_the_core_by_name(tmp_path, core):
+    make = TINY_CFGS[core]
+    with pytest.raises(ValueError, match=f"{core}.*inference_service"):
+        make(actor_transport="process")
+    with pytest.raises(ValueError, match=f"{core}.*netwire"):
+        make(replay_transport="socket", replay_shards=2,
+             device_replay=False, in_graph_per=False)
+    with pytest.raises(ValueError, match=f"{core}.*netwire"):
+        make(replay_shards=2, device_replay=False, in_graph_per=False)
+    with pytest.raises(ValueError, match=f"{core}.*fused_double_unroll"):
+        make(fused_double_unroll=True)
+    with pytest.raises(ValueError, match=f"{core}.*stored_hidden_mode"):
+        make(stored_hidden_mode="seq_start")
     from r2d2_tpu.serving.server import run_server
 
-    with pytest.raises(ValueError, match="session tier"):
-        run_server(tiny_cfg(), str(tmp_path))
+    with pytest.raises(ValueError, match=f"session tier.*{core}"):
+        run_server(make(), str(tmp_path))
 
 
-def test_the_sharding_table_resolves_every_leaf_of_the_core():
+@pytest.mark.parametrize("core", sorted(TINY_CFGS))
+def test_the_sharding_table_resolves_every_leaf_of_the_core(core):
     from jax.sharding import PartitionSpec as P
 
     from r2d2_tpu.parallel.mesh import make_mesh
     from r2d2_tpu.parallel.sharding import ShardingTable
 
-    cfg = tiny_cfg()
+    cfg = TINY_CFGS[core]()
     net = create_network(cfg, A)
     state = jax.eval_shape(lambda: create_train_state(
         cfg, init_params(cfg, net, jax.random.PRNGKey(0))))
