@@ -20,8 +20,8 @@ TPU-first redesign:
 - Priorities (worker.py:268-276, a host-side Python loop in the reference,
   forcing a device→host sync every step) are computed inside the jit as
   masked segment max/mean and returned as one small array.
-- Target sync (worker.py:376-377) happens in-graph via a step-counter select,
-  so the whole training loop state lives on device.
+- Target sync (worker.py:376-377) happens in-graph as a conditional copy on
+  the step counter, so the whole training loop state lives on device.
 """
 from __future__ import annotations
 
@@ -225,6 +225,21 @@ def _td_loss(cfg: Config, batch, q_online, q_target_seq, with_aux: bool):
     return loss, (priorities, aux)
 
 
+def _sync_target(sync, params, target_params):
+    """The hard target sync (worker.py:376-377): the new parameters on the
+    update that syncs, a copy; the target network as it is, untouched, on
+    every other.  A ``jnp.where`` over the leaves gives the same values
+    and reads and writes every target leaf on every update."""
+    return jax.lax.cond(sync, lambda p, t: p, lambda p, t: t,
+                        params, target_params)
+
+
+def target_syncs(cfg: Config, updates: int) -> int:
+    """How many of the first ``updates`` updates took the sync's copying
+    branch: the host's reckoning, from its own count of updates."""
+    return updates // cfg.target_net_update_interval
+
+
 def make_train_step(cfg: Config, net: R2D2Network,
                     learnhealth: bool = False):
     """Returns ``train_step(state, batch) -> (state, loss, priorities)``
@@ -271,9 +286,7 @@ def make_train_step(cfg: Config, net: R2D2Network,
 
             step = state.step + 1
             sync = (step % cfg.target_net_update_interval) == 0
-            new_target = jax.tree.map(
-                lambda p, t: jnp.where(sync, p, t), new_params,
-                state.target_params)
+            new_target = _sync_target(sync, new_params, state.target_params)
 
         new_state = TrainState(step=step, params=new_params,
                                target_params=new_target,

@@ -56,7 +56,7 @@ from r2d2_tpu.checkpoint import Checkpointer
 from r2d2_tpu.config import Config
 from r2d2_tpu.envs import create_env
 from r2d2_tpu.learner.learner import Learner
-from r2d2_tpu.learner.step import create_train_state
+from r2d2_tpu.learner.step import create_train_state, target_syncs
 from r2d2_tpu.models.network import create_network, init_params
 from r2d2_tpu.parallel.mesh import make_mesh
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
@@ -865,9 +865,11 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
             # the newest harvested dispatch: they ride its result vector
             for name, value in lc.items():
                 tracer.gauge("core." + name, value)  # graftlint: disable=telemetry-discipline -- the names are the model's own closed set (a core module's COUNTERS), not data
+            syncs = target_syncs(cfg, s["training_steps"])
+            tracer.gauge("learner.target_syncs", syncs)
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
-                training_steps=s["training_steps"],
+                training_steps=s["training_steps"], target_syncs=syncs,
                 updates_per_sec=(s["training_steps"] - last_steps) / dt,
                 mean_episode_return=(s["episode_reward"] / s["num_episodes"]
                                      if s["num_episodes"] else float("nan")),
@@ -1335,9 +1337,11 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
             dt = now - last_time
             tracer.gauge("batch_queue_depth", batch_queue.qsize())
             tracer.gauge("priority_queue_depth", priority_queue.qsize())
+            syncs = target_syncs(cfg, s["training_steps"])
+            tracer.gauge("learner.target_syncs", syncs)
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
-                training_steps=s["training_steps"],
+                training_steps=s["training_steps"], target_syncs=syncs,
                 updates_per_sec=(s["training_steps"] - last_steps) / dt,
                 mean_episode_return=(s["episode_reward"] / s["num_episodes"]
                                      if s["num_episodes"] else float("nan")),

@@ -452,7 +452,7 @@ def test_anakin_train_fast_plumbing():
     """Unmarked fast e2e: the full train() branch (telemetry, log loop,
     cadences) completes, counters are consistent, guards hold."""
     cfg = anakin_config(training_steps=24, log_interval=0.2,
-                        save_interval=10 ** 8)
+                        save_interval=10 ** 8, target_net_update_interval=5)
     m = train(cfg, verbose=False, max_wall_seconds=240)
     assert m["num_updates"] >= 24
     assert np.isfinite(m["mean_loss"])
@@ -463,6 +463,10 @@ def test_anakin_train_fast_plumbing():
     assert len(m["logs"]) > 0
     last = m["logs"][-1]
     assert last["anakin"]["super_steps"] == m["anakin_super_steps"]
+    # the updates that took the sync's copying branch, by the host's count
+    for e in m["logs"]:
+        assert e["target_syncs"] == e["training_steps"] // 5
+        assert e["trace"]["gauge.learner.target_syncs"] == e["target_syncs"]
     from r2d2_tpu.utils.trace import RETRACES
 
     RETRACES.assert_within_budgets()

@@ -4,8 +4,10 @@ import numpy as np
 import pytest
 
 from r2d2_tpu.config import test_config as make_test_config
+from r2d2_tpu.learner import step as step_module
 from r2d2_tpu.learner.step import (
     TrainState, create_train_state, loss_and_priorities,
+    make_super_step_fn, make_train_step,
     _window_indices, value_rescale, inverse_value_rescale,
 )
 from r2d2_tpu.models.network import R2D2Network, create_network, init_params
@@ -188,6 +190,70 @@ def test_train_step_reduces_loss_and_syncs_target():
             assert max(jax.tree.leaves(diff)) == 0.0
     assert losses[-1] < losses[0]
     assert int(state.step) == 10
+
+
+def _select_sync(sync, params, target_params):
+    """The oracle of ``step._sync_target``: the select over every leaf
+    that the step held before its sync became a conditional copy."""
+    return jax.tree.map(lambda p, t: jnp.where(sync, p, t), params,
+                        target_params)
+
+
+@pytest.mark.parametrize("interval,updates,k,learnhealth", [
+    (1000, 6, 1, False),   # an interval the run never reaches
+    (1, 4, 1, False),      # every update syncs
+    (5, 12, 4, False),     # syncs at updates 5 and 10, inside super-steps
+    (3, 7, 1, True),       # the diagnostics read the new target
+], ids=["never", "every_update", "inside_a_super_step", "learnhealth"])
+def test_conditional_target_sync_is_the_select_bit_for_bit(
+        monkeypatch, interval, updates, k, learnhealth):
+    """The state after N updates under the conditional copy equals, leaf
+    for leaf and bit for bit, the state under the select, as do the
+    losses, priorities and diagnostics on the way."""
+    cfg = make_test_config(target_net_update_interval=interval,
+                           learnhealth_interval=2 if learnhealth else 0)
+    net = create_network(cfg, A)
+    rng = np.random.default_rng(12)
+    batches = [make_batch(cfg, rng, B=4) for _ in range(3)]
+
+    def run():
+        state = create_train_state(
+            cfg, init_params(cfg, net, jax.random.PRNGKey(5)))
+        outs = []
+        if k == 1:
+            fn = jax.jit(make_train_step(cfg, net, learnhealth=learnhealth))
+            for i in range(updates):
+                state, *out = fn(state, batches[i % len(batches)])
+                outs.append(out)
+        else:
+            stacked = {key: jnp.stack([b[key] for b in batches])
+                       for key in batches[0] if key != "is_weights"}
+            fn = jax.jit(make_super_step_fn(
+                cfg, net, k, gather=lambda arrays, ints_t, w_t: {
+                    **jax.tree.map(lambda a: a[ints_t[0, 0]], arrays),
+                    "is_weights": w_t}))
+            for d in range(updates // k):
+                pick = (np.arange(k) + d * k) % len(batches)
+                ints = np.broadcast_to(
+                    pick[:, None, None], (k, 4, 6)).astype(np.int32)
+                w = np.stack([batches[i]["is_weights"] for i in pick])
+                state, *out = fn(state, stacked, ints, w)
+                outs.append(out)
+        return jax.tree.map(np.asarray, (state, outs))
+
+    got = run()
+    monkeypatch.setattr(step_module, "_sync_target", _select_sync)
+    want = run()
+    assert int(got[0].step) == updates
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    synced = max(jax.tree.leaves(jax.tree.map(
+        lambda p, t: float(np.abs(p - t).max()),
+        got[0].params, got[0].target_params))) == 0.0
+    assert synced == (updates % interval == 0)
+    if interval > updates:     # the target is still the initial network
+        first = init_params(cfg, net, jax.random.PRNGKey(5))
+        jax.tree.map(np.testing.assert_array_equal, got[0].target_params,
+                     jax.tree.map(np.asarray, first))
 
 
 def test_gradients_do_not_flow_into_target_selection():

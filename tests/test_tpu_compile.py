@@ -1,8 +1,9 @@
 """The flagship cell's super-step, the ``xing4`` core's expert layer, its
 blocks' residual streams and the fused cells' rollout, compiled for a
 described (not attached) v5e: what only the TPU compiler decides about the
-device ring, about the routed rows' buffers, about the passes over the
-streams and about the fused loop's lane buffers, checked without a chip.
+device ring, about the optimizer's passes over the weights' state, about
+the routed rows' buffers, about the passes over the streams and about the
+fused loop's lane buffers, checked without a chip.
 
 The compiler has twice chosen a layout for the frame ring under which the
 super-step copies all of it on every dispatch (36 % of device time at a
@@ -60,6 +61,75 @@ def _computations(text):
         elif name is not None:
             comps[name].append(line)
     return comps, entry
+
+
+def _operations(lines):
+    """(name, result's type, operation, operands' names, ``op_name``) of a
+    computation's instructions, in order."""
+    out = []
+    for line in lines:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$",
+                     line)
+        if not m:
+            continue
+        rest, depth, end = m.group(4), 1, 0
+        while depth and end < len(rest):
+            depth += (rest[end] == "(") - (rest[end] == ")")
+            end += 1
+        path = re.search(r'op_name="([^"]*)"', line)
+        out.append((m.group(1), m.group(2), m.group(3),
+                    re.findall(r"%([\w.\-]+)", rest[:end]),
+                    path.group(1) if path else ""))
+    return out
+
+
+def _arrays(result):
+    """[(``f32[512,2048]``, its bytes)] of a result's type, a tuple's
+    elements apart, layouts left out."""
+    sizes = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "u8": 1, "pred": 1}
+    return [(f"{dtype}[{dims}]",
+             sizes[dtype] * int(np.prod([int(d) for d in dims.split(",")
+                                         if d])))
+            for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", result)
+            if dtype in sizes]
+
+
+def assert_the_target_sync_copies_only_when_it_syncs(text):
+    """The hard target sync is a conditional on the step counter
+    (learner/step._sync_target), and the compiler carries the target
+    network through it in place: its first branch (``lax.cond``'s false
+    one, 1,999 updates of 2,000) holds nothing but its parameter, its
+    second the copies; no operation under ``optimizer`` outside it reads a
+    target leaf; and no pass under ``optimizer`` yields more than three
+    arrays of the largest leaf's shape — Adam's two moments and the
+    weights, not the target network written back beside them as the
+    select over every leaf did (PERF.md Findings, PR 36)."""
+    comps, _ = _computations(text)
+    syncs = [(lines, line) for lines in comps.values() for line in lines
+             if " conditional(" in line and '/optimizer/cond"' in line]
+    assert len(syncs) == 1, [line for _, line in syncs]
+    lines, line = syncs[0]
+    identity, copying = (
+        _operations(comps[name]) for name in re.search(
+            r"branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}",
+            line).groups())
+    assert [kind for _, _, kind, _, _ in identity] == ["parameter"]
+    assert "copy" in {kind for _, _, kind, _, _ in copying}
+    ops = _operations(lines)
+    by_name = {op[0]: op for op in ops}
+    # conditional(predicate, the first branch's operands, the second's)
+    kept = _operations([line])[0][3][1]
+    target = set(by_name[kept][3])
+    assert by_name[kept][2] == "tuple" and len(target) > 1
+    assert [name for name, _, _, operands, path in ops
+            if "/optimizer/" in path and target & set(operands)
+            and name != kept] == []
+    largest = max((a for leaf in target for a in _arrays(by_name[leaf][1])),
+                  key=lambda a: a[1])[0]
+    passes = {name: [a for a, _ in _arrays(result)].count(largest)
+              for name, result, kind, _, path in ops
+              if "/optimizer/" in path and kind == "fusion"}
+    assert max(passes.values()) == 3, (largest, passes)
 
 
 @pytest.fixture(scope="module")
@@ -135,6 +205,11 @@ def test_fabric_super_step_reads_the_frames_as_windows(fabric_super_step):
     words = frame_words(int(np.prod(cfg.stored_obs_shape)))
     assert re.search(rf"u32\[{B},1,{T},{words}\]", text)
     assert not re.search(rf"u8\[{B * T},\d+\]\S* fusion\(", text)
+
+
+def test_fabric_super_step_copies_the_target_only_when_it_syncs(
+        fabric_super_step):
+    assert_the_target_sync_copies_only_when_it_syncs(fabric_super_step[1])
 
 
 # ------------------------------------------- the routed experts' row ladder
@@ -214,14 +289,8 @@ def _entry_operations(text):
     """(name, result's type, operation, ``op_name``) of the entry
     computation's instructions: what runs once a call, in order."""
     comps, entry = _computations(text)
-    out = []
-    for line in comps[entry]:
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
-        path = re.search(r'op_name="([^"]*)"', line)
-        if m:
-            out.append((m.group(1), m.group(2), m.group(3),
-                        path.group(1) if path else ""))
-    return out
+    return [(name, result, kind, path)
+            for name, result, kind, _, path in _operations(comps[entry])]
 
 
 @pytest.fixture(scope="module")
@@ -419,8 +488,12 @@ def test_the_olmo_hybrid_fused_step_holds_within_the_chip(one_chip):
     copied whole, PERF.md Findings, PR 35; and it refuses 384 blocks by
     1.53 GB).  Nor does the step copy the ring of stored states or the
     snapshot slots: nothing but a parameter and the loops' own tuples has
-    their shapes.  (~75 s on every core of the host: ``slow``, so that
-    tier-1's 3-second rehearsal windows are not starved beside it.)"""
+    their shapes.  The target network rides the step's k updates in place
+    (2.23 GB that the select read and wrote on each of them), and the
+    conditional that carries it costs no buffer: the program's temporaries
+    are no more than they were under the select.  (~75 s on every core of
+    the host: ``slow``, so that tier-1's 3-second rehearsal windows are
+    not starved beside it.)"""
     from benchmark.drivers.train import ACTION_DIM
     from r2d2_tpu.learner.anakin import make_anakin_super_step
     from r2d2_tpu.learner.step import create_train_state
@@ -447,3 +520,5 @@ def test_the_olmo_hybrid_fused_step_holds_within_the_chip(one_chip):
         shape = "bf16[{}]".format(",".join(map(str, arr.shape)))
         assert shape in text, (name, shape)
         assert not re.findall(rf"= {re.escape(shape)}\S* copy\(", text), name
+    assert_the_target_sync_copies_only_when_it_syncs(text)
+    assert memory.temp_size_in_bytes <= 5_985_007_616
