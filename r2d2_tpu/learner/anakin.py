@@ -1421,8 +1421,7 @@ def run_anakin_loop(learner: Any, plane: AnakinPlane,
             if stop is not None and stop():
                 break
             if not plane.ready:
-                with tracer.span("anakin.rollout_dispatch"):
-                    plane.rollout_step(learner.state.params)
+                plane.rollout_step(learner.state.params)
                 continue
             if cfg.transfer_guard and not guard_armed:
                 guard_stack.enter_context(TRANSFER_GUARD.arm())

@@ -40,6 +40,7 @@ runs one device act per step (parallel/inference_service.py).
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import os
 import queue
@@ -64,7 +65,13 @@ from r2d2_tpu.telemetry import Telemetry, format_entry
 from r2d2_tpu.utils.math import epsilon_ladder
 from r2d2_tpu.utils.store import ParamStore
 from r2d2_tpu.utils.supervisor import Heartbeat, Supervisor
-from r2d2_tpu.utils.trace import Tracer, device_memory, device_profile
+from r2d2_tpu.utils.trace import (
+    SetupClock,
+    Tracer,
+    device_memory,
+    device_profile,
+    maybe_phase,
+)
 
 log = logging.getLogger(__name__)
 
@@ -77,10 +84,12 @@ def _default_env_factory(cfg: Config, seed: int):
 
 def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
            checkpoint_dir: Optional[str], resume: bool,
-           tracer: Optional[Tracer] = None):
+           tracer: Optional[Tracer] = None,
+           setup: Optional[SetupClock] = None):
     """Common bring-up: envs, net, state (maybe restored), buffer, stores.
     ``tracer`` goes to the in-process buffer and the thread actors, whose
-    spans split a block's write and a lockstep step.
+    spans split a block's write and a lockstep step; ``setup`` times the
+    state and the ring as the phases ``setup.state`` and ``setup.ring``.
 
     The single-process drivetrain is exactly the one ``cfg`` names: a
     ``device_replay`` ring that does not fit the device is a ValueError
@@ -99,21 +108,23 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
     else:
         envs = [env_factory(cfg, cfg.seed + i) for i in range(cfg.num_actors)]
         action_dim = envs[0].action_space.n
-    net = create_network(cfg, action_dim)
-    params = init_params(cfg, net, jax.random.PRNGKey(cfg.seed))
-    state = create_train_state(cfg, params)
+    with maybe_phase(setup, "setup.state"):
+        net = create_network(cfg, action_dim)
+        params = init_params(cfg, net, jax.random.PRNGKey(cfg.seed))
+        state = create_train_state(cfg, params)
 
-    checkpointer = (Checkpointer(checkpoint_dir, keep=cfg.keep_checkpoints)
-                    if checkpoint_dir else None)
-    start_env_steps, start_minutes = 0, 0.0
-    if (checkpointer is not None and resume
-            and checkpointer.latest_step() is not None):
-        from r2d2_tpu.checkpoint import check_arch_compat
+        checkpointer = (Checkpointer(checkpoint_dir,
+                                     keep=cfg.keep_checkpoints)
+                        if checkpoint_dir else None)
+        start_env_steps, start_minutes = 0, 0.0
+        if (checkpointer is not None and resume
+                and checkpointer.latest_step() is not None):
+            from r2d2_tpu.checkpoint import check_arch_compat
 
-        check_arch_compat(cfg, checkpointer.peek_meta())
-        state, meta = checkpointer.restore(jax.device_get(state))
-        start_env_steps = int(meta.get("env_steps", 0))
-        start_minutes = float(meta.get("minutes", 0.0))
+            check_arch_compat(cfg, checkpointer.peek_meta())
+            state, meta = checkpointer.restore(jax.device_get(state))
+            start_env_steps = int(meta.get("env_steps", 0))
+            start_minutes = float(meta.get("minutes", 0.0))
 
     mesh = make_mesh(cfg) if use_mesh else None
     # ONE sharding table per bring-up: every sharding constructor (the
@@ -138,9 +149,10 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
     if cfg.device_replay and jax.process_count() == 1:
         from r2d2_tpu.replay.device_ring import DeviceRing
 
-        layout = _checked_ring_layout(cfg, action_dim, mesh)
-        ring = (DeviceRing(cfg, action_dim, table=table, layout=layout)
-                if mesh is not None else DeviceRing(cfg, action_dim))
+        with maybe_phase(setup, "setup.ring"):
+            layout = _checked_ring_layout(cfg, action_dim, mesh)
+            ring = (DeviceRing(cfg, action_dim, table=table, layout=layout)
+                    if mesh is not None else DeviceRing(cfg, action_dim))
     elif cfg.device_replay:
         # multi-host: each host owns the slot slabs of its dp groups — a
         # dp-layout ring over its LOCAL submesh.  The learner stitches the
@@ -171,9 +183,10 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
             # must push the whole pod to host staging, not deadlock it
             ok = sync_counter(int(shapes_ok and fits), reduce="min") > 0
             if ok:
-                ring = DeviceRing(cfg, action_dim,
-                                  table=ShardingTable(lmesh, cfg),
-                                  layout="dp")
+                with maybe_phase(setup, "setup.ring"):
+                    ring = DeviceRing(cfg, action_dim,
+                                      table=ShardingTable(lmesh, cfg),
+                                      layout="dp")
             else:
                 warnings.warn(
                     "multi-host device_replay disabled (on at least one "
@@ -197,8 +210,9 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
         # config validation already rejected device_replay/anakin here.
         from r2d2_tpu.parallel.replay_net import NetShardedReplayPlane
 
-        buffer = NetShardedReplayPlane(
-            cfg, action_dim, rng=np.random.default_rng(cfg.seed))
+        with maybe_phase(setup, "setup.ring"):
+            buffer = NetShardedReplayPlane(
+                cfg, action_dim, rng=np.random.default_rng(cfg.seed))
         replay_plane = buffer
     elif cfg.replay_shards > 1:
         # sharded replay plane (parallel/replay_shards.py): K owner
@@ -210,13 +224,15 @@ def _build(cfg: Config, env_factory: EnvFactory, use_mesh: bool,
         # device_replay here, so `ring` is None on this path.
         from r2d2_tpu.parallel.replay_shards import ShardedReplayPlane
 
-        buffer = ShardedReplayPlane(
-            cfg, action_dim, rng=np.random.default_rng(cfg.seed))
+        with maybe_phase(setup, "setup.ring"):
+            buffer = ShardedReplayPlane(
+                cfg, action_dim, rng=np.random.default_rng(cfg.seed))
         replay_plane = buffer
     else:
-        buffer = ReplayBuffer(cfg, action_dim,
-                              rng=np.random.default_rng(cfg.seed),
-                              device_ring=ring, tracer=tracer)
+        with maybe_phase(setup, "setup.ring"):
+            buffer = ReplayBuffer(cfg, action_dim,
+                                  rng=np.random.default_rng(cfg.seed),
+                                  device_ring=ring, tracer=tracer)
     buffer.env_steps = start_env_steps
     epsilons = [epsilon_ladder(i, cfg.num_actors, cfg.base_eps, cfg.eps_alpha)
                 for i in range(cfg.num_actors)]
@@ -693,12 +709,12 @@ def train_sync(cfg: Config, env_factory: EnvFactory = _default_env_factory,
 # anakin trainer: ONE compiled on-device program (learner/anakin.py)
 # --------------------------------------------------------------------------
 
-def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
+def _train_anakin(cfg: Config, tracer: Tracer, setup: SetupClock,
+                  checkpoint_dir: Optional[str] = None,
                   resume: bool = False, use_mesh: bool = False,
                   max_wall_seconds: Optional[float] = None,
                   verbose: bool = True,
                   log_sink: Optional[Callable[[Dict[str, Any]], None]] = None,
-                  tracer: Optional[Tracer] = None,
                   profile_dir: Optional[str] = None,
                   stop_fn: Optional[Callable[[], bool]] = None
                   ) -> Dict[str, Any]:
@@ -741,21 +757,23 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     # exactly as the in_graph_per drivetrain's (effective-config pattern)
     cfg = cfg.replace(device_replay=True, in_graph_per=True)
     action_dim = 4  # both anakin envs' action set (envs/anakin.py)
-    net = create_network(cfg, action_dim)
-    params = init_params(cfg, net, jax.random.PRNGKey(cfg.seed))
-    state = create_train_state(cfg, params)
-    del params      # the state holds copies; a large model's would not fit
-    checkpointer = (Checkpointer(checkpoint_dir, keep=cfg.keep_checkpoints)
-                    if checkpoint_dir else None)
-    start_env_steps, start_minutes = 0, 0.0
-    if (checkpointer is not None and resume
-            and checkpointer.latest_step() is not None):
-        from r2d2_tpu.checkpoint import check_arch_compat
+    with setup.phase("setup.state"):
+        net = create_network(cfg, action_dim)
+        params = init_params(cfg, net, jax.random.PRNGKey(cfg.seed))
+        state = create_train_state(cfg, params)
+        del params  # the state holds copies; a large model's would not fit
+        checkpointer = (Checkpointer(checkpoint_dir,
+                                     keep=cfg.keep_checkpoints)
+                        if checkpoint_dir else None)
+        start_env_steps, start_minutes = 0, 0.0
+        if (checkpointer is not None and resume
+                and checkpointer.latest_step() is not None):
+            from r2d2_tpu.checkpoint import check_arch_compat
 
-        check_arch_compat(cfg, checkpointer.peek_meta())
-        state, meta = checkpointer.restore(jax.device_get(state))
-        start_env_steps = int(meta.get("env_steps", 0))
-        start_minutes = float(meta.get("minutes", 0.0))
+            check_arch_compat(cfg, checkpointer.peek_meta())
+            state, meta = checkpointer.restore(jax.device_get(state))
+            start_env_steps = int(meta.get("env_steps", 0))
+            start_minutes = float(meta.get("minutes", 0.0))
 
     # multi-chip anakin (ROADMAP item 2): under --mesh the fused program
     # compiles through the ONE table-driven sharded entry point — lanes,
@@ -765,15 +783,16 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
     # single-device path is unchanged.
     mesh = make_mesh(cfg) if use_mesh else None
     table = None
-    layout = _checked_ring_layout(cfg, action_dim, mesh,
-                                  state_bytes=_tree_bytes(state))
-    if mesh is not None:
-        from r2d2_tpu.parallel.sharding import ShardingTable
+    with setup.phase("setup.ring"):
+        layout = _checked_ring_layout(cfg, action_dim, mesh,
+                                      state_bytes=_tree_bytes(state))
+        if mesh is not None:
+            from r2d2_tpu.parallel.sharding import ShardingTable
 
-        table = ShardingTable(mesh, cfg)
-        ring = DeviceRing(cfg, action_dim, table=table, layout=layout)
-    else:
-        ring = DeviceRing(cfg, action_dim)
+            table = ShardingTable(mesh, cfg)
+            ring = DeviceRing(cfg, action_dim, table=table, layout=layout)
+        else:
+            ring = DeviceRing(cfg, action_dim)
     # no ParamStore: the fused loop acts on the CURRENT params in-graph
     # and nothing else consumes published snapshots in this mode (no
     # fleets, pump, or inference service) — publishing would just run a
@@ -806,7 +825,6 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
                     "loop snapshot (different transport) — resuming with "
                     "a cold ring", stacklevel=2)
 
-    tracer = tracer or Tracer()
     scaffold = _HostScaffold(
         cfg, checkpoint_dir, max_wall_seconds=max_wall_seconds,
         signal_msg="draining the anakin loop, then saving full "
@@ -929,6 +947,7 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
         try:
             scaffold.start(loops)
             with device_profile(profile_dir):
+                setup.begin_fill()
                 metrics = run_anakin_loop(
                     learner, plane, stop=learner_stop, tracer=tracer,
                     snapshot_fn=(save_anakin_snapshot if want_full_save
@@ -988,6 +1007,32 @@ def _train_anakin(cfg: Config, checkpoint_dir: Optional[str] = None,
 # threaded fabric trainer (the reference's process topology, thread-native)
 # --------------------------------------------------------------------------
 
+def _timed_setup(trainer):
+    """``trainer`` with its set-up timed phase by phase
+    (``utils/trace.SetupClock``, docs/OBSERVABILITY.md "Set-up"): the
+    run's ``Tracer`` and the clock exist from the call's first line, the
+    clock's ``jax.monitoring`` listeners live until the first training
+    dispatch returns or, on every other way out (a configuration refused
+    in set-up included), until the ``finally`` here, and
+    ``metrics["setup"]`` is what the clock read."""
+
+    @functools.wraps(trainer)
+    def timed(cfg: Config, *args, tracer: Optional[Tracer] = None,
+              **kwargs) -> Dict[str, Any]:
+        tracer = tracer or Tracer()
+        setup = SetupClock(tracer)
+        try:
+            metrics = trainer(cfg, *args, tracer=tracer, _setup=setup,
+                              **kwargs)
+        finally:
+            setup.close()
+        metrics["setup"] = setup.seconds
+        return metrics
+
+    return timed
+
+
+@_timed_setup
 def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
           checkpoint_dir: Optional[str] = None, resume: bool = False,
           use_mesh: bool = False, max_wall_seconds: Optional[float] = None,
@@ -996,7 +1041,8 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
           tracer: Optional[Tracer] = None,
           profile_dir: Optional[str] = None,
           max_thread_restarts: int = 3,
-          stop_fn: Optional[Callable[[], bool]] = None) -> Dict[str, Any]:
+          stop_fn: Optional[Callable[[], bool]] = None,
+          _setup: Optional[SetupClock] = None) -> Dict[str, Any]:
     """The full concurrent system (reference train.py:20-44 equivalent).
 
     Threads and their reference analogues:
@@ -1043,6 +1089,10 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
     supervised fabric thread.  The in-memory ``metrics["logs"]`` list is
     a ``cfg.log_history_cap`` ring — the JSONL file is the durable
     record.
+
+    ``tracer`` and ``_setup`` come from :func:`_timed_setup` (a caller's
+    own ``tracer`` passes through); ``metrics["setup"]`` splits the run's
+    set-up into its phases and JAX's compile work.
     """
     if cfg.actor_transport == "anakin":
         # the Podracer fused on-device loop (learner/anakin.py): env,
@@ -1068,15 +1118,14 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                 "(the fused loop has its own on-device eval-lane "
                 "follow-on, ROADMAP item 2) — running without the eval "
                 "sidecar", stacklevel=2)
-        return _train_anakin(cfg, checkpoint_dir=checkpoint_dir,
+        return _train_anakin(cfg, tracer, _setup,
+                             checkpoint_dir=checkpoint_dir,
                              resume=resume, use_mesh=use_mesh,
                              max_wall_seconds=max_wall_seconds,
                              verbose=verbose, log_sink=log_sink,
-                             tracer=tracer, profile_dir=profile_dir,
-                             stop_fn=stop_fn)
-    tracer = tracer or Tracer()
+                             profile_dir=profile_dir, stop_fn=stop_fn)
     sys = _build(cfg, env_factory, use_mesh, checkpoint_dir, resume,
-                 tracer=tracer)
+                 tracer=tracer, setup=_setup)
     actors: List[VectorActor] = sys["actors"]
     buffer: ReplayBuffer = sys["buffer"]
     learner: Learner = sys["learner"]
@@ -1509,6 +1558,7 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                 sidecar.start()
             scaffold.start(loops)
             with device_profile(profile_dir):
+                _setup.begin_fill()
                 if sys["ring"] is not None:
                     metrics = learner.run_device(buffer, sys["ring"],
                                                  priority_sink,

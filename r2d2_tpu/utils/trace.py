@@ -22,6 +22,10 @@ the survey calls for:
 - :func:`maybe_span` / :func:`held` — the one "span or nothing" for call
   sites whose tracer is optional, and a ``with lock:`` whose wait is a
   span.
+- :class:`SetupClock` — ``train()``'s set-up as ``setup.*`` spans of its
+  tracer: host phases back to back, and JAX's own trace, lower and
+  compile events, kept by ``jax.monitoring`` listeners that live only
+  until the first training dispatch returns.
 - :class:`RetraceGuard` — compile-boundary discipline made checkable
   (Podracer, PAPERS.md): every jitted entry point wraps its Python
   function in :data:`RETRACES`.wrap(name, fn, budget), so each XLA trace
@@ -148,6 +152,8 @@ class Tracer:
 
             events = EVENTS
         self._event_sink = events
+        # the SetupClock timing this tracer's run until its first dispatch
+        self._setup: Optional[SetupClock] = None
 
     @contextlib.contextmanager
     def span(self, name: str, step: Optional[int] = None) -> Iterator[None]:
@@ -165,16 +171,27 @@ class Tracer:
             dt = time.perf_counter() - t0
             if annotations is not None:
                 annotations.close()
-            with self._lock:
-                stat = self._spans.get(name)
-                if stat is None:
-                    stat = self._spans[name] = _Stat()
-                stat.update(dt, self._alpha)
-            events = self._event_sink
-            if events is not None and events.armed:
-                # pass-through into the armed capture window; every
-                # call site above passes a literal name
-                events.complete(name, t0, dt)  # graftlint: disable=telemetry-discipline -- pass-through bridge; span() call sites pass literal names
+            self.record(name, t0, dt)
+
+    def record(self, name: str, t0: float, dt: float) -> None:
+        """A finished span: ``dt`` seconds from ``t0`` on the
+        ``perf_counter`` clock, taken into the statistics and handed to
+        the event sink exactly as :meth:`span` does with the body it
+        timed."""
+        with self._lock:
+            stat = self._spans.get(name)
+            if stat is None:
+                stat = self._spans[name] = _Stat()
+            stat.update(dt, self._alpha)
+        events = self._event_sink
+        if events is not None and events.armed:
+            # pass-through into the armed capture window; every call site
+            # of span() and record() passes a literal name
+            events.complete(name, t0, dt)  # graftlint: disable=telemetry-discipline -- pass-through bridge; span() call sites pass literal names
+        setup = self._setup
+        if setup is not None and name == "learner.step_dispatch":
+            # every drivetrain's first training dispatch ends set-up
+            setup.dispatched(t0, t0 + dt)
 
     def gauge(self, name: str, value: float) -> None:
         with self._lock:
@@ -223,6 +240,162 @@ def held(lock: Any, tracer: Optional[Tracer], wait_name: str
         yield
     finally:
         lock.release()
+
+
+# JAX's own compile events (jax._src.dispatch, jax._src.compiler) as
+# jax.monitoring hands them to a listener, and the set-up span that each
+# kind becomes; start and end come on time.time()'s clock
+_JAX_COMPILE_SPANS = (
+    ("/jax/core/compile/jaxpr_trace_duration", "setup.trace"),
+    ("/jax/core/compile/jaxpr_to_mlir_module_duration", "setup.lower"),
+    ("/jax/core/compile/backend_compile_duration", "setup.compile"),
+)
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_COMPILE = _JAX_COMPILE_SPANS[2][0]
+
+
+def union_seconds(intervals) -> float:
+    """Length of the union of ``(start, end)`` intervals.  A jit traced
+    inside another's trace records its own event inside the outer one,
+    so a plain sum of the events would count it twice."""
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+class SetupClock:
+    """``train()``'s set-up, phase by phase, as spans of the run's
+    :class:`Tracer` (docs/OBSERVABILITY.md, "Set-up").
+
+    A phase is a host interval: ``perf_counter`` read at its two edges,
+    never a wait for the device, so the device work a phase starts (the
+    ring's upload, ``init_params``' programs) runs on beside the next one
+    as it does without the clock.  ``setup.state`` and ``setup.ring`` are
+    the bodies of :meth:`phase`; ``setup.drivetrain`` runs from the end of
+    the last phase to :meth:`begin_fill` (the trainer, as it enters the
+    drivetrain), ``setup.fill`` from there to the start of the run's
+    first ``learner.step_dispatch`` span, ``setup.first_dispatch`` is that
+    span, and ``setup.train`` runs from the clock's construction to the
+    span's end.  The tracer hands the span over as it records it
+    (:meth:`Tracer.record`), so every drivetrain ends set-up by the span
+    it already has.
+
+    From construction until :meth:`close`, one ``jax.monitoring``
+    time-span listener and one duration listener keep JAX's own compile
+    events; the first dispatch closes the clock, the trainer's ``finally``
+    every other way out.  Each kind becomes one span: ``setup.trace``,
+    ``setup.lower`` and ``setup.compile`` the union of their events'
+    intervals, ``setup.cache_load`` the sum of the persistent cache's
+    loads.  They overlap the phases by design: a second view of the same
+    interval.  Every span is recorded when set-up ends, with the start
+    and the length it had; :attr:`seconds` is ``metrics["setup"]``."""
+
+    def __init__(self, tracer: Tracer):
+        from jax import monitoring
+
+        self._tracer = tracer
+        self._lock = threading.Lock()
+        self._events: List[Tuple[str, float, float]] = []
+        self._phases: Dict[str, List[float]] = {}   # name -> [t0, seconds]
+        self._fill_t0: Optional[float] = None
+        self.seconds: Dict[str, float] = {}
+        self.t0 = self._last_end = time.perf_counter()
+        self._wall_to_perf = self.t0 - time.time()
+        self._listening = True
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        tracer._setup = self
+
+    def _on_span(self, event: str, start: float, end: float,
+                 **kwargs: Any) -> None:
+        with self._lock:
+            self._events.append((event, start, end))
+
+    def _on_duration(self, event: str, duration: float,
+                     **kwargs: Any) -> None:
+        if event == _JAX_CACHE_LOAD:
+            end = time.time()
+            with self._lock:
+                self._events.append((event, end - duration, end))
+
+    @contextlib.contextmanager
+    def phase(self, name: str) -> Iterator[None]:
+        """Time the body as phase ``name``.  A phase entered twice (the
+        fabric builds its ``Learner`` between the ring and the buffer)
+        keeps its first start and adds up the seconds."""
+        t0 = time.perf_counter()
+        yield
+        self._last_end = time.perf_counter()
+        got = self._phases.setdefault(name, [t0, 0.0])
+        got[1] += self._last_end - t0
+
+    def begin_fill(self) -> None:
+        """The trainer enters its drivetrain: ``setup.drivetrain`` ends,
+        ``setup.fill`` begins."""
+        self._fill_t0 = time.perf_counter()
+        self._phases["setup.drivetrain"] = [
+            self._last_end, self._fill_t0 - self._last_end]
+
+    def dispatched(self, start: float, end: float) -> None:
+        """The first training dispatch ran from ``start`` to ``end``:
+        set-up is over.  Closes the clock and records every span."""
+        if not self.close():
+            return
+        fill_t0 = start if self._fill_t0 is None else self._fill_t0
+        self._phases["setup.fill"] = [fill_t0, start - fill_t0]
+        self._phases["setup.first_dispatch"] = [start, end - start]
+        self._phases["setup.train"] = [self.t0, end - self.t0]
+        with self._lock:
+            events = list(self._events)
+        spans = dict(self._phases)
+        for event, name in _JAX_COMPILE_SPANS:
+            got = [(s, e) for ev, s, e in events if ev == event]
+            spans[name] = [self._first_start(got), union_seconds(got)]
+        loads = [(s, e) for ev, s, e in events if ev == _JAX_CACHE_LOAD]
+        spans["setup.cache_load"] = [self._first_start(loads),
+                                     sum((e - s for s, e in loads), 0.0)]
+        for name, (t0, dt) in spans.items():
+            self._tracer.record(name, t0, dt)
+            self.seconds[name[len("setup."):] + "_s"] = dt
+        # a program loaded from the persistent cache is a backend compile
+        # with a cache load inside it
+        compiles = [(s, e) for ev, s, e in events if ev == _BACKEND_COMPILE]
+        load_ends = sorted(e for _, e in loads)
+        loaded = sum(bisect.bisect_right(load_ends, e)
+                     > bisect.bisect_left(load_ends, s) for s, e in compiles)
+        self.seconds.update(programs_compiled=len(compiles) - loaded,
+                            programs_loaded=loaded)
+
+    def _first_start(self, intervals) -> float:
+        """The earliest start on the ``perf_counter`` clock (the clock's
+        own start where there is none)."""
+        if not intervals:
+            return self.t0
+        return min(s for s, _ in intervals) + self._wall_to_perf
+
+    def close(self) -> bool:
+        """Unregister the listeners and leave the tracer; False where the
+        clock was closed already."""
+        with self._lock:
+            if not self._listening:
+                return False
+            self._listening = False
+        from jax import monitoring
+
+        self._tracer._setup = None
+        monitoring.unregister_event_time_span_listener(self._on_span)
+        monitoring.unregister_event_duration_listener(self._on_duration)
+        return True
+
+
+def maybe_phase(clock: Optional[SetupClock], name: str):
+    """``clock.phase(name)``, or nothing where set-up is not timed."""
+    if clock is None:
+        return _NO_SPAN
+    return clock.phase(name)
 
 
 class RetraceBudgetExceeded(AssertionError):
