@@ -39,6 +39,7 @@ from flax import linen as nn
 
 from r2d2_tpu.config import Config
 from r2d2_tpu.models.state import state_spec, zero_state  # noqa: F401
+from r2d2_tpu.ops import pool
 
 
 def _dtype(name: str):
@@ -95,7 +96,17 @@ class NatureTorso(nn.Module):
 
 
 class ImpalaTorso(nn.Module):
-    """IMPALA deep residual CNN (BASELINE configs[4] scaled-model stress)."""
+    """IMPALA deep residual CNN (BASELINE configs[4] scaled-model stress).
+
+    Each stage pools its first convolution's output through
+    ``ops/pool.max_pool``: the values are ``nn.max_pool``'s, and where the
+    pool is differentiated on a TPU its forward stores which of a window's
+    nine positions won, one byte a pooled element, and its backward routes
+    the cotangent by that index alone.  XLA's own gradient kept every
+    pre-pool activation (1.73 GB at the first stage of the IMPALA cell)
+    for a ``select-and-scatter`` that read it again.  The kernels work on
+    the ``{0,3,2,1}`` layout the compiler gives these activations (frames
+    minor), as its row-major ``[H, W, C, N]`` view (PERF.md, Findings)."""
     out_dim: int
     compute_dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -108,7 +119,7 @@ class ImpalaTorso(nn.Module):
                   param_dtype=self.param_dtype)
         for ch in self.channels:
             x = nn.Conv(ch, (3, 3), **kw)(x)
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+            x = pool.max_pool(x)
             for _ in range(self.blocks_per_stage):
                 skip = x
                 x = nn.Conv(ch, (3, 3), **kw)(nn.relu(x))

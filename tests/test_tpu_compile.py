@@ -1,9 +1,10 @@
 """The flagship cell's super-step, the ``xing4`` core's expert layer, its
-blocks' residual streams and the fused cells' rollout, compiled for a
-described (not attached) v5e: what only the TPU compiler decides about the
-device ring, about the optimizer's passes over the weights' state, about
-the routed rows' buffers, about the passes over the streams and about the
-fused loop's lane buffers, checked without a chip.
+blocks' residual streams, the IMPALA torso's gradient and the fused cells'
+rollout, compiled for a described (not attached) v5e: what only the TPU
+compiler decides about the device ring, about the optimizer's passes over
+the weights' state, about the routed rows' buffers, about the passes over
+the streams, about the max-pool's layouts and about the fused loop's lane
+buffers, checked without a chip.
 
 The compiler has twice chosen a layout for the frame ring under which the
 super-step copies all of it on every dispatch (36 % of device time at a
@@ -381,6 +382,123 @@ def test_a_block_passes_over_its_streams_once_a_sublayer(
     # scope, as the expressions' ``multiply_reduce_fusion`` did
     assert not [name for name, result, _, path in ops
                 if wide in result and path.endswith("reduce_sum")]
+
+
+# ------------------------------------------------- the IMPALA torso's pools
+
+def _pool_shapes(cfg):
+    """The pre-pool and pooled shapes of the IMPALA torso's three stages,
+    ``[N, H, W, C]`` and the kernels' ``[H, W, C, N]`` view of each, as the
+    compiled text writes them (``bf16[7680,84,84,16]``, ...)."""
+    from r2d2_tpu.ops import pool
+
+    n, size, shapes = cfg.batch_size * cfg.seq_len, cfg.obs_shape[0], []
+    for c in (16, 32, 32):
+        for h in (size, pool.geometry(size)[0]):
+            shapes += [f"{n},{h},{h},{c}", f"{h},{h},{c},{n}"]
+        size = pool.geometry(size)[0]
+    return shapes
+
+
+def _lowered_on_tpu(build, *args):
+    """``build().lower(*args)`` with the program asking the backend, as the
+    chip's would, which paths it takes (the pools' kernels; the learner's
+    scan network beside acting's Pallas LSTM): here the backend is the
+    CPU."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return build().lower(*args)
+
+
+def assert_the_pools_keep_an_index_in_the_step_layout(text, cfg):
+    """The max-pool's backward is the kernels' (ops/pool.py), not XLA's
+    ``select-and-scatter`` over the pre-pool activations; the kernels read
+    and write the step's own ``{0,3,2,1}`` arrays as their ``[H, W, C, N]``
+    view, so no ``copy`` or ``transpose`` of a pool's array stands beside
+    them; and each pre-pool activation is read by its forward kernel alone
+    — nothing keeps it for the backward (PERF.md, Findings)."""
+    assert "select-and-scatter(" not in text
+    comps, _ = _computations(text)
+    ops = [op for lines in comps.values() for op in _operations(lines)]
+    by_name = {op[0]: op for op in ops}
+    calls = {kind: [op for op in ops if op[0].startswith(kind + ".")
+                    and op[2] == "custom-call"]
+             for kind in ("max_pool_forward", "max_pool_backward")}
+    assert [len(c) for c in calls.values()] == [3, 3]
+    shapes = _pool_shapes(cfg)
+    relaid = [(name, result) for name, result, kind, _, _ in ops
+              if kind in ("copy", "transpose")
+              and any(f"[{s}]" in result for s in shapes)]
+    assert relaid == []
+    for name, result, _, operands, _ in calls["max_pool_forward"]:
+        (pre,) = operands
+        while by_name[pre][2] == "bitcast":
+            pre = by_name[pre][3][0]
+        readers = [op[0] for op in ops if pre in op[3]]
+        assert by_name[pre][2] == "fusion" and readers == [name], \
+            (name, pre, readers)
+
+
+@pytest.fixture(scope="module")
+def impala_cell(one_chip):
+    from benchmark.drivers.train import build_config
+    from benchmark.manifest import Manifest
+
+    return build_config(Manifest().cell("impala_deep_lstm2.anakin"), False)
+
+
+def test_the_impala_torso_pools_by_the_stored_index(one_chip, impala_cell):
+    """The gradient of the torso alone at the cell's 7,680 frames (~25 s):
+    where the step spent 10.9 ms an update in two ``select-and-scatter``
+    ops over the pre-pool tensors (PERF.md, Findings), it holds the three
+    pools' forward and backward kernels, on the step's own layout."""
+    from r2d2_tpu.models.network import ImpalaTorso
+
+    _, sharding = one_chip
+    cfg = impala_cell
+    n = cfg.batch_size * cfg.seq_len
+    torso = ImpalaTorso(out_dim=cfg.hidden_dim, compute_dtype=jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda a: sds(a.shape), jax.eval_shape(
+        lambda k: torso.init(k, jnp.zeros((1, *cfg.obs_shape))),
+        jax.random.PRNGKey(0)))
+
+    def gradient(p, x, g):
+        return jax.grad(lambda p: jnp.sum(
+            torso.apply(p, x).astype(jnp.float32) * g))(p)
+
+    text = compile_uncached(_lowered_on_tpu(
+        lambda: jax.jit(gradient), params, sds((n, *cfg.obs_shape)),
+        sds((n, cfg.hidden_dim)))).as_text()
+    assert_the_pools_keep_an_index_in_the_step_layout(text, cfg)
+
+
+@pytest.mark.slow
+def test_the_impala_fused_step_pools_by_the_stored_index(one_chip,
+                                                         impala_cell):
+    """The same of ``impala_deep_lstm2.anakin``'s whole super-step (~60 s):
+    the online pass's three pools take the kernels; the target network's
+    forward and acting, which nothing differentiates, keep
+    ``nn.max_pool``'s ``reduce-window`` and store no index."""
+    from benchmark.drivers.train import ACTION_DIM
+    from r2d2_tpu.learner.anakin import make_anakin_super_step
+    from r2d2_tpu.learner.step import create_train_state
+
+    _, sharding = one_chip
+    cfg, net, env, (params, *loop) = _fused_cell(
+        one_chip, "impala_deep_lstm2.anakin")
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda p: create_train_state(cfg, p), params))
+    text = compile_uncached(_lowered_on_tpu(
+        lambda: make_anakin_super_step(cfg, net, env, ACTION_DIM),
+        state, *loop,
+        jax.ShapeDtypeStruct((), jnp.uint32, sharding=sharding))).as_text()
+    assert_the_pools_keep_an_index_in_the_step_layout(text, cfg)
+    assert len(re.findall(r"= bf16\[\S+ reduce-window\(", text)) >= 3
 
 
 # ------------------------------------------------ the fused loop's lane buffers
