@@ -18,6 +18,10 @@ from typing import Any, Dict, List, Optional, Tuple
 # the r8-era "mp" axis folded into "tp" with the sharding table
 MESH_AXES = ("dp", "fsdp", "tp")
 
+# core="ouro": the attention heads (and key/value heads) a published layer
+# has, config.json's num_attention_heads; a run holds up to all of them
+OURO_HEADS = 16
+
 
 def validate_mesh_shape(mesh_shape) -> dict:
     """The single mesh-axis rule set (axis names, duplicates, sizes),
@@ -297,7 +301,12 @@ class Config:
     # layer over chips (the router keeps its core_experts outputs either
     # way).  olmo_hybrid reads core_dim, core_layers, core_context,
     # core_heads_held, core_dense_dim (its feed-forward) and its own
-    # three head sizes
+    # three head sizes.  "ouro" (models/ouro.py: core_layers layers of
+    # softmax attention and a SwiGLU feed-forward, run core_loops times
+    # with the same weights, over stored keys and values a loop and a
+    # layer) reads core_dim, core_layers, core_context, core_heads_held,
+    # core_head_dim, core_rope_theta, core_dense_dim and core_loops;
+    # ouro_core_config sets its published sizes
     core: str = "lstm"
     core_dim: int = 3584            # width of the residual streams
     core_layers: int = 40           # blocks, the leading dense ones included
@@ -323,6 +332,7 @@ class Config:
     core_head_dim: int = 128        # olmo_hybrid: a softmax head
     core_linear_key_dim: int = 96   # olmo_hybrid: a delta-rule head's keys
     core_linear_value_dim: int = 192  # ... and its values
+    core_loops: int = 4             # ouro: runs of the stack (total_ut_steps)
 
     # --- evaluation -------------------------------------------------------
     test_epsilon: float = 0.001  # reference: config.py:37
@@ -1022,8 +1032,16 @@ class Config:
             raise ValueError(f"unknown torso {self.torso!r}")
         if self.lstm_layers < 1:
             raise ValueError("lstm_layers must be >= 1")
-        if self.core not in ("lstm", "xing4", "olmo_hybrid"):
+        if self.core not in ("lstm", "xing4", "olmo_hybrid", "ouro"):
             raise ValueError(f"unknown core {self.core!r}")
+        if self.core == "ouro":
+            if self.core_loops < 1:
+                raise ValueError("core='ouro' runs its layers core_loops "
+                                 "times: core_loops must be >= 1")
+            if not 1 <= self.core_heads_held <= OURO_HEADS:
+                raise ValueError(
+                    "core='ouro': core_heads_held must lie in [1, "
+                    f"{OURO_HEADS}], the heads a published layer has")
         if self.core == "olmo_hybrid" and self.core_layers % 4:
             raise ValueError(
                 "core='olmo_hybrid' takes whole periods of its layer_types "
@@ -1047,8 +1065,9 @@ class Config:
             # paths that would carry one wide state a lane, a session or
             # a sequence over a wire sized for the LSTM's 4 kB state
             # (xing4's latent cache is 369 kB in bfloat16 at the published
-            # widths, olmo_hybrid's matrices and rows 602 kB): not built,
-            # refused by name (ROADMAP Queue 2)
+            # widths, olmo_hybrid's matrices and rows 602 kB, ouro's caches
+            # of four loops 2 MiB): not built, refused by name (ROADMAP
+            # Queue 2)
             if self.actor_transport == "process":
                 raise ValueError(
                     f"core={self.core!r} does not run under "
@@ -1319,6 +1338,26 @@ def olmo_hybrid_core_config(game: str = "MsPacman", **kw) -> Config:
                 core="olmo_hybrid", remat=True,
                 core_dim=3840, core_layers=32, core_heads_held=30,
                 core_dense_dim=11008)
+    base.update(kw)
+    return Config(**base)
+
+
+def ouro_core_config(game: str = "MsPacman", **kw) -> Config:
+    """R2D2 with the looped layers of Ouro-2.6B
+    (huggingface.co/ByteDance/Ouro-2.6B, ``config.json``) as its memory
+    core, at the published sizes: 48 layers of width 2,048, each softmax
+    attention of 16 heads of 128 (as many key and value heads) under RoPE
+    of theta 1,000,000 and a SwiGLU feed-forward of 5,632, the whole stack
+    run ``total_ut_steps`` = 4 times with the same weights.  Torso, heads,
+    windows and optimizer are R2D2's (``pong_config``).  Whole, it fits
+    no chip: a run states its share (``core_layers``)."""
+    base = dict(game_name=game, num_actors=64,
+                device_replay=True, in_graph_per=True,
+                superstep_k=4, superstep_pipeline=2,
+                core="ouro", remat=True,
+                core_dim=2048, core_layers=48, core_heads_held=OURO_HEADS,
+                core_head_dim=128, core_dense_dim=5632,
+                core_rope_theta=1e6, core_loops=4)
     base.update(kw)
     return Config(**base)
 

@@ -25,8 +25,9 @@ owns (models/state.py, :func:`state_spec`) and every other module derives — fo
 ``(2, layers, H)`` float32 where axis 0 is (h, c); for ``core="xing4"``
 (models/xing4.py) the latent cache ``(layers, W, latent)`` in the compute
 dtype; for ``core="olmo_hybrid"`` (models/olmo_hybrid.py) a flat vector of
-delta-rule matrices, convolution tails and stored keys and values.  Zeros
-are the initial state of all three.
+delta-rule matrices, convolution tails and stored keys and values; for
+``core="ouro"`` (models/ouro.py) the stored keys and values of every loop
+and layer.  Zeros are the initial state of all four.
 """
 from __future__ import annotations
 
@@ -48,11 +49,13 @@ def _dtype(name: str):
 
 # The memory cores that are modules of their own, by ``cfg.core``: each
 # exports ``Core`` (a flax module ``(feats, hidden) -> (outs, hidden')``
-# built from ``cfg, compute_dtype, param_dtype``), ``COUNTERS`` and
-# ``step_buffers`` (the hooks at the end of this file).  The LSTM stack
-# lives here and has none of the three.
+# built from ``cfg, compute_dtype, param_dtype``), ``COUNTERS`` (or
+# ``counter_names(cfg)`` where their number follows the configuration)
+# and ``step_buffers`` (the hooks at the end of this file).  The LSTM
+# stack lives here and has none of them.
 _CORE_MODULES = {"xing4": "r2d2_tpu.models.xing4",
-                 "olmo_hybrid": "r2d2_tpu.models.olmo_hybrid"}
+                 "olmo_hybrid": "r2d2_tpu.models.olmo_hybrid",
+                 "ouro": "r2d2_tpu.models.ouro"}
 
 
 def core_module(cfg: Config):
@@ -395,7 +398,11 @@ def counter_names(cfg: Config) -> tuple:
     """Names of the float32 scalars the model's train step leaves in its
     buffers for the host to log, in :func:`read_counters`' order."""
     module = core_module(cfg)
-    return () if module is None else module.COUNTERS
+    if module is None:
+        return ()
+    if hasattr(module, "counter_names"):
+        return module.counter_names(cfg)
+    return module.COUNTERS
 
 
 def read_counters(cfg: Config, variables) -> jnp.ndarray:

@@ -24,6 +24,14 @@ One state is one array whose zeros are the initial state:
   lays out blocks-minor — and either way copies the ring whole at every
   dispatch's start and end (1.2-1.7 GB; tests/test_tpu_compile.py
   compiles the step for a described v5e).
+- ``core="ouro"`` (models/ouro.py): the stored keys and values of every
+  loop and layer ``(loops, layers, W, 2, heads, head_dim)`` in the compute
+  dtype — for each of the core's layer applications the W most recent
+  steps' (unrotated key, value).  Heads and head size are the minor pair,
+  at the published widths one whole bfloat16 tile (16 x 128): kept as one
+  minor axis of 4,096, a 2 MiB state made the chip's compiler split the
+  batch's gather from the ring into four, each behind a slice of the
+  whole ring of states (4 GB copied an update; PERF.md, Findings).
 
 A per-step **stream** from which the state at a step can be cut (the fused
 loop keeps one a lane, learner/anakin.py) has two parts,
@@ -197,7 +205,40 @@ class _OlmoHybrid:
             axis=1)
 
 
-_KINDS = {"lstm": _Lstm, "xing4": _Xing4, "olmo_hybrid": _OlmoHybrid}
+class _Ouro:
+    """A state is a window of rows as ``xing4``'s is, but a row of every
+    loop and layer is 128 KiB at the published widths (64 lanes x 456
+    steps of them: 3.8 GB, and the chip's compiler refuses the step), so
+    the whole state is the snapshot part, kept only at the steps a stored
+    sequence can start (64 lanes x 12 slots x 2 MiB: 1.6 GB), and an
+    entry of the rows holds no value."""
+
+    @staticmethod
+    def state(cfg):
+        return ((cfg.core_loops, cfg.core_layers, cfg.core_context, 2,
+                 cfg.core_heads_held, cfg.core_head_dim),
+                _DTYPES[cfg.compute_dtype])
+
+    @classmethod
+    def stream(cls, cfg):
+        shape, dtype = cls.state(cfg)
+        return StreamSpec(0, (0,), dtype, shape)
+
+    @staticmethod
+    def entry(cfg, state):
+        return state.reshape(state.shape[0], -1)[:, :0]
+
+    @staticmethod
+    def snapshot(cfg, state):
+        return state
+
+    @staticmethod
+    def states(cfg, stream, idx, snapshots):
+        return snapshots
+
+
+_KINDS = {"lstm": _Lstm, "xing4": _Xing4, "olmo_hybrid": _OlmoHybrid,
+          "ouro": _Ouro}
 
 
 def state_spec(cfg: Config) -> Tuple[Tuple[int, ...], np.dtype]:

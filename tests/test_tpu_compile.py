@@ -562,7 +562,8 @@ def _fused_cell(one_chip, cell):
 
 @pytest.mark.parametrize("cell", ["nature_xing4_l5e8h4.anakin",
                                   "impala_deep_lstm2.anakin",
-                                  "nature_olmohybrid_l4h4.anakin"])
+                                  "nature_olmohybrid_l4h4.anakin",
+                                  "nature_ouro_l4u4.anakin"])
 def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
     """A cut writes only the rows it keeps and a reset follows its cut's
     read, both in place (learner/anakin._retain_prefix, ``_done_cut``):
@@ -570,8 +571,10 @@ def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
     the frame stream's shape — 186 MB and 199 MB a piece in the ``xing4``
     cell, 2.96 % of its device time before PR 31 (PERF.md Findings) —
     stands in the env-step loop's body or in the identity branch of a
-    cut's conditional; nor, in the ``olmo_hybrid`` cell, one of the
-    snapshot slots' (331 MB).  (~30 s, ~12 s and ~40 s.)"""
+    cut's conditional; nor, in the ``olmo_hybrid`` and ``ouro`` cells, one
+    of the snapshot slots' (331 MB and 1.61 GB; the ``ouro`` state is
+    snapshots alone, and its rows hold no value).  (~30 s, ~12 s, ~40 s
+    and ~30 s.)"""
     from benchmark.drivers.train import ACTION_DIM
     from r2d2_tpu.learner.anakin import make_anakin_rollout
 
@@ -585,7 +588,9 @@ def test_no_env_step_copies_a_lane_buffer(one_chip, cell):
              for line in body]
     copies = []
     for buf in ("buf_hidden", "buf_obs", "buf_snapshot"):
-        if buf not in ast:          # only a state with a part kept whole
+        # only a state with a part kept whole has snapshots, and only one
+        # with a window of rows has rows of any size
+        if buf not in ast or not ast[buf].size:
             continue
         shape = "{}[{}]".format(names[ast[buf].dtype.name],
                                 ",".join(map(str, ast[buf].shape)))
@@ -640,3 +645,56 @@ def test_the_olmo_hybrid_fused_step_holds_within_the_chip(one_chip):
         assert not re.findall(rf"= {re.escape(shape)}\S* copy\(", text), name
     assert_the_target_sync_copies_only_when_it_syncs(text)
     assert memory.temp_size_in_bytes <= 5_985_007_616
+
+
+@pytest.mark.slow
+def test_the_ouro_fused_step_holds_within_the_chip(one_chip):
+    """``nature_ouro_l4u4.anakin``'s whole super-step at the ring its file
+    names compiles for the described v5e, with neither the ring of stored
+    states (192 blocks of 10 states of 2 MiB) nor the lanes' snapshot
+    slots (64 lanes of 12) copied, whole or in pieces: nothing but a
+    parameter and the loops' own tuples has their shapes, and the batch's
+    gather reads its 64 states alone.  Kept as a window of rows a step instead
+    (128 KiB a step of every loop and layer), the lanes' streams alone
+    are 3.8 GB and the compiler refuses the step by 15 MB; at 320 blocks
+    it refuses this one by 463 MB (PERF.md, Findings).  The four
+    loops run the four stacked layers through one lowering, so the step
+    holds no more than the stack's weights, target and moments.  (~60 s
+    on every core of the host: ``slow``, as the ``olmo_hybrid`` cell's.)"""
+    from benchmark.drivers.train import ACTION_DIM
+    from r2d2_tpu.learner.anakin import make_anakin_super_step
+    from r2d2_tpu.learner.step import create_train_state
+
+    _, sharding = one_chip
+    cfg, net, env, (params, *loop) = _fused_cell(
+        one_chip, "nature_ouro_l4u4.anakin")
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        jax.eval_shape(lambda p: create_train_state(cfg, p), params))
+    compiled = compile_uncached(make_anakin_super_step(
+        cfg, net, env, ACTION_DIM).lower(
+            state, *loop, jax.ShapeDtypeStruct(
+                (), jnp.uint32, sharding=sharding)))
+    memory = compiled.memory_analysis()
+    assert cfg.num_blocks == 192
+    # weights, target, moments (3.4 GB), the ring's frames and states
+    # (0.61 + 4.03 GB) and the lanes' snapshots (1.61 GB): 9.28 GiB of
+    # arguments, every byte of which the step hands back in place
+    assert 9.1 * 2 ** 30 < memory.argument_size_in_bytes < 9.5 * 2 ** 30
+    assert memory.alias_size_in_bytes > 0.999 * memory.argument_size_in_bytes
+    text = compiled.as_text()
+    for name, arr in (("hidden", loop[1]["hidden"]),
+                      ("buf_snapshot", loop[0]["buf_snapshot"])):
+        shape = "bf16[{}]".format(",".join(map(str, arr.shape)))
+        assert shape in text, (name, shape)
+        assert not re.findall(rf"= {re.escape(shape)}\S* copy\(", text), name
+        # nor a piece of either: with heads and head size folded into one
+        # minor axis of 4,096 the compiler split the batch's gather into
+        # four, each behind a slice of the whole ring of states
+        # (`mini-gather-slice`, 4 GB an update on the chip)
+        lead = "bf16[{},{},".format(*arr.shape[:2])
+        pieces = {m for m in re.findall(rf"{re.escape(lead)}[\d,]*\]", text)
+                  if m != shape}
+        assert pieces == set(), (name, pieces)
+    assert "mini-gather" not in text
+    assert_the_target_sync_copies_only_when_it_syncs(text)

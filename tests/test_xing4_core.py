@@ -70,9 +70,16 @@ def olmo_tiny_cfg(**kw):
     return make(**kw)
 
 
+def ouro_tiny_cfg(**kw):
+    from test_ouro_core import tiny_cfg as make
+
+    return make(**kw)
+
+
 # the cores that are modules of their own, at the tests' widths: what the
 # program's paths do with a state that is not the LSTM's
-TINY_CFGS = {"xing4": tiny_cfg, "olmo_hybrid": olmo_tiny_cfg}
+TINY_CFGS = {"xing4": tiny_cfg, "olmo_hybrid": olmo_tiny_cfg,
+             "ouro": ouro_tiny_cfg}
 
 
 def shaken(tree, seed, scale=0.1):
@@ -521,7 +528,7 @@ def env_factory(cfg, seed):
 
 
 @pytest.mark.parametrize("drivetrain", ["thread_fabric", "fused_loop"])
-@pytest.mark.parametrize("core", ["lstm", "xing4", "olmo_hybrid"])
+@pytest.mark.parametrize("core", ["lstm", "xing4", "olmo_hybrid", "ouro"])
 def test_each_core_trains_through_the_fabric_and_the_fused_loop(
         core, drivetrain):
     """A few updates end to end: host thread actors cutting blocks into the
@@ -565,6 +572,15 @@ def test_each_core_trains_through_the_fabric_and_the_fused_loop(
         assert 0 < last["core"]["beta_mean"] < 2
         for name in olmo_hybrid.COUNTERS:
             assert last["trace"]["gauge.core." + name] == last["core"][name]
+    if core == "ouro" and drivetrain == "fused_loop":
+        from r2d2_tpu.models import ouro
+
+        # loop_delta_rel of loops 1..3: later loops still move the stream
+        last = m["logs"][-1]
+        assert set(last["core"]) == set(ouro.counter_names(cfg))
+        for name, value in last["core"].items():
+            assert 0 < value < 10
+            assert last["trace"]["gauge.core." + name] == value
 
 
 def test_the_fused_loop_cuts_the_states_the_host_cutter_cuts():
@@ -635,8 +651,10 @@ def test_the_learner_asks_the_model_for_what_it_keeps_not_for_its_name():
             source = f.read()
         assert "xing4" not in source and "cfg.core" not in source, name
         assert "olmo_hybrid" not in source, name
+        assert "ouro" not in source, name
         assert "router_bias" not in source and "expert_load" not in source
         assert "linear_stats" not in source, name
+        assert "loop_deltas" not in source, name
 
 
 def test_the_cores_constants_are_the_sources():
@@ -663,7 +681,8 @@ def test_the_cores_constants_are_the_sources():
 
 @pytest.mark.parametrize("core,stored", [
     ("xing4", [[3, 4, 20], "float32"]),
-    ("olmo_hybrid", [[64, 128], "float32"])])
+    ("olmo_hybrid", [[64, 128], "float32"]),
+    ("ouro", [[4, 4, 4, 2, 2, 16], "float32"])])
 def test_checkpoint_restore_and_eval_with_a_cores_state(tmp_path, core,
                                                          stored):
     from r2d2_tpu.checkpoint import (
