@@ -32,7 +32,8 @@ and layer.  Zeros are the initial state of all four.
 from __future__ import annotations
 
 import importlib
-from typing import Any, Tuple
+import itertools
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +41,7 @@ from flax import linen as nn
 
 from r2d2_tpu.config import Config
 from r2d2_tpu.models.state import state_spec, zero_state  # noqa: F401
-from r2d2_tpu.ops import pool
+from r2d2_tpu.ops import stem
 
 
 def _dtype(name: str):
@@ -98,18 +99,42 @@ class NatureTorso(nn.Module):
         return x
 
 
+class ConvPool(nn.Module):
+    """A stage's first convolution (3 x 3, SAME) and the max-pool after it
+    (3 x 3, stride 2, SAME), through ``ops/stem.conv_pool``.  Its
+    parameters are ``nn.Conv``'s (``kernel``, ``bias``: the same shapes,
+    initializers and draws), so it takes the name the ``nn.Conv`` had."""
+    features: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (3, 3, x.shape[-1], self.features),
+                            self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,), self.param_dtype)
+        return stem.conv_pool(x, kernel, bias, self.dtype)
+
+
 class ImpalaTorso(nn.Module):
     """IMPALA deep residual CNN (BASELINE configs[4] scaled-model stress).
 
-    Each stage pools its first convolution's output through
-    ``ops/pool.max_pool``: the values are ``nn.max_pool``'s, and where the
-    pool is differentiated on a TPU its forward stores which of a window's
-    nine positions won, one byte a pooled element, and its backward routes
-    the cotangent by that index alone.  XLA's own gradient kept every
-    pre-pool activation (1.73 GB at the first stage of the IMPALA cell)
-    for a ``select-and-scatter`` that read it again.  The kernels work on
-    the ``{0,3,2,1}`` layout the compiler gives these activations (frames
-    minor), as its row-major ``[H, W, C, N]`` view (PERF.md, Findings)."""
+    Each stage opens with a convolution and a max-pool (:class:`ConvPool`).
+    Where the step is traced for a TPU, the first stage, whose convolution
+    has ONE input channel, is one pass of ``ops/stem.py``'s kernels: the
+    convolution's 84 x 84 x 16 output (1.73 GB at the IMPALA cell's 7,680
+    frames) lives in VMEM a few rows at a time, and the backward returns
+    the kernel's and bias's gradients from the pooled cotangent and the
+    stored window index, the frames being data.  The later stages' 16 and
+    32 input channels keep their convolutions on the matrix unit, and pool
+    through ``ops/pool.max_pool``: a forward kernel that stores which of a
+    window's nine positions won, one byte a pooled element, and a backward
+    that routes the cotangent by it.  Both work on the ``{0,3,2,1}`` layout
+    the compiler gives these activations (frames minor), as its row-major
+    ``[H, W, C, N]`` view (PERF.md, Findings).  Elsewhere (the CPU,
+    acting's 64 frames) it is ``nn.Conv`` and ``nn.max_pool``'s values."""
     out_dim: int
     compute_dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -120,13 +145,15 @@ class ImpalaTorso(nn.Module):
     def __call__(self, x):
         kw = dict(padding="SAME", dtype=self.compute_dtype,
                   param_dtype=self.param_dtype)
+        # the names the convolutions have always had: Conv_0, Conv_1, ...
+        names = (f"Conv_{i}" for i in itertools.count())
         for ch in self.channels:
-            x = nn.Conv(ch, (3, 3), **kw)(x)
-            x = pool.max_pool(x)
+            x = ConvPool(ch, dtype=self.compute_dtype,
+                         param_dtype=self.param_dtype, name=next(names))(x)
             for _ in range(self.blocks_per_stage):
                 skip = x
-                x = nn.Conv(ch, (3, 3), **kw)(nn.relu(x))
-                x = nn.Conv(ch, (3, 3), **kw)(nn.relu(x))
+                x = nn.Conv(ch, (3, 3), name=next(names), **kw)(nn.relu(x))
+                x = nn.Conv(ch, (3, 3), name=next(names), **kw)(nn.relu(x))
                 x = x + skip
         x = nn.relu(x)
         x = x.reshape(x.shape[0], -1)
@@ -344,6 +371,19 @@ def resolve_lstm_impl(cfg: Config) -> str:
     if cfg.lstm_impl != "auto":
         return cfg.lstm_impl
     return "pallas" if jax.default_backend() == "tpu" else "scan"
+
+
+def stem_fused(cfg: Config) -> Optional[float]:
+    """The share of the train step's stem passes (the online forward, its
+    backward, the target's forward) that take ``ops/stem.py``'s kernels,
+    as the step decides when it is traced: all three see the batch's
+    ``batch_size * seq_len`` frames, so it is 1 or 0.  None where the torso
+    has no stem (every torso but ``impala``)."""
+    if cfg.torso != "impala":
+        return None
+    frames = cfg.batch_size * cfg.seq_len
+    return float(stem.engages((frames, *cfg.stored_obs_shape),
+                              ImpalaTorso.channels[0]))
 
 
 def create_network(cfg: Config, action_dim: int) -> R2D2Network:

@@ -2,11 +2,18 @@
 a backward that reads the pooled cotangent and the window index the
 forward stored, never the activation that was pooled.
 
+Which stage takes what: on a TPU the second and third stages, whose
+convolutions have 16 and 32 input channels and run on the matrix unit,
+pool through :func:`max_pool`'s kernels; the first stage, whose
+convolution has one input channel, is ops/stem.py's one pass, which
+computes the convolution in VMEM and pools it with :func:`pool_rows`, this
+module's forward step, so the 1.73 GB it would pool is never written.
+
 Why an index.  Differentiated through ``nn.max_pool``, XLA keeps each
 pre-pool activation alive until the backward and hands it to a
 ``select-and-scatter``, which reads it again beside the cotangent to find
 each window's maximum a second time: at the IMPALA cell's first stage
-(7,680 frames of 84 x 84 x 16 in bfloat16) that is 1.73 GB kept and
+(7,680 frames of 84 x 84 x 16 in bfloat16) that was 1.73 GB kept and
 3.90 GB moved to route 0.43 GB of cotangent (PERF.md, Findings).
 What the backward needs of a window is only which of its nine positions
 won: :func:`forward` records it, one byte a pooled element, and
@@ -97,12 +104,12 @@ def _pool_bwd(hw, interpret, index, g):
 _pool.defvjp(_pool_fwd, _pool_bwd)
 
 
-def _to_view(a):
+def to_view(a):
     """``[N, H, W, C]`` -> the kernels' ``[H, W, C, N]``."""
     return jnp.transpose(a, (1, 2, 3, 0))
 
 
-def _from_view(a):
+def from_view(a):
     return jnp.transpose(a, (3, 0, 1, 2))
 
 
@@ -114,35 +121,45 @@ def _columns(width: int, out: int, lo: int, pc: int):
     return first, last
 
 
-def _call(kernel, grid, ins, in_specs, outs, out_specs, scratch, *, name,
-          interpret):
+def call(kernel, grid, ins, in_specs, outs, out_specs, scratch, *, name,
+         interpret, flops=0, vmem_limit=None):
     """``pallas_call`` over (programs of frames, steps down the rows): the
-    steps carry rows in VMEM, so they run in order."""
+    steps carry rows in VMEM, so they run in order.  ``vmem_limit``, in
+    bytes, raises the compiler's default for a kernel that holds more."""
     nbytes = sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
                  for a in list(ins) + list(outs))
     params = {} if interpret else dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary")))
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit))
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=outs, scratch_shapes=scratch, interpret=interpret,
         name=name, cost_estimate=pl.CostEstimate(
-            flops=0, transcendentals=0, bytes_accessed=nbytes),
+            flops=flops, transcendentals=0, bytes_accessed=nbytes),
         **params)(*ins)
 
 
 # ----------------------------------------------------------------- forward
 
-def _forward_kernel(x_ref, y_ref, k_ref, best_ref, arg_ref, *, H, W, Wo, lr,
-                    lc):
-    """Step ``s`` holds input rows ``2 s`` and ``2 s + 1``.  With ``lr``
-    (the rows' low padding) 0 it finishes window row ``s - 1``, whose last
-    row is ``2 s``, and starts row ``s`` from both; with ``lr`` 1 it
-    finishes window row ``s``, whose first row, ``2 s - 1``, was the last
-    step's second.  ``best_ref`` / ``arg_ref`` carry the started window
-    row between steps."""
+def _forward_kernel(x_ref, y_ref, k_ref, best_ref, arg_ref, **geometry):
+    pool_rows(lambda r, col: x_ref[r, col].astype(f32), y_ref, k_ref,
+              best_ref, arg_ref, **geometry)
+
+
+def pool_rows(read, y_ref, k_ref, best_ref, arg_ref, *, H, W, Wo, lr, lc):
+    """The forward's step ``s``: it holds input rows ``2 s`` and
+    ``2 s + 1``, which ``read(r, col)`` gives (``r`` 0 or 1, one input
+    column as float32 ``(C, lanes)``).  With ``lr`` (the rows' low
+    padding) 0 it finishes window row ``s - 1``, whose last row is
+    ``2 s``, and starts row ``s`` from both; with ``lr`` 1 it finishes
+    window row ``s``, whose first row, ``2 s - 1``, was the last step's
+    second.  ``best_ref`` / ``arg_ref`` carry the started window row
+    between steps.  Without ``k_ref`` (and ``arg_ref``) it pools the values
+    alone and stores no index."""
     s = pl.program_id(1)
     ninf = jnp.array(-jnp.inf, f32)
     first_kept = s > 0
+    indexed = k_ref is not None
     # rows past the input: 2 Ho with lr 0 (its last step), H with H odd
     row0_real = 2 * s < H
     row1_real = 2 * s + 1 < H
@@ -150,7 +167,7 @@ def _forward_kernel(x_ref, y_ref, k_ref, best_ref, arg_ref, *, H, W, Wo, lr,
     def column(r, b, pc):
         """Input column ``2 b - lc + pc`` of the step's row ``r``, -inf in
         the padding."""
-        v = x_ref[r, jnp.clip(2 * b - lc + pc, 0, W - 1)].astype(f32)
+        v = read(r, jnp.clip(2 * b - lc + pc, 0, W - 1))
         first, last = _columns(W, Wo, lc, pc)
         if (first, last) == (0, Wo - 1):
             return v
@@ -160,36 +177,48 @@ def _forward_kernel(x_ref, y_ref, k_ref, best_ref, arg_ref, *, H, W, Wo, lr,
         """(max over a window row's three columns, its first column); -inf
         where the row is past the input."""
         best = column(r, b, 0)
-        arg = jnp.zeros(best.shape, jnp.int32)
+        arg = jnp.zeros(best.shape, jnp.int32) if indexed else None
         for pc in (1, 2):
             best, arg = after(best, arg, column(r, b, pc), pc)
         return jnp.where(real, best, ninf), arg
 
     def after(best, arg, v, pos):
         """A later position of the same window: it wins only if greater."""
+        if not indexed:
+            return jnp.maximum(best, v), None
         take = v > best
         return jnp.where(take, v, best), jnp.where(take, pos, arg)
+
+    def plus(arg, rows):
+        return None if arg is None else arg + rows
+
+    def emit(b, best, arg):
+        y_ref[0, b] = best.astype(y_ref.dtype)
+        if indexed:
+            k_ref[0, b] = arg.astype(k_ref.dtype)
+
+    def keep(b, best, arg):
+        best_ref[b] = best
+        if indexed:
+            arg_ref[b] = arg
 
     def step(b, _):
         v0, p0 = row_max(0, b, row0_real)
         v1, p1 = row_max(1, b, row1_real)
         if lr == 0:
             # window row s - 1 ends with input row 2 s, its position row 2
-            best, arg = after(best_ref[b], arg_ref[b], v0, p0 + 6)
-            y_ref[0, b] = best.astype(y_ref.dtype)
-            k_ref[0, b] = arg.astype(k_ref.dtype)
-            best, arg = after(v0, p0, v1, p1 + 3)   # window row s: rows 0, 1
+            best = best_ref[b]
+            arg = arg_ref[b] if indexed else None
+            emit(b, *after(best, arg, v0, plus(p0, 6)))
+            # window row s: rows 0, 1
+            keep(b, *after(v0, p0, v1, plus(p1, 3)))
         else:
             # window row s: the carried position row 0, then rows 1 and 2
             best = jnp.where(first_kept, best_ref[b], ninf)
-            arg = jnp.where(first_kept, arg_ref[b], 0)
-            best, arg = after(best, arg, v0, p0 + 3)
-            best, arg = after(best, arg, v1, p1 + 6)
-            y_ref[0, b] = best.astype(y_ref.dtype)
-            k_ref[0, b] = arg.astype(k_ref.dtype)
-            best, arg = v1, p1          # window row s + 1's position row 0
-        best_ref[b] = best
-        arg_ref[b] = arg
+            arg = jnp.where(first_kept, arg_ref[b], 0) if indexed else None
+            best, arg = after(best, arg, v0, plus(p0, 3))
+            emit(b, *after(best, arg, v1, plus(p1, 6)))
+            keep(b, v1, p1)             # window row s + 1's position row 0
         return 0
 
     jax.lax.fori_loop(0, Wo, step, 0)
@@ -207,16 +236,16 @@ def forward(x, *, interpret: bool = False):
     pooled = pl.BlockSpec((1, Wo, C, nb),
                           lambda n, s: (jnp.maximum(s - 1 + lr, 0), 0, 0, n))
     # lr 0 ends its last window a step late
-    y, index = _call(
+    y, index = call(
         kernel, (N // nb, Ho + 1 - lr),
-        [_to_view(x)],
+        [to_view(x)],
         [pl.BlockSpec((2, W, C, nb),
                       lambda n, s: (jnp.minimum(s, last), 0, 0, n))],
         [jax.ShapeDtypeStruct((Ho, Wo, C, N), x.dtype),
          jax.ShapeDtypeStruct((Ho, Wo, C, N), jnp.int8)], [pooled, pooled],
         [pltpu.VMEM((Wo, C, nb), f32), pltpu.VMEM((Wo, C, nb), jnp.int32)],
         name="max_pool_forward", interpret=interpret)
-    return _from_view(y), index
+    return from_view(y), index
 
 
 # ---------------------------------------------------------------- backward
@@ -286,12 +315,12 @@ def backward(index, g, hw, *, interpret: bool = False):
     window = pl.BlockSpec((1, Wo, C, nb),
                           lambda n, s: (jnp.minimum(s, Ho - 1), 0, 0, n))
     # lr 1 writes its last rows a step late
-    (dx,) = _call(
+    (dx,) = call(
         kernel, (N // nb, Ho + lr),
-        [_to_view(g), index], [window, window],
+        [to_view(g), index], [window, window],
         [jax.ShapeDtypeStruct((H, W, C, N), g.dtype)],
         [pl.BlockSpec((2, W, C, nb),
                       lambda n, s: (jnp.maximum(s - lr, 0), 0, 0, n))],
         [pltpu.VMEM((Wo, C, nb), g.dtype), pltpu.VMEM((Wo, C, nb), jnp.int32)],
         name="max_pool_backward", interpret=interpret)
-    return _from_view(dx)
+    return from_view(dx)

@@ -58,7 +58,8 @@ from r2d2_tpu.config import Config
 from r2d2_tpu.envs import create_env
 from r2d2_tpu.learner.learner import Learner
 from r2d2_tpu.learner.step import create_train_state, target_syncs
-from r2d2_tpu.models.network import create_network, init_params
+from r2d2_tpu.models.network import (create_network, init_params,
+                                     stem_fused)
 from r2d2_tpu.parallel.mesh import make_mesh
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
 from r2d2_tpu.telemetry import Telemetry, format_entry
@@ -871,6 +872,7 @@ def _train_anakin(cfg: Config, tracer: Tracer, setup: SetupClock,
 
     def log_loop():
         last_steps, last_frames, last_time = 0, 0, time.time()
+        stem_share = stem_fused(cfg)
         while not stop():
             time.sleep(min(cfg.log_interval, 0.5))
             now = time.time()
@@ -885,6 +887,8 @@ def _train_anakin(cfg: Config, tracer: Tracer, setup: SetupClock,
                 tracer.gauge("core." + name, value)  # graftlint: disable=telemetry-discipline -- the names are the model's own closed set (a core module's COUNTERS), not data
             syncs = target_syncs(cfg, s["training_steps"])
             tracer.gauge("learner.target_syncs", syncs)
+            if stem_share is not None:
+                tracer.gauge("torso.stem_fused", stem_share)
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"], target_syncs=syncs,
@@ -911,6 +915,8 @@ def _train_anakin(cfg: Config, tracer: Tracer, setup: SetupClock,
             )
             if lc:
                 entry["core"] = lc
+            if stem_share is not None:
+                entry["torso"] = dict(stem_fused=stem_share)
             # learnhealth + alerts: the anakin PER leaves live in-graph
             # (no host tree to walk), so no replay data-health here —
             # the in-graph diag bundle covers the learner side
@@ -1377,6 +1383,7 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
 
     def log_loop():
         last_steps, last_time = 0, time.time()
+        stem_share = stem_fused(cfg)
         while not stop():
             time.sleep(min(cfg.log_interval, 0.5))
             now = time.time()
@@ -1388,6 +1395,8 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
             tracer.gauge("priority_queue_depth", priority_queue.qsize())
             syncs = target_syncs(cfg, s["training_steps"])
             tracer.gauge("learner.target_syncs", syncs)
+            if stem_share is not None:
+                tracer.gauge("torso.stem_fused", stem_share)
             entry = dict(
                 time=now, buffer_size=s["size"], env_steps=s["env_steps"],
                 training_steps=s["training_steps"], target_syncs=syncs,
@@ -1401,6 +1410,8 @@ def train(cfg: Config, env_factory: EnvFactory = _default_env_factory,
                 learner_heartbeat_age=heartbeat.age(),
                 telemetry_port=telemetry.port,
             )
+            if stem_share is not None:
+                entry["torso"] = dict(stem_fused=stem_share)
             if chaos is not None:
                 entry["chaos"] = chaos.counts()
             if plane is not None:

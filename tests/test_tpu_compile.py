@@ -411,12 +411,13 @@ def _lowered_on_tpu(build, *args):
 
 
 def assert_the_pools_keep_an_index_in_the_step_layout(text, cfg):
-    """The max-pool's backward is the kernels' (ops/pool.py), not XLA's
-    ``select-and-scatter`` over the pre-pool activations; the kernels read
-    and write the step's own ``{0,3,2,1}`` arrays as their ``[H, W, C, N]``
-    view, so no ``copy`` or ``transpose`` of a pool's array stands beside
-    them; and each pre-pool activation is read by its forward kernel alone
-    — nothing keeps it for the backward (PERF.md, Findings)."""
+    """The later stages' max-pool backward is the kernels' (ops/pool.py),
+    not XLA's ``select-and-scatter`` over the pre-pool activations (the
+    first stage's pool is the stem's, below); the kernels read and write
+    the step's own ``{0,3,2,1}`` arrays as their ``[H, W, C, N]`` view, so
+    no ``copy`` or ``transpose`` of a pool's array stands beside them; and
+    each pre-pool activation is read by its forward kernel alone — nothing
+    keeps it for the backward (PERF.md, Findings)."""
     assert "select-and-scatter(" not in text
     comps, _ = _computations(text)
     ops = [op for lines in comps.values() for op in _operations(lines)]
@@ -424,7 +425,7 @@ def assert_the_pools_keep_an_index_in_the_step_layout(text, cfg):
     calls = {kind: [op for op in ops if op[0].startswith(kind + ".")
                     and op[2] == "custom-call"]
              for kind in ("max_pool_forward", "max_pool_backward")}
-    assert [len(c) for c in calls.values()] == [3, 3]
+    assert [len(c) for c in calls.values()] == [2, 2]
     shapes = _pool_shapes(cfg)
     relaid = [(name, result) for name, result, kind, _, _ in ops
               if kind in ("copy", "transpose")
@@ -439,6 +440,36 @@ def assert_the_pools_keep_an_index_in_the_step_layout(text, cfg):
             (name, pre, readers)
 
 
+def assert_the_stem_never_writes_its_convolution(text, cfg, forwards):
+    """The first stage's convolution and pool are ops/stem.py's kernels:
+    ``forwards`` ``stem_forward`` calls (the online pass, and the target
+    network's in the whole step) and one ``stem_backward``, each reading
+    the frames as the same ``[H, W, N]`` array; no array of the
+    convolution's output shape, ``[N, 84, 84, 16]`` in any layout, is
+    left anywhere: not the forwards' outputs, not the cotangent that
+    ``Conv_0``'s weight gradient was summed over (PERF.md, Findings)."""
+    n, size = cfg.batch_size * cfg.seq_len, cfg.obs_shape[0]
+    comps, _ = _computations(text)
+    ops = [op for lines in comps.values() for op in _operations(lines)]
+    by_name = {op[0]: op for op in ops}
+    calls = {kind: [op for op in ops if op[0].startswith(kind + ".")
+                    and op[2] == "custom-call"]
+             for kind in ("stem_forward", "stem_backward")}
+    assert [len(c) for c in calls.values()] == [forwards, 1]
+    views = ([op[3][-1] for op in calls["stem_forward"]]
+             + [op[3][0] for op in calls["stem_backward"]])
+    assert {by_name[v][1].split("{")[0] for v in views} == {
+        f"bf16[{size},{size},{n}]"}
+    roots = set()
+    for view in views:
+        while by_name[view][2] == "bitcast":
+            view = by_name[view][3][0]
+        roots.add(view)
+    assert len(roots) == 1, roots
+    pre = [f"[{n},{size},{size},16]", f"[{size},{size},16,{n}]"]
+    assert [s for s in pre if s in text] == []
+
+
 @pytest.fixture(scope="module")
 def impala_cell(one_chip):
     from benchmark.drivers.train import build_config
@@ -447,11 +478,10 @@ def impala_cell(one_chip):
     return build_config(Manifest().cell("impala_deep_lstm2.anakin"), False)
 
 
-def test_the_impala_torso_pools_by_the_stored_index(one_chip, impala_cell):
-    """The gradient of the torso alone at the cell's 7,680 frames (~25 s):
-    where the step spent 10.9 ms an update in two ``select-and-scatter``
-    ops over the pre-pool tensors (PERF.md, Findings), it holds the three
-    pools' forward and backward kernels, on the step's own layout."""
+@pytest.fixture(scope="module")
+def impala_torso_gradient(one_chip, impala_cell):
+    """The compiled text of the torso's gradient alone at the cell's 7,680
+    frames (~25 s)."""
     from r2d2_tpu.models.network import ImpalaTorso
 
     _, sharding = one_chip
@@ -470,19 +500,42 @@ def test_the_impala_torso_pools_by_the_stored_index(one_chip, impala_cell):
         return jax.grad(lambda p: jnp.sum(
             torso.apply(p, x).astype(jnp.float32) * g))(p)
 
-    text = compile_uncached(_lowered_on_tpu(
-        lambda: jax.jit(gradient), params, sds((n, *cfg.obs_shape)),
+    return compile_uncached(_lowered_on_tpu(
+        lambda: jax.jit(gradient), params,
+        sds((n, *cfg.obs_shape), jnp.bfloat16),
         sds((n, cfg.hidden_dim)))).as_text()
-    assert_the_pools_keep_an_index_in_the_step_layout(text, cfg)
+
+
+def test_the_impala_torso_pools_by_the_stored_index(impala_torso_gradient,
+                                                    impala_cell):
+    """Where the step spent 10.9 ms an update in two ``select-and-scatter``
+    ops over the pre-pool tensors (PERF.md, Findings), the torso's gradient
+    holds the later pools' forward and backward kernels, on the step's own
+    layout."""
+    assert_the_pools_keep_an_index_in_the_step_layout(impala_torso_gradient,
+                                                      impala_cell)
+
+
+def test_the_impala_stem_never_writes_its_convolution(impala_torso_gradient,
+                                                      impala_cell):
+    """Where the first stage's convolution wrote 1.73 GB, its pool read it
+    back and its weight gradient was summed over a cotangent of the same
+    size (six of the step's ten largest operations, PERF.md §5), the
+    torso's gradient holds one ``stem_forward`` and one ``stem_backward``
+    and no array of that shape."""
+    assert_the_stem_never_writes_its_convolution(impala_torso_gradient,
+                                                 impala_cell, forwards=1)
 
 
 @pytest.mark.slow
 def test_the_impala_fused_step_pools_by_the_stored_index(one_chip,
                                                          impala_cell):
     """The same of ``impala_deep_lstm2.anakin``'s whole super-step (~60 s):
-    the online pass's three pools take the kernels; the target network's
-    forward and acting, which nothing differentiates, keep
-    ``nn.max_pool``'s ``reduce-window`` and store no index."""
+    the online pass's later two pools take ops/pool.py's kernels and its
+    first stage the stem's; the target network's forward takes the stem's
+    forward too, and keeps ``nn.max_pool``'s ``reduce-window`` at the later
+    stages, as acting does at all three (64 frames), storing no index; no
+    ``reduce-window`` pools the first stage's 7,680 frames."""
     from benchmark.drivers.train import ACTION_DIM
     from r2d2_tpu.learner.anakin import make_anakin_super_step
     from r2d2_tpu.learner.step import create_train_state
@@ -498,7 +551,11 @@ def test_the_impala_fused_step_pools_by_the_stored_index(one_chip,
         state, *loop,
         jax.ShapeDtypeStruct((), jnp.uint32, sharding=sharding))).as_text()
     assert_the_pools_keep_an_index_in_the_step_layout(text, cfg)
-    assert len(re.findall(r"= bf16\[\S+ reduce-window\(", text)) >= 3
+    assert_the_stem_never_writes_its_convolution(text, cfg, forwards=2)
+    pooled = re.findall(r"= bf16\[([\d,]+)\]\S* reduce-window\(", text)
+    assert len(pooled) >= 3
+    n = cfg.batch_size * cfg.seq_len
+    assert f"{n},42,42,16" not in pooled and f"42,42,16,{n}" not in pooled
 
 
 # ------------------------------------------------ the fused loop's lane buffers
